@@ -342,6 +342,9 @@ def run() -> list[dict]:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tune", action="store_true",
                     help="autotune kernel blocks before benchmarking")

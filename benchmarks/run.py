@@ -11,6 +11,9 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     t_all = time.time()
     rows: list[tuple[str, float, str]] = []
     details: dict = {}

@@ -1153,6 +1153,9 @@ def _row_derived(e: dict) -> str:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke: small tenant/slot grid")

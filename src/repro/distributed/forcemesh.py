@@ -76,6 +76,7 @@ def install(spec: tuple[int, int] | None = None):
     import jax
 
     from repro.distributed import context
+    from repro.launch.mesh import make_mesh
 
     d, m = spec
     if len(jax.devices()) < d * m:
@@ -83,6 +84,6 @@ def install(spec: tuple[int, int] | None = None):
             f"{ENV}={d}x{m} needs {d * m} devices but jax initialised "
             f"{len(jax.devices())}; apply_xla_flags() must run before "
             "anything touches jax")
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     context.set_mesh_axes("data", "model", mesh)
     return mesh

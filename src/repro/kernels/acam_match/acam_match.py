@@ -349,7 +349,7 @@ def _classify_margins_chunked_kernel(f_ref, thr_ref, t_ref, v_ref, lo_ref,
 
     @pl.when(k == nk - 1)
     def _chunk_epilogue():
-        from repro.kernels.layout import windowed_margin
+        from repro.kernels.layout import store_class_chunk, windowed_margin
 
         np_ = float(nk * f_ref.shape[-1])
         scores = (np_ + acc_ref[...]) * 0.5 - (np_ - n_true)
@@ -358,18 +358,14 @@ def _classify_margins_chunked_kernel(f_ref, thr_ref, t_ref, v_ref, lo_ref,
         chunk_pc = s[:, :cc]
         for kk in range(1, num_k):
             chunk_pc = jnp.maximum(chunk_pc, s[:, kk * cc:(kk + 1) * cc])
-        # running per-class max in the revisited (bm, Cp) block; the j == 0
-        # chunk overwrites whatever the buffer held (uninitialised memory)
-        prev = jnp.where(j == 0,
-                         jnp.full(pc_ref.shape, -jnp.inf, pc_ref.dtype),
-                         pc_ref[...])
-        # chunk offsets are cc (lane-tile) multiples, so the dynamic lane
-        # slice stays aligned on TPU
-        pc = jax.lax.dynamic_update_slice(prev, chunk_pc, (0, j * cc))
-        pc_ref[...] = pc
+        # per-class max of this chunk lands in its columns of the revisited
+        # (bm, Cp) block; the nj chunks tile Cp, so every column is written
+        # before the last chunk reads the block back
+        store_class_chunk(pc_ref, chunk_pc, j)
 
         @pl.when(j == nj - 1)
         def _final():
+            pc = pc_ref[...]
             pred, margin = windowed_margin(pc, lo_ref[..., :1],
                                            hi_ref[..., :1], float(n_true))
             pred_ref[...] = jnp.broadcast_to(pred[:, None], pred_ref.shape)
@@ -480,7 +476,7 @@ def _serve_kernel(f_ref, slot_ref, thr_ref, t_ref, v_ref, lo_ref, hi_ref,
 
     @pl.when(k == nk - 1)
     def _chunk_epilogue():
-        from repro.kernels.layout import windowed_margin
+        from repro.kernels.layout import store_class_chunk, windowed_margin
 
         np_ = float(nk * f_ref.shape[-1])
         scores = (np_ + acc_ref[...]) * 0.5 - (np_ - n_true)
@@ -489,14 +485,11 @@ def _serve_kernel(f_ref, slot_ref, thr_ref, t_ref, v_ref, lo_ref, hi_ref,
         chunk_pc = s[:, :cc]
         for kk in range(1, num_k):
             chunk_pc = jnp.maximum(chunk_pc, s[:, kk * cc:(kk + 1) * cc])
-        prev = jnp.where(j == 0,
-                         jnp.full(pc_ref.shape, -jnp.inf, pc_ref.dtype),
-                         pc_ref[...])
-        pc = jax.lax.dynamic_update_slice(prev, chunk_pc, (0, j * cc))
-        pc_ref[...] = pc
+        store_class_chunk(pc_ref, chunk_pc, j)
 
         @pl.when(j == nj - 1)
         def _final():
+            pc = pc_ref[...]
             pred, margin = windowed_margin(pc, lo_ref[..., :1],
                                            hi_ref[..., :1], float(n_true))
             # the cascade's escalation mask: strictly below tau asks for the

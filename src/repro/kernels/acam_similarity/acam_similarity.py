@@ -51,6 +51,18 @@ DEFAULT_BLOCK = (8, 128, 128)  # bm (queries), bn (templates), bk (features)
 PRED_LANES = 128  # WTA index output padded to one lane tile
 
 
+def eq11(hits: jax.Array, d: jax.Array, n: int, alpha: float) -> jax.Array:
+    """Eq. 10-11 from the hit COUNT: S = H_count / (N (1 + alpha D)).
+
+    One division of a runtime denominator, the same op in these kernels
+    and in the jnp oracles, so the two round alike on a TPU. The textbook
+    form (H_count / N) / (1 + alpha D) divides by the constant N, which XLA
+    turns into a multiply by 1/N and Mosaic does not: on a TPU v5e that
+    put the kernel an ulp away from the reference (1.0000001 for a perfect
+    match), which breaks ties differently."""
+    return hits / (float(n) * (1.0 + alpha * d))
+
+
 def _kernel(q_ref, lo_ref, hi_ref, d_ref, h_ref, s_ref, *, nk: int,
             alpha: float, n_true: int):
     k = pl.program_id(2)
@@ -75,8 +87,7 @@ def _kernel(q_ref, lo_ref, hi_ref, d_ref, h_ref, s_ref, *, nk: int,
         # padded feature columns have lo=0=hi and q=0 => they count as hits;
         # subtract the pad count from H before normalising by the true N.
         pad_hits = float(nk * q_ref.shape[-1] - n_true)
-        h = (h_ref[...] - pad_hits) / float(n_true)
-        s_ref[...] = h / (1.0 + alpha * d_ref[...])
+        s_ref[...] = eq11(h_ref[...] - pad_hits, d_ref[...], n_true, alpha)
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "block", "interpret"))
@@ -148,8 +159,7 @@ def _classify_kernel(f_ref, thr_ref, lo_ref, hi_ref, vrow_ref, d_ref, h_ref,
         from repro.kernels.layout import wta_epilogue
 
         pad_hits = float(nk * f_ref.shape[-1] - n_true)
-        h = (h_ref[...] - pad_hits) / float(n_true)
-        s = h / (1.0 + alpha * d_ref[...])
+        s = eq11(h_ref[...] - pad_hits, d_ref[...], n_true, alpha)
         per_class, pred = wta_epilogue(s, vrow_ref[...], cp, num_k)
         pc_ref[...] = per_class
         pred_ref[...] = jnp.broadcast_to(pred[:, None], pred_ref.shape)
@@ -257,24 +267,20 @@ def _serve_kernel(f_ref, slot_ref, thr_ref, lo_ref, hi_ref, v_ref, wlo_ref,
 
     @pl.when(k == nk - 1)
     def _chunk_epilogue():
-        from repro.kernels.layout import windowed_margin
+        from repro.kernels.layout import store_class_chunk, windowed_margin
 
         pad_hits = float(nk * f_ref.shape[-1] - n_true)
-        h = (h_ref[...] - pad_hits) / float(n_true)
-        s = h / (1.0 + alpha * d_ref[...])
+        s = eq11(h_ref[...] - pad_hits, d_ref[...], n_true, alpha)
         vrow = v_ref[...].reshape(1, num_k * cc)
         s = jnp.where(vrow > 0, s, -jnp.inf)
         chunk_pc = s[:, :cc]
         for kk in range(1, num_k):
             chunk_pc = jnp.maximum(chunk_pc, s[:, kk * cc:(kk + 1) * cc])
-        prev = jnp.where(j == 0,
-                         jnp.full(pc_ref.shape, -jnp.inf, pc_ref.dtype),
-                         pc_ref[...])
-        pc = jax.lax.dynamic_update_slice(prev, chunk_pc, (0, j * cc))
-        pc_ref[...] = pc
+        store_class_chunk(pc_ref, chunk_pc, j)
 
         @pl.when(j == nj - 1)
         def _final():
+            pc = pc_ref[...]
             pred, margin = windowed_margin(pc, wlo_ref[..., :1],
                                            whi_ref[..., :1], 1.0)
             esc = (margin < tau_ref[..., 0]).astype(jnp.int32)

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 LANE = 128
 
@@ -79,6 +80,27 @@ def class_chunk(cp: int, num_k: int, max_rows: int, lane: int = LANE) -> int:
     return best
 
 
+def store_class_chunk(pc_ref, chunk_pc: jax.Array, j) -> None:
+    """Store chunk ``j``'s (bm, cc) per-class max into its columns of the
+    (bm, Cp) per-class block, through the ref (Mosaic lowers no
+    ``dynamic_update_slice`` on a loaded value). Chunk widths are lane
+    multiples, so the dynamic column offset stays lane-aligned on TPU."""
+    cc = chunk_pc.shape[-1]
+    off = pl.multiple_of(j * cc, LANE)
+    pc_ref[:, pl.ds(off, cc)] = chunk_pc
+
+
+def first_argmax(x: jax.Array) -> jax.Array:
+    """Index of the first maximum along the last axis, int32: the tie rule
+    of `jnp.argmax` (lowest index), spelled out because Mosaic's argmax
+    lowering does not keep it — on a TPU v5e, in-kernel `jnp.argmax` picked
+    other tied classes than the jnp reference. A row of all -inf gives 0,
+    as `jnp.argmax` does. Pure jnp, safe inside a Pallas kernel body."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    top = jnp.max(x, axis=-1, keepdims=True)
+    return jnp.min(jnp.where(x == top, iota, x.shape[-1]), axis=-1)
+
+
 def wta_epilogue(scores: jax.Array, valid_row: jax.Array, cp: int,
                  num_k: int) -> tuple[jax.Array, jax.Array]:
     """Shared fused-kernel epilogue over K-major scores (pure jnp, runs
@@ -92,8 +114,7 @@ def wta_epilogue(scores: jax.Array, valid_row: jax.Array, cp: int,
     per_class = s[:, :cp]
     for kk in range(1, num_k):
         per_class = jnp.maximum(per_class, s[:, kk * cp:(kk + 1) * cp])
-    pred = jnp.argmax(per_class, axis=-1).astype(jnp.int32)
-    return per_class, pred
+    return per_class, first_argmax(per_class)
 
 
 def windowed_margin(per_class: jax.Array, class_lo: jax.Array,
@@ -120,7 +141,7 @@ def windowed_margin(per_class: jax.Array, class_lo: jax.Array,
     win = (iota >= class_lo) & (iota < class_hi)
     s = jnp.where(win, per_class, -jnp.inf)
     top1 = jnp.max(s, axis=-1)
-    pred = jnp.argmax(s, axis=-1).astype(jnp.int32)
+    pred = first_argmax(s)
     runner = jnp.where(iota == pred[:, None], -jnp.inf, s)
     # clamp the runner-up at (top1 - cap): bounds the margin and keeps the
     # subtraction finite when the window holds a single valid class

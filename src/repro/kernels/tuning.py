@@ -225,18 +225,25 @@ def autotune(kernel: str, shape: tuple[int, int, int], dtype,
 
     `run` must execute the kernel end to end for a given block (the caller
     binds the concrete operands). Candidates that fail to lower (e.g. VMEM
-    overflow on a real TPU) are skipped rather than fatal.
+    overflow on a real TPU) are skipped; when none compiles, the kernel
+    cannot run at this shape and the first failure is raised.
     """
     best: tuple[float, Block] | None = None
+    failures: list[tuple[Block, Exception]] = []
     for block in (cands if cands is not None else candidates(kernel)):
         try:
             us = _time_call(lambda: run(block), iters)
-        except Exception:  # noqa: BLE001 — lowering/OOM failures just lose
+        except Exception as e:  # noqa: BLE001 — a block that fails loses
+            failures.append((block, e))
             continue
         if best is None or us < best[0]:
             best = (us, block)
     if best is None:
-        return default_block(kernel)
+        block, err = failures[0]
+        raise RuntimeError(
+            f"autotune {kernel} at {shape}: none of {len(failures)} candidate "
+            f"blocks ran (first, {block}: {type(err).__name__}: {err})"
+        ) from err
     if save:
         _save_entry(entry_key(kernel, shape, dtype), best[1], best[0])
     return best[1]

@@ -1,12 +1,27 @@
-"""Production mesh definitions (multi-pod dry-run spec).
+"""Mesh construction: every mesh in the repo is built by `make_mesh`.
 
-A function, not a module-level constant, so importing this module never
+Functions, not module-level constants, so importing this module never
 touches jax device state.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
-import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
+              devices=None) -> jax.sharding.Mesh:
+    """The one mesh constructor: ``jax.make_mesh`` with every axis
+    ``AxisType.Auto``. Since jax 0.8 ``jax.make_mesh`` defaults to Explicit
+    axes, under which ``with_sharding_constraint`` and the pallas calls
+    inside the engine's ``shard_map`` are refused; the code here places
+    arrays through GSPMD and so needs Auto axes. ``devices`` defaults to
+    ``jax.devices()``; the first ``prod(shape)`` of them are used."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,12 +29,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     "pod" axis: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests (axes kept for spec parity)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_serving_mesh(*, bank_shards: int = 1,
@@ -47,6 +62,5 @@ def make_serving_mesh(*, bank_shards: int = 1,
         raise ValueError(
             f"bank_shards={bank_shards} must divide the {ndev} available "
             "devices")
-    return jax.sharding.Mesh(
-        np.asarray(devs).reshape(ndev // bank_shards, bank_shards),
-        tuple(axis_names))
+    return make_mesh((ndev // bank_shards, bank_shards), axis_names,
+                     devices=devs)

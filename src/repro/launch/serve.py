@@ -298,6 +298,9 @@ def run_lm_cached(args) -> dict:
 
 
 def main(argv=None) -> dict:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", choices=("lm", "acam", "lm-cached"),
                     default="lm")

@@ -51,6 +51,9 @@ def synthetic_batch(cfg: lm.ArchConfig, batch: int, seq: int, step: int) -> dict
 
 
 def main(argv=None) -> dict:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true")

@@ -181,8 +181,9 @@ def similarity_scores_ref(
     above = jnp.maximum(q - hi, 0.0)
     below = jnp.maximum(lo - q, 0.0)
     d = jnp.sum(above**2 + below**2, axis=-1)  # Eq. 9
-    hit = jnp.mean((q >= lo) & (q <= hi), axis=-1)  # Eq. 10
-    s = hit / (1.0 + alpha * d)  # Eq. 11
+    hits = jnp.sum(((q >= lo) & (q <= hi)).astype(jnp.float32), axis=-1)
+    from repro.kernels.acam_similarity.acam_similarity import eq11
+    s = eq11(hits, d, q.shape[-1], alpha)  # Eq. 10-11
     if valid is not None:
         s = jnp.where(valid[None, :, :], s, NEG)
     return s

@@ -57,7 +57,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.templates import TemplateBank
@@ -263,10 +262,10 @@ class MatchEngine:
         in_specs = tuple(plan.batch_spec() for _ in batch_args) + tuple(
             bank_specs(plan) if isinstance(a, TemplateBank)
             else plan.class_spec() for a in bank_args)
-        # check_rep=False: pallas_call has no replication rule; outputs are
+        # check_vma=False: pallas_call has no replication rule; outputs are
         # either batch-local or made identical on every shard by the reduce.
-        sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+        sharded = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=False)
         return sharded(*batch_args, *bank_args)
 
     @staticmethod
@@ -456,12 +455,12 @@ class MatchEngine:
 
             in_specs = tuple(plan.batch_spec() for _ in batch_args) + (
                 P(), bank_specs(plan))
-            sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=(plan.batch_spec(1),
-                                           plan.batch_spec(2),
-                                           plan.batch_spec(1),
-                                           plan.batch_spec(1)),
-                                check_rep=False)
+            sharded = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                    out_specs=(plan.batch_spec(1),
+                                               plan.batch_spec(2),
+                                               plan.batch_spec(1),
+                                               plan.batch_spec(1)),
+                                    check_vma=False)
             return sharded(*batch_args, thr_table, bank)
         cap = be.margin_cap(features.shape[-1])
 
@@ -474,12 +473,12 @@ class MatchEngine:
 
         in_specs = tuple(plan.batch_spec() for _ in batch_args) + (
             P(), bank_specs(plan))
-        sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=(plan.batch_spec(1),
-                                       plan.batch_class_spec(2),
-                                       plan.batch_spec(1),
-                                       plan.batch_spec(1)),
-                            check_rep=False)
+        sharded = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                out_specs=(plan.batch_spec(1),
+                                           plan.batch_class_spec(2),
+                                           plan.batch_spec(1),
+                                           plan.batch_spec(1)),
+                                check_vma=False)
         return sharded(*batch_args, thr_table, bank)
 
     def __call__(self, features: Array, bank: TemplateBank,

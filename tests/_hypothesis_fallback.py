@@ -1,12 +1,10 @@
 """Import-or-shim for `hypothesis`.
 
-The container this repo's tier-1 suite runs in does not ship `hypothesis`
-(and installing packages is off-limits), which used to kill collection of
-three test modules with ImportError. Test modules import `given`/`settings`/
-`st` from here instead: when the real package is available it is used
-verbatim; otherwise a deterministic single-example fallback runs each
-property test once at the midpoint of every strategy's range — strictly
-weaker than real property testing, but the assertions still execute.
+Test modules import `given`/`settings`/`st` from here: when `hypothesis` is
+installed it is used verbatim; where it is not, a deterministic
+single-example fallback runs each property test once at the midpoint of
+every strategy's range — strictly weaker than real property testing, but
+the assertions still execute and collection never fails on the import.
 """
 from __future__ import annotations
 

@@ -226,7 +226,8 @@ class TestForced2x2Mesh:
                 pred1, pc1 = eng.classify_features(feats, bank)
                 p1, _, m1 = eng.classify_features_margin(feats, bank, lo, hi)
 
-                mesh = jax.make_mesh((2, 2), ("data", "model"))
+                from repro.launch.mesh import make_mesh
+                mesh = make_mesh((2, 2), ("data", "model"))
                 context.set_mesh_axes("data", "model", mesh)
                 plan, _ = match.plan_for(batch=b, num_classes=c)
                 assert plan.bank_shards == 2 and plan.dp_devices == 2, plan
@@ -309,7 +310,8 @@ class TestForced2x2Mesh:
                     svc1, out1 = build_and_serve(layout, slots, churn)
                     assert svc1.registry.bank_shards == 1
 
-                    mesh = jax.make_mesh((2, 2), ("data", "model"))
+                    from repro.launch.mesh import make_mesh
+                    mesh = make_mesh((2, 2), ("data", "model"))
                     context.set_mesh_axes("data", "model", mesh)
                     svc2, out2 = build_and_serve(layout, slots, churn)
                     context.clear()
@@ -374,7 +376,8 @@ class TestForced2x2Mesh:
             results = {}
             for strat in ("allgather", "tree"):
                 os.environ["REPRO_REDUCE_STRATEGY"] = strat
-                mesh = jax.make_mesh((2, 2), ("data", "model"))
+                from repro.launch.mesh import make_mesh
+                mesh = make_mesh((2, 2), ("data", "model"))
                 context.set_mesh_axes("data", "model", mesh)
                 plan, _ = match.plan_for(batch=b, num_classes=c)
                 assert plan.bank_shards == 2 and plan.reduce == strat, plan

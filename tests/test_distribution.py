@@ -35,13 +35,10 @@ class TestShardingRules:
     def _specs(self, arch="tinyllama-1.1b"):
         from repro import configs
         from repro.distributed import sharding
-        # spec construction needs only mesh *shape* metadata; a 1-device
-        # host is enough to build an abstract 16x16 mesh? No — use the
-        # abstract mesh API via make_mesh on available devices:
-        import numpy as np
-        from jax.sharding import Mesh
-        devs = np.asarray(jax.devices()[:1]).reshape(1, 1)
-        mesh = Mesh(devs, ("data", "model"))
+        # spec construction needs only mesh *shape* metadata: a 1x1 mesh
+        # on the host device is enough
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         cfg = configs.get(arch)
         return cfg, mesh, sharding
 
@@ -66,10 +63,8 @@ class TestShardingRules:
 
     def test_dp_axes(self):
         from repro.distributed import sharding
-        from jax.sharding import Mesh
-        import numpy as np
-        mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
-                    ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         assert sharding.dp_axes(mesh) == "data"
 
 
@@ -86,7 +81,8 @@ class TestMultiDevice:
             from repro.launch import steps as steps_lib
             from repro.models import lm
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             cfg = configs.get("qwen3-1.7b", smoke=True)
             mesh_ctx.set_mesh_axes("data", "model")
             with mesh:
@@ -126,7 +122,8 @@ class TestMultiDevice:
                      "labels": jnp.ones((4, 16), jnp.int32)}
             ckdir = tempfile.mkdtemp()
 
-            mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh_a = make_mesh((4, 2), ("data", "model"))
             mesh_ctx.set_mesh_axes("data", "model")
             with mesh_a:
                 fn, in_sp, _, opt = steps_lib.build_train_step(
@@ -140,7 +137,8 @@ class TestMultiDevice:
                 ck = Checkpointer(ckdir)
                 ck.save(0, {"p": params, "o": opt_state})
 
-            mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh_b = make_mesh((2, 4), ("data", "model"))
             with mesh_b:
                 fn2, in_sp2, _, opt2 = steps_lib.build_train_step(
                     cfg, mesh_b, example_batch=batch)
@@ -166,3 +164,29 @@ class TestMultiDevice:
             dryrun.main()
             """, timeout=900)
         assert "all dry-run cells passed" in out
+
+
+class TestCompileCache:
+    """`repro.launch.compile_cache`: the environment wins, else a fixed
+    directory inside the checkout that git ignores."""
+
+    def test_env_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch import compile_cache
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_in_checkout(self, monkeypatch):
+        from repro.launch import compile_cache
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = compile_cache.use_compile_cache()
+            assert path == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()

@@ -9,9 +9,11 @@ import numpy as np
 import pytest
 from _hypothesis_fallback import given, settings, st
 
+from repro.kernels import layout
 from repro.kernels.acam_match import ops as match_ops
 from repro.kernels.acam_match.ref import acam_match_ref
 from repro.kernels.acam_similarity import ops as sim_ops
+from repro.kernels.acam_similarity.acam_similarity import eq11
 from repro.kernels.acam_similarity.ref import acam_similarity_ref
 from repro.kernels.kd_loss import ops as kd_ops
 from repro.kernels.kd_loss.ref import kd_loss_ref
@@ -86,6 +88,25 @@ class TestAcamSimilarity:
         s = sim_ops.similarity_scores(q, lo, hi)
         assert bool(jnp.all((s >= 0) & (s <= 1)))
         np.testing.assert_allclose(s, 1.0)  # everything inside the window
+
+    @pytest.mark.parametrize("n", [50, 784])
+    def test_eq11_perfect_match_is_one(self, n):
+        hits = jnp.asarray([float(n), 0.0])
+        s = eq11(hits, jnp.zeros(2), n, 1.0)
+        assert s.tolist() == [1.0, 0.0]
+
+
+class TestLayout:
+    @pytest.mark.parametrize("row", [
+        [0.5, 1.0, 1.0, 0.2],           # tie: lowest index wins
+        [1.0, 1.0, 1.0, 1.0],
+        [-np.inf, -np.inf, -np.inf],    # nothing valid: index 0
+        [-np.inf, 3.0, -np.inf, 3.0],
+    ])
+    def test_first_argmax_matches_jnp_argmax(self, row):
+        x = jnp.asarray([row], jnp.float32)
+        assert int(layout.first_argmax(x)[0]) == int(jnp.argmax(x[0]))
+        assert layout.first_argmax(x).dtype == jnp.int32
 
 
 class TestKDLoss:

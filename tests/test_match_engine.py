@@ -310,7 +310,8 @@ class TestPartitionPlan:
     def _with_mesh(self, shape):
         from repro.distributed import context
 
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(shape, ("data", "model"))
         context.set_mesh_axes("data", "model", mesh)
         return mesh
 
@@ -388,7 +389,8 @@ class TestMeshGenerationRetrace:
             size0 = hybrid._fused_forward._cache_size()
             clf.predict(x)  # same generation: cache hit
             assert hybrid._fused_forward._cache_size() == size0
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((1, 1), ("data", "model"))
             context.set_mesh_axes("data", "model", mesh)
             p1 = clf.predict(x)
             assert hybrid._fused_forward._cache_size() == size0 + 1
@@ -430,7 +432,8 @@ class TestMeshGenerationRetrace:
             size0 = sched_lib._batched_classify._cache_size()
             tick_once(sched, feats[0])  # same generation: cache hit
             assert sched_lib._batched_classify._cache_size() == size0
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((1, 1), ("data", "model"))
             context.set_mesh_axes("data", "model", mesh)
             out1 = tick_once(sched, feats[0])
             # mesh_gen is a static jit arg: a new mesh keys a fresh trace
@@ -564,7 +567,8 @@ class TestMeshSharded:
                                                            lo, hi)
                 s1 = eng.scores(feats, bank)
 
-                mesh = jax.make_mesh((2, 1), ("data", "model"))
+                from repro.launch.mesh import make_mesh
+                mesh = make_mesh((2, 1), ("data", "model"))
                 context.set_mesh_axes("data", "model", mesh)
                 assert match.dp_axes_in_mesh()[1] == ("data",)
                 pred2, pc2 = eng.classify_features(feats, bank)
@@ -628,7 +632,8 @@ class TestMeshSharded:
             pred1 = clf.predict(x)
             served1 = serve_once()
 
-            mesh = jax.make_mesh((2, 1), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 1), ("data", "model"))
             context.set_mesh_axes("data", "model", mesh)
             pred2 = clf.predict(x)
             served2 = serve_once()

@@ -257,3 +257,12 @@ class TestTuning:
                                cands=[(128, 128, 256), (256, 128, 256)],
                                iters=1, save=False)
         assert best == (256, 128, 256)
+
+    def test_no_candidate_runs_raises(self):
+        def run(block):
+            raise RuntimeError("VMEM OOM")
+
+        with pytest.raises(RuntimeError, match="none of 2 candidate blocks"):
+            tuning.autotune("acam_match", (1, 1, 1), jnp.float32, run,
+                            cands=[(128, 128, 256), (256, 128, 256)],
+                            iters=1, save=False)

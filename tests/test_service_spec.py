@@ -209,7 +209,8 @@ class TestLegacyShims:
         with warnings.catch_warnings():
             warnings.simplefilter("error")
             ACAMService(N, bank_shards=1)  # explicit: intent is clear
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((1, 1), ("data", "model"))
             context.set_mesh_axes("data", "model", mesh)
             ACAMService(N)  # mesh installed: inference is well-defined
 
@@ -508,7 +509,8 @@ class TestForcedMeshControlPlane:
             pe, pce = eng.backend(None).classify_features_keyed(
                 feats, bank, jax.random.PRNGKey(9), bank_shards=2)
 
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             context.set_mesh_axes("data", "model", mesh)
             plan, _ = match.plan_for(batch=b, num_classes=c)
             assert plan.bank_shards == 2, plan
