@@ -54,8 +54,8 @@ and appends its row to an existing ``BENCH_serving.json``; with
 (the CI telemetry-smoke job).
 
 The **telemetry-overhead row** (`telemetry_overhead_bench`) serves one
-identical stream twice — span sampling off / full flight recorder with
-the JSONL sink — asserts preds/margins/escalations are bit-identical
+identical stream twice — no sink and spans off / the JSONL sink and
+every serving span on — asserts preds/margins/escalations are bit-identical
 either way, and records ``telemetry_overhead_pct`` (the tests hold the
 same comparison under 5%).
 
@@ -99,7 +99,7 @@ NUM_CLASSES = 10
 
 def make_spec(slots: int, *, requests: int, backend: str | None = None,
               bank_shards: int = 1, install_mesh: bool = False,
-              telemetry_dir: str | None = None, span_sample: float = 1.0):
+              telemetry_dir: str | None = None):
     """The bench's one `ServiceSpec`: every measurement constructs through
     the spec path (`HybridService.from_spec`), never the legacy keywords.
     Taus ride in explicit match-count units; the service converts to the
@@ -121,8 +121,7 @@ def make_spec(slots: int, *, requests: int, backend: str | None = None,
         scheduler=spec_lib.SchedulerSpec(slots=slots),
         cascade=spec_lib.CascadeSpec(tau=8.0, tau_units="count",
                                      max_queue=max(requests, 4096)),
-        obs=spec_lib.ObsSpec(telemetry_dir=telemetry_dir,
-                             span_sample=span_sample),
+        obs=spec_lib.ObsSpec(telemetry_dir=telemetry_dir),
     )
 
 
@@ -322,8 +321,9 @@ def reshard_bench(*, seed: int = 0, tenants: int = 8, slots: int = 64,
 
 def telemetry_overhead_bench(*, smoke: bool = False, seed: int = 0) -> dict:
     """The flight recorder's tax: serve the IDENTICAL request stream twice
-    — spans sampled out and no JSONL sink, then the full recorder (every
-    request a span, event log on) — and record the per-request overhead.
+    — no JSONL sink and the serving spans off, then the full recorder
+    (event log on, every serving span on) — and record the per-request
+    overhead.
     Doubles as the purity check: preds/margins/escalations must be
     bit-identical either way (telemetry observes, never steers)."""
     import tempfile
@@ -368,10 +368,10 @@ def telemetry_overhead_bench(*, smoke: bool = False, seed: int = 0) -> dict:
     # per arm: a one-sided hiccup (GC pause, slow JSONL flush) lands in
     # one pass of one arm and the median rejects it, where a min would
     # bias low and a mean would smear it in
-    base_svc, base_reqs = build(spec_lib.ObsSpec(span_sample=0.0))
+    base_svc, base_reqs = build(spec_lib.ObsSpec())
     with tempfile.TemporaryDirectory() as td:
         tel_svc, tel_reqs = build(spec_lib.ObsSpec(telemetry_dir=td,
-                                                   span_sample=1.0))
+                                                   profile_annotations=True))
         base_us_all, tel_us_all = [], []
         base_sig = tel_sig = tel_m = None
         for _ in range(9):

@@ -133,10 +133,10 @@ def main():
     # every number above was a view over `svc.obs` (repro.obs): the
     # latency quantiles are exact-from-buckets reads of ONE histogram
     # (the shed check reads the identical value), the energy ledger is
-    # bit-exact with the per-response sum, and span conservation
-    # (started == finished + in-flight) is a structural property.
+    # bit-exact with the per-response sum, and request conservation
+    # (started == finished + in-flight) holds at every quiescent point.
     fleet = svc.obs.ledger.fleet()
-    spans = svc.obs.spans.conservation()
+    spans = svc.obs.conservation()
     assert fleet["total_nj"] == sum(r.energy_j for r in responses) * 1e9
     assert spans["started"] == spans["finished"] + spans["in_flight"]
     print(f"   energy ledger: {fleet['total_nj']:.1f} nJ over "
@@ -144,7 +144,7 @@ def main():
           f"{fleet['backend_share']:.3f}; bit-exact with per-response sum)")
     print(f"   latency p50/p99: {m['latency_p50_ms']:.3f}/"
           f"{m['latency_p99_ms']:.3f} ms (exact from histogram buckets)")
-    print(f"   spans: {spans['started']} started == {spans['finished']} "
+    print(f"   requests: {spans['started']} started == {spans['finished']} "
           f"finished + {spans['in_flight']} in-flight "
           f"(dispositions {spans['by_disposition']})")
 

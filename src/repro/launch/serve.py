@@ -72,7 +72,6 @@ def build_acam_spec(args):
                                      deadline_ms=args.deadline_ms,
                                      shed_queue=args.shed_queue),
         obs=spec_lib.ObsSpec(telemetry_dir=args.telemetry_dir,
-                             span_sample=args.span_sample,
                              profile_annotations=args.profile_annotations),
     )
 
@@ -373,12 +372,12 @@ def main(argv=None) -> dict:
                          "(DIR/events.jsonl, one line per serving tick + "
                          "every lifecycle event) and write a Prometheus "
                          "scrape file (DIR/metrics.prom) after serving")
-    ap.add_argument("--span-sample", type=float, default=1.0,
-                    help="fraction of requests carrying a full per-request "
-                         "span (deterministic in the request id)")
     ap.add_argument("--profile-annotations", action="store_true",
-                    help="wrap the fused dispatch in a jax.profiler "
-                         "TraceAnnotation (visible in device traces)")
+                    help="turn on every serving span: a jax.profiler "
+                         "TraceAnnotation at each layer boundary of the "
+                         "served path (step, tick, pack, dispatch, launch, "
+                         "fetch, escalate, finalize), visible in device "
+                         "traces")
     ap.add_argument("--device-noise", default="global",
                     choices=("global", "per_shard"),
                     help="sigma_program noise semantics for the device "

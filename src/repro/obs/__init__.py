@@ -5,8 +5,9 @@ itself. Four pieces, one facade:
 
   * `MetricsRegistry` (`.registry`) — labeled counters/gauges +
     fixed-bucket latency histograms with exact-from-buckets quantiles.
-  * `SpanRecorder` (`.spans`) — per-request flight records across
-    admission -> queue -> tick -> dispatch -> cascade -> response.
+  * serving spans (`FlightRecorder.span`) — `jax.profiler` trace
+    annotations at every layer boundary of the served path, on the
+    device trace's clock; off unless `ObsSpec.profile_annotations`.
   * `EnergyLedger` (`.energy`) — per-tenant + fleet SS V-D nJ totals
     with the E_backend / E_frontend split, bit-exact with the
     per-response attributions.
@@ -28,15 +29,14 @@ Reset contract (`FlightRecorder.reset`, behind
   surviving  gauges (queue depth, shed mode, straggler strikes), the
              histogram's ROLLING window (the shed_p99_ms overload
              signal — a metrics reset must never blind load shedding),
-             span conservation totals (started == finished + in-flight
-             is a structural invariant, not a per-run statistic), the
+             the request conservation totals (started == finished +
+             in-flight is an invariant, not a per-run statistic), the
              tick-id sequence, and the event log (append-only).
 """
 from __future__ import annotations
 
 import contextlib
 import os
-import time
 from collections import deque
 
 from .energy import NJ, EnergyLedger
@@ -45,15 +45,21 @@ from .export import (EVENT_SCHEMA, JsonlEventLog, read_events,
                      validate_prometheus_text, write_prometheus)
 from .registry import (DEFAULT_LATENCY_BUCKETS_MS, Counter, Gauge,
                        Histogram, MetricsRegistry)
-from .spans import DISPOSITIONS, Span, SpanRecorder
 
 __all__ = [
     "FlightRecorder", "MetricsRegistry", "Counter", "Gauge", "Histogram",
-    "SpanRecorder", "Span", "EnergyLedger", "JsonlEventLog", "read_events",
-    "render_prometheus", "validate_prometheus_text", "write_prometheus",
-    "validate_event", "EVENT_SCHEMA", "DISPOSITIONS",
-    "DEFAULT_LATENCY_BUCKETS_MS", "NJ",
+    "EnergyLedger", "JsonlEventLog", "read_events", "render_prometheus",
+    "validate_prometheus_text", "write_prometheus", "validate_event",
+    "EVENT_SCHEMA", "DISPOSITIONS", "NO_SPAN", "DEFAULT_LATENCY_BUCKETS_MS",
+    "NJ",
 ]
+
+#: terminal dispositions a request can leave the service with
+DISPOSITIONS = ("ok", "escalated", "shed", "expired", "error", "rejected")
+
+#: what `FlightRecorder.span` returns with annotations off: one shared
+#: context manager that does nothing, so an unannotated span costs a call
+NO_SPAN = contextlib.nullcontext()
 
 
 class FlightRecorder:
@@ -61,35 +67,47 @@ class FlightRecorder:
 
     Built from an `ObsSpec` (`repro.serve.spec`); a default-constructed
     recorder (no spec) records in memory with no event log — telemetry
-    is always *on*, the spec only controls buckets, sampling, the
-    JSONL sink, and profiler annotations.
+    is always *on*, the spec only controls buckets, the JSONL sink, and
+    the serving spans (`span`).
     """
 
     def __init__(self, obs=None):
         buckets = DEFAULT_LATENCY_BUCKETS_MS
         window = 256
-        sample = 1.0
         telemetry_dir = None
         self.profile_annotations = False
         if obs is not None:
             # () in the spec means "the default bucket ladder"
             buckets = tuple(obs.latency_buckets_ms) or buckets
             window = obs.latency_window
-            sample = obs.span_sample
             telemetry_dir = obs.telemetry_dir
             self.profile_annotations = obs.profile_annotations
+        self._annotation = None
+        if self.profile_annotations:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
         self.registry = MetricsRegistry()
-        self.spans = SpanRecorder(sample_rate=sample)
         self.ledger = EnergyLedger()
         self.events = JsonlEventLog(
             os.path.join(telemetry_dir, "events.jsonl")
             if telemetry_dir else None)
         self.tick_seq = 0
+        #: request conservation: admitted and closed totals (and closed by
+        #: disposition). Unlike the counters they survive `reset()`, so
+        #: started == finished + in-flight holds at every quiescent point.
+        self.started_total = 0
+        self.finished_total = 0
+        self.by_disposition: dict[str, int] = {}
 
         r = self.registry
         self.latency = r.histogram(
             "acam_request_latency_ms",
             "submit -> response wall time of error-free responses (ms)",
+            buckets=buckets, window=window)
+        self.queue_wait = r.histogram(
+            "acam_queue_wait_ms",
+            "submit -> tick pickup wait of every dequeued request (ms)",
             buckets=buckets, window=window)
         self.submitted = r.counter(
             "acam_requests_submitted_total", "requests admitted to the queue")
@@ -172,31 +190,33 @@ class FlightRecorder:
 
     # -- admission ---------------------------------------------------------
 
-    def record_submit(self, request_id: int, tenant_id: str,
-                      t_admit: float) -> None:
+    def record_submit(self) -> None:
         self.submitted.inc()
-        self.spans.start(request_id, tenant_id, t_admit)
+        self.started_total += 1
 
     def record_rejected(self) -> None:
         self.rejected.inc()
 
     # -- scheduler hooks ---------------------------------------------------
 
-    def record_tick_dispatch(self, request_ids, fill: int, dt_s: float,
-                             slow: bool, t_dequeue: float) -> int:
-        """One fused dispatch happened: allocate the tick id, stamp every
-        batched span with it (batch-level — one clock read for the whole
-        tick, not one per request), and feed the scheduler counters."""
+    def next_tick_id(self) -> int:
+        """Allocate the id of the tick that starts now (its ``sched.tick``
+        span and its event-log line carry it)."""
         tick_id = self.tick_seq
         self.tick_seq += 1
-        dt_ms = dt_s * 1e3
-        self.last_dispatch_ms = dt_ms
-        for rid in request_ids:
-            span = self.spans.active.get(rid)
-            if span is not None:
-                span.t_dequeue = t_dequeue
-                span.tick_id = tick_id
-                span.dispatch_ms = dt_ms
+        return tick_id
+
+    def record_queue_wait(self, items, t_dequeue: float) -> None:
+        """Observe the queue wait of every request a tick dequeued: one
+        clock read (the tick's start) serves the whole batch."""
+        observe = self.queue_wait.observe
+        for item in items:
+            observe((t_dequeue - item.submit_t) * 1e3)
+
+    def record_tick_dispatch(self, fill: int, dt_s: float,
+                             slow: bool) -> None:
+        """One fused dispatch happened: feed the scheduler counters."""
+        self.last_dispatch_ms = dt_s * 1e3
         self.ticks.inc()
         self.dispatches.inc()
         self.served.inc(fill)
@@ -206,7 +226,6 @@ class FlightRecorder:
         self.fill_min.set_min(fill)
         self.fill_max.set_max(fill)
         self.fill_window.append(fill)
-        return tick_id
 
     def record_expired(self, n: int) -> None:
         self.expired.inc(n)
@@ -218,14 +237,15 @@ class FlightRecorder:
         for host, strikes in flagged.items():
             self.straggler_strikes.set(strikes, host=host)
 
-    def profile_span(self, name: str):
-        """Context manager annotating the fused dispatch in `jax.profiler`
-        traces (no-op unless `ObsSpec.profile_annotations`)."""
-        if not self.profile_annotations:
-            return contextlib.nullcontext()
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
+    def span(self, name: str, **stats):
+        """A serving span: ``jax.profiler.TraceAnnotation(name, **stats)``
+        with `ObsSpec.profile_annotations` on, so it lies on the device
+        trace's clock and its stats land in the trace as event stats; else
+        the shared `NO_SPAN`, which builds and formats nothing. The span
+        names are stable: the benchmark's per-layer metrics read them."""
+        if self._annotation is None:
+            return NO_SPAN
+        return self._annotation(name, **stats)
 
     # -- cascade / response ------------------------------------------------
 
@@ -256,7 +276,7 @@ class FlightRecorder:
         """Close one request: disposition counter, latency observation
         (error-free responses only — expired/evicted latencies measure
         the queue deadline, not service), energy ledger + stage counters,
-        and the span."""
+        and the conservation totals."""
         if resp.error is not None:
             disposition = "expired" if "deadline" in resp.error else "error"
         elif resp.shed:
@@ -276,8 +296,28 @@ class FlightRecorder:
         if frontend_j:
             self.energy.inc(frontend_j, stage="frontend",
                             tenant=resp.tenant_id)
-        self.spans.finish(resp.request_id, disposition,
-                          escalated=resp.escalated)
+        self.record_finish(disposition)
+
+    def record_finish(self, disposition: str) -> None:
+        """Close one request in the conservation totals; a disposition
+        outside `DISPOSITIONS` is refused."""
+        if disposition not in DISPOSITIONS:
+            raise ValueError(f"unknown disposition {disposition!r}; "
+                             f"expected one of {DISPOSITIONS}")
+        self.finished_total += 1
+        self.by_disposition[disposition] = \
+            self.by_disposition.get(disposition, 0) + 1
+
+    def conservation(self) -> dict:
+        """started == finished + in-flight: every admitted request is
+        closed exactly once, whatever its disposition (the burst and
+        deadline tests hold the service to it)."""
+        return {
+            "started": self.started_total,
+            "finished": self.finished_total,
+            "in_flight": self.started_total - self.finished_total,
+            "by_disposition": dict(self.by_disposition),
+        }
 
     def add_busy(self, seconds: float) -> None:
         self.busy_seconds.inc(seconds)

@@ -25,11 +25,19 @@ Turns the fused Pallas classify kernel into a service tier:
 
 Every number the service reports lives in its `repro.obs.FlightRecorder`
 (`self.obs`): `metrics()` and `health()` are thin reads over its metric
-registry, per-request spans travel admission -> tick -> response through
-it, the SS V-D energy ledger aggregates there, and — when the spec sets
+registry, every admitted request is counted in and out of its conservation
+totals, the SS V-D energy ledger aggregates there, and — when the spec sets
 `obs.telemetry_dir` — a JSONL event log records every tick and lifecycle
 event. The shed_p99_ms overload check reads the SAME histogram quantile
 `metrics()` reports (one source of truth, not three reservoirs).
+
+With `obs.profile_annotations` on, a step is traced in the recorder's spans
+(`FlightRecorder.span`): ``acam.step`` around the whole step, holding the
+scheduler's ``sched.tick`` (`repro.serve.scheduler`), ``acam.escalate``
+(the escalation's pack, head tables, dispatch and copy-back; stats ``n``
+escalated and ``bucket``) and ``acam.finalize`` (the per-request
+accounting). What ``acam.step`` holds outside them is the cascade loop and
+the building of the responses.
 
 Escalated slots from one tick are themselves coalesced into one dense-head
 dispatch (padded to power-of-two buckets so the escalation path compiles a
@@ -193,9 +201,9 @@ class ACAMService:
         `HybridService.from_spec`.)"""
         spec.validate()
         self.spec = spec
-        #: the flight recorder: metric registry + span recorder + energy
-        #: ledger + event log. Lives as long as the service (reconfigure
-        #: rebuilds schedulers, never this).
+        #: the flight recorder: metric registry + conservation totals +
+        #: energy ledger + event log + serving spans. Lives as long as the
+        #: service (reconfigure rebuilds schedulers, never this).
         self.obs = FlightRecorder(spec.obs)
         self.registry = TemplateBankRegistry(
             spec.registry.num_features, k_max=spec.registry.k_max,
@@ -362,7 +370,8 @@ class ACAMService:
 
     def submit(self, request: ClassifyRequest) -> int:
         """Admit one request into the scheduler queue; returns request id.
-        Admission opens the request's span (`repro.obs.SpanRecorder`)."""
+        Admission counts the request into the recorder's conservation
+        totals."""
         if request.tenant_id not in self.registry:
             self.obs.record_rejected()
             raise AdmissionError(f"unknown tenant {request.tenant_id!r}")
@@ -381,7 +390,7 @@ class ACAMService:
         self.scheduler.submit(WorkItem(
             request_id=self._next_id, tenant_id=request.tenant_id,
             features=feats, submit_t=t_admit))
-        self.obs.record_submit(self._next_id, request.tenant_id, t_admit)
+        self.obs.record_submit()
         self.obs.set_queue_depth(self.scheduler.qsize)
         return self._next_id
 
@@ -416,6 +425,10 @@ class ACAMService:
         gracefully — every slot is answered from the ACAM stage alone
         (``shed=True`` where the margin asked for escalation) instead of
         queueing CNN head work behind a growing backlog."""
+        with self.obs.span("acam.step"):
+            return self._step()
+
+    def _step(self) -> list[ClassifyResponse]:
         t0 = time.perf_counter()
         responses: list[ClassifyResponse] = []
         casc = self.spec.cascade
@@ -494,41 +507,43 @@ class ACAMService:
                        dispatched: bool, escalation: bool,
                        now: float | None = None,
                        frontend: dict[int, float] | None = None) -> None:
-        """Book one step into the flight recorder: close every response's
-        span (disposition + latency + SS V-D energy split), bump the busy
-        clock and queue gauge, and — when the event log is on — append the
+        """Book one step into the flight recorder: close every response
+        (disposition + latency + SS V-D energy split), bump the busy clock
+        and queue gauge, and — when the event log is on — append the
         step's "tick" line. Pure accounting: preds/margins/escalations are
         already fixed by the time this runs, so telemetry can never change
         a served answer."""
         obs = self.obs
-        if escalation:
-            obs.record_escalation_dispatch()
-        for r in responses:
-            if r.error is not None:
-                obs.finish_request(r, 0.0, 0.0)
-            else:
-                rt = self._tenants[r.tenant_id]
-                if frontend is not None:
-                    fj = frontend.get(r.request_id, 0.0)
+        with obs.span("acam.finalize"):
+            if escalation:
+                obs.record_escalation_dispatch()
+            for r in responses:
+                if r.error is not None:
+                    obs.finish_request(r, 0.0, 0.0)
                 else:
-                    fj = self._frontend_j if r.escalated else 0.0
-                obs.finish_request(r, rt.backend_j, fj)
-        now = time.perf_counter() if now is None else now
-        obs.add_busy(now - t0)
-        obs.set_queue_depth(self.scheduler.qsize)
-        if obs.events.enabled:
-            obs.emit(
-                "tick",
-                tick_id=obs.tick_seq - 1 if dispatched else -1,
-                fill=fill,
-                served=sum(r.error is None for r in responses),
-                escalated=sum(r.escalated for r in responses),
-                shed=sum(r.shed for r in responses),
-                expired=n_expired,
-                dt_ms=round(obs.last_dispatch_ms, 4) if dispatched else 0.0,
-                queue_depth=self.scheduler.qsize,
-                shed_mode=int(shedding),
-                energy_j=sum(r.energy_j for r in responses))
+                    rt = self._tenants[r.tenant_id]
+                    if frontend is not None:
+                        fj = frontend.get(r.request_id, 0.0)
+                    else:
+                        fj = self._frontend_j if r.escalated else 0.0
+                    obs.finish_request(r, rt.backend_j, fj)
+            now = time.perf_counter() if now is None else now
+            obs.add_busy(now - t0)
+            obs.set_queue_depth(self.scheduler.qsize)
+            if obs.events.enabled:
+                obs.emit(
+                    "tick",
+                    tick_id=obs.tick_seq - 1 if dispatched else -1,
+                    fill=fill,
+                    served=sum(r.error is None for r in responses),
+                    escalated=sum(r.escalated for r in responses),
+                    shed=sum(r.shed for r in responses),
+                    expired=n_expired,
+                    dt_ms=(round(obs.last_dispatch_ms, 4)
+                           if dispatched else 0.0),
+                    queue_depth=self.scheduler.qsize,
+                    shed_mode=int(shedding),
+                    energy_j=sum(r.energy_j for r in responses))
 
     def _wants_escalation(self, r: SlotResult) -> bool:
         """Routing-policy hook: should this served slot escalate to the
@@ -547,22 +562,23 @@ class ACAMService:
         return self._frontend_j
 
     def _run_escalation(self, escalate: list[SlotResult]) -> dict[int, int]:
-        """Coalesce a tick's escalated slots into one dense-head dispatch."""
-        n = self.registry.num_features
+        """Coalesce a tick's escalated slots into one dense-head dispatch
+        (span ``acam.escalate``)."""
         size = _bucket(len(escalate), self.config.slots)
-        feats = np.zeros((size, n), np.float32)
-        slot = np.zeros((size,), np.int32)
-        ncls = np.ones((size,), np.int32)
-        for i, r in enumerate(escalate):
-            feats[i] = r.item.features
-            slot[i] = r.entry.slot
-            ncls[i] = r.entry.num_classes
-        w_table, b_table = self._head_tables()
-        pred = np.asarray(_escalate_heads(
-            w_table, b_table, jnp.asarray(feats), jnp.asarray(slot),
-            jnp.asarray(ncls)))
-        return {r.item.request_id: int(pred[i])
-                for i, r in enumerate(escalate)}
+        with self.obs.span("acam.escalate", n=len(escalate), bucket=size):
+            feats = np.zeros((size, self.registry.num_features), np.float32)
+            slot = np.zeros((size,), np.int32)
+            ncls = np.ones((size,), np.int32)
+            for i, r in enumerate(escalate):
+                feats[i] = r.item.features
+                slot[i] = r.entry.slot
+                ncls[i] = r.entry.num_classes
+            w_table, b_table = self._head_tables()
+            pred = np.asarray(_escalate_heads(
+                w_table, b_table, jnp.asarray(feats), jnp.asarray(slot),
+                jnp.asarray(ncls)))
+            return {r.item.request_id: int(pred[i])
+                    for i, r in enumerate(escalate)}
 
     def drain(self) -> list[ClassifyResponse]:
         """Run ticks until the queue empties (the control plane's quiesce
@@ -683,8 +699,8 @@ class ACAMService:
                    they describe the service NOW), the latency
                    histogram's ROLLING window (the shed_p99_ms overload
                    signal: a metrics reset must never blind load
-                   shedding), span conservation totals, in-flight spans,
-                   the tick-id sequence, straggler-monitor history, and
+                   shedding), the request conservation totals (started,
+                   finished, in flight), the tick-id sequence, straggler-monitor history, and
                    the append-only event log."""
         from repro.serve.scheduler import SchedulerStats
 

@@ -36,10 +36,17 @@ layer (`repro.serve.acam_service`) still owns the routing itself. It does own tw
 `repro.ft.elastic.StragglerMonitor` — slow-tick strikes are surfaced
 through `SchedulerStats.slow_ticks` / `last_verdict` so the control plane
 can shed load or shrink the mesh before latency collapses.
+
+With `ObsSpec.profile_annotations` on, every tick is traced in spans of the
+flight recorder (`FlightRecorder.span`): ``sched.tick`` around the tick
+(stats ``tick_id`` and ``fill``, the requests dequeued), holding
+``sched.pack`` (pop, placement lookup, numpy operands) and
+``acam_fused_dispatch``, which holds ``sched.launch`` (the uploads and the
+jitted call) and ``sched.fetch`` (the wait for the device and the four
+copies back).
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -52,6 +59,7 @@ import numpy as np
 
 from repro import match as match_lib
 from repro.ft.elastic import StragglerMonitor
+from repro.obs import FlightRecorder
 from repro.serve.registry import TemplateBankRegistry, TenantEntry
 
 
@@ -197,11 +205,12 @@ class MicroBatchScheduler:
         self.monitor = monitor if monitor is not None else StragglerMonitor(
             n_hosts=1)
         self.last_verdict: dict | None = None
-        #: optional `repro.obs.FlightRecorder`: the scheduler stamps every
-        #: dispatched request's span with the tick id / dequeue time and
-        #: feeds the registry's scheduler counters. `SchedulerStats` stays
-        #: as a plain in-object mirror (cheap, and directly inspectable).
-        self.recorder = recorder
+        #: the `repro.obs.FlightRecorder` (the service's; a private one when
+        #: none is given): tick ids, queue waits, the registry's scheduler
+        #: counters and the tick's spans. `SchedulerStats` stays as a plain
+        #: in-object mirror (cheap, and directly inspectable).
+        self.recorder = recorder if recorder is not None \
+            else FlightRecorder()
         #: optional tenant_id -> margin threshold (float | None). Installed
         #: by the service layer; feeds the per-slot ``tau`` operand so the
         #: cascade's ``margin < tau`` compare runs inside the serve kernel.
@@ -242,7 +251,7 @@ class MicroBatchScheduler:
         while self._queue and now - self._queue[0].submit_t > deadline_s:
             out.append(self._queue.popleft())
         self.stats.expired += len(out)
-        if out and self.recorder is not None:
+        if out:
             self.recorder.record_expired(len(out))
         return out
 
@@ -258,9 +267,52 @@ class MicroBatchScheduler:
         bank flip needs no drain."""
         if not self._queue:
             return []
+        obs = self.recorder
         t0 = time.perf_counter()
-        popped = [self._queue.popleft()
-                  for _ in range(min(self.slots, len(self._queue)))]
+        n = min(self.slots, len(self._queue))
+        with obs.span("sched.tick", tick_id=obs.next_tick_id(), fill=n):
+            with obs.span("sched.pack"):
+                dead, batch, operands = self._pack(n, t0)
+            if not batch:
+                return dead
+            from repro.distributed import context
+
+            cfg = self.engine_config._replace(
+                backend=self.backend or match_lib.default_backend())
+            with obs.span("acam_fused_dispatch"):
+                with obs.span("sched.launch"):
+                    out = _batched_classify(
+                        self.registry.device_bank(),
+                        self.registry.thresholds_table(),
+                        *(jnp.asarray(a) for a in operands), config=cfg,
+                        mesh_gen=context.generation())
+                with obs.span("sched.fetch"):
+                    pred, per_class, margin, esc = (np.asarray(a)
+                                                    for a in out)
+            dt = time.perf_counter() - t0
+            self.last_verdict = self.monitor.observe(0, dt)
+            slow = bool(self.last_verdict["stragglers"])
+            self.stats.record_tick(len(batch), dt_s=dt, slow=slow)
+            obs.record_tick_dispatch(len(batch), dt, slow)
+
+            # winner's absolute score: per_class is logically (slots, C_cap)
+            # under every plan, so per_class[i, pred[i]] is uniform. An
+            # empty window's pred is 0 and its score -inf; clamp to 0.0.
+            score = per_class[np.arange(len(batch)), pred[:len(batch)]]
+            score = np.where(np.isfinite(score), score, 0.0)
+            return dead + [
+                SlotResult(item=item, entry=entry,
+                           pred_local=int(pred[i]) - entry.offset,
+                           margin=float(margin[i]), escalate=bool(esc[i]),
+                           score=float(score[i]))
+                for i, (item, entry) in enumerate(batch)]
+
+    def _pack(self, n: int, t0: float):
+        """Pop ``n`` requests and build the tick's numpy operands. ->
+        (results of requests whose tenant is gone, [(item, entry)] to
+        serve, (feats, tenant slot, window lo, window hi, tau))."""
+        popped = [self._queue.popleft() for _ in range(n)]
+        self.recorder.record_queue_wait(popped, t0)
         # resolve placements NOW: queued requests must see the tenant's
         # current class window, not the one from submit time
         dead = []
@@ -274,10 +326,9 @@ class MicroBatchScheduler:
             else:
                 batch.append((item, entry))
         if not batch:
-            return dead
-        n = self.registry.num_features
-
-        feats = np.zeros((self.slots, n), np.float32)
+            return dead, batch, ()
+        feats = np.zeros((self.slots, self.registry.num_features),
+                         np.float32)
         slot_idx = np.zeros((self.slots,), np.int32)
         lo = np.zeros((self.slots,), np.int32)
         hi = np.zeros((self.slots,), np.int32)  # padding: empty window [0, 0)
@@ -290,44 +341,7 @@ class MicroBatchScheduler:
                 t = self.tau_fn(item.tenant_id)
                 if t is not None:
                     tau[i] = t
-
-        from repro.distributed import context
-
-        cfg = self.engine_config._replace(
-            backend=self.backend or match_lib.default_backend())
-        annotate = self.recorder.profile_span("acam_fused_dispatch") \
-            if self.recorder is not None else contextlib.nullcontext()
-        with annotate:
-            pred, per_class, margin, esc = _batched_classify(
-                self.registry.device_bank(),
-                self.registry.thresholds_table(),
-                jnp.asarray(feats), jnp.asarray(slot_idx), jnp.asarray(lo),
-                jnp.asarray(hi), jnp.asarray(tau), config=cfg,
-                mesh_gen=context.generation())
-            pred = np.asarray(pred)
-            per_class = np.asarray(per_class)  # logically (slots, C_cap)
-            margin = np.asarray(margin)
-            esc = np.asarray(esc)
-        dt = time.perf_counter() - t0
-        self.last_verdict = self.monitor.observe(0, dt)
-        slow = bool(self.last_verdict["stragglers"])
-        self.stats.record_tick(len(batch), dt_s=dt, slow=slow)
-        if self.recorder is not None:
-            self.recorder.record_tick_dispatch(
-                [item.request_id for item in popped], len(batch), dt, slow,
-                t0)
-
-        # winner's absolute score: per_class is logically (slots, C_cap)
-        # under every plan, so per_class[i, pred[i]] is uniform. An empty
-        # window's pred is 0 and its score -inf; clamp to 0.0 (no match).
-        score = per_class[np.arange(len(batch)), pred[:len(batch)]]
-        score = np.where(np.isfinite(score), score, 0.0)
-        return dead + [
-            SlotResult(item=item, entry=entry,
-                       pred_local=int(pred[i]) - entry.offset,
-                       margin=float(margin[i]), escalate=bool(esc[i]),
-                       score=float(score[i]))
-            for i, (item, entry) in enumerate(batch)]
+        return dead, batch, (feats, slot_idx, lo, hi, tau)
 
     def drain(self) -> list[SlotResult]:
         out: list[SlotResult] = []

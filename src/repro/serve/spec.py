@@ -169,7 +169,7 @@ class ObsSpec(NamedTuple):
     Telemetry is always on — the recorder is how `metrics()`/`health()`
     and the overload policy see anything at all — so this spec only
     shapes it: histogram resolution, the rolling-window length behind
-    the shed_p99_ms signal, span sampling, and the optional sinks.
+    the shed_p99_ms signal, and the optional sinks.
 
     ``latency_buckets_ms``  upper bounds (ms) of the request-latency
                             histogram; quantiles are exact from these
@@ -181,17 +181,19 @@ class ObsSpec(NamedTuple):
                             log (`events.jsonl`: one line per serving
                             tick + every lifecycle event) under this
                             directory. None: no event log.
-    ``span_sample``         fraction of requests carrying a full span
-                            (deterministic in the request id); span
-                            *conservation counters* always run.
-    ``profile_annotations`` wrap the fused dispatch in a
-                            `jax.profiler.TraceAnnotation` so device
-                            traces show serving-tick boundaries."""
+    ``profile_annotations`` turn on every serving span
+                            (`FlightRecorder.span`): a
+                            `jax.profiler.TraceAnnotation` at each layer
+                            boundary of the served path (``acam.step``,
+                            ``sched.tick``, ``sched.pack``,
+                            ``acam_fused_dispatch``, ``sched.launch``,
+                            ``sched.fetch``, ``acam.escalate``,
+                            ``acam.finalize``), on the device trace's
+                            clock. Off: each span is a shared no-op."""
 
     latency_buckets_ms: tuple = ()  # () -> repro.obs default buckets
     latency_window: int = 256
     telemetry_dir: str | None = None
-    span_sample: float = 1.0
     profile_annotations: bool = False
 
 
@@ -303,9 +305,6 @@ class ServiceSpec(NamedTuple):
         if obs.latency_window < 1:
             raise ValueError(f"latency_window must be >= 1, got "
                              f"{obs.latency_window}")
-        if not 0.0 <= obs.span_sample <= 1.0:
-            raise ValueError(f"span_sample must be in [0, 1], got "
-                             f"{obs.span_sample}")
         dev = self.engine.device or ACAMConfig()
         if (self.engine.backend == "device" and mesh.bank_shards > 1
                 and dev.sigma_program > 0.0
@@ -366,7 +365,10 @@ class ServiceSpec(NamedTuple):
             eng["block"] = tuple(int(b) for b in eng["block"])
         if eng.get("device") is not None:
             eng["device"] = ACAMConfig(**eng["device"])
-        obs = dict(d.get("obs", {}))
+        # telemetry knobs never change an answer: one this build does not
+        # know (written by an older or newer one) is dropped, not refused
+        obs = {k: v for k, v in d.get("obs", {}).items()
+               if k in ObsSpec._fields}
         if "latency_buckets_ms" in obs:
             obs["latency_buckets_ms"] = tuple(
                 float(x) for x in obs["latency_buckets_ms"])

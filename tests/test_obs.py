@@ -5,18 +5,21 @@ Coverage layers:
   * registry primitives: labeled counters/gauges, the dual-view histogram
     (exact-from-buckets quantiles, cumulative-clears vs window-survives),
     the label-cardinality guard, kind-conflict detection;
-  * span ledger: open-once/close-once conservation as a structural
-    property, deterministic request-id sampling;
+  * request conservation (started == finished + in-flight) and the
+    serving spans: a shared no-op with annotations off, and with them on
+    every span of the served path, nested, in a CPU profiler trace;
   * energy ledger: fleet and per-tenant totals BIT-EXACT (`==`, not
     approx) with the left-fold sum over per-response attributions;
   * exporters: JSONL schema round-trip, torn-final-line tolerance,
     Prometheus exposition rendering + duplicate/cardinality validation;
-  * service integration over the bursty trace harness: span counts ==
-    request counts across every disposition (ok/escalated/shed/expired),
-    `reset_metrics()` exact clear/survive semantics, tick events
-    reconcile with the registry, and telemetry on-vs-off serves
-    bit-identical results with <5% latency overhead.
+  * service integration over the bursty trace harness: conservation
+    totals == request counts across every disposition (ok/escalated/
+    shed/expired), the queue-wait histogram, `reset_metrics()` exact
+    clear/survive semantics, tick events reconcile with the registry,
+    and telemetry on-vs-off serves bit-identical results with <5%
+    latency overhead.
 """
+import glob
 import json
 import os
 import sys
@@ -26,12 +29,11 @@ import numpy as np
 import pytest
 
 from repro.match.config import EngineConfig
-from repro.obs import (DEFAULT_LATENCY_BUCKETS_MS, EnergyLedger,
+from repro.obs import (DEFAULT_LATENCY_BUCKETS_MS, NO_SPAN, EnergyLedger,
                        FlightRecorder, JsonlEventLog, MetricsRegistry,
                        read_events, validate_event,
                        validate_prometheus_text)
 from repro.obs.registry import MAX_LABEL_SETS, Histogram
-from repro.obs.spans import SpanRecorder, sampled
 from repro.serve import acam_service as svc_lib
 from repro.serve import spec as spec_lib
 from repro.serve.acam_service import AdmissionError, ClassifyRequest
@@ -178,40 +180,125 @@ class TestRegistry:
 # Spans
 # ---------------------------------------------------------------------------
 
+#: each serving span and the span it nests in (`FlightRecorder.span`)
+SPAN_PARENT = {
+    "acam.step": None,
+    "sched.tick": "acam.step",
+    "sched.pack": "sched.tick",
+    "acam_fused_dispatch": "sched.tick",
+    "sched.launch": "acam_fused_dispatch",
+    "sched.fetch": "acam_fused_dispatch",
+    "acam.escalate": "acam.step",
+    "acam.finalize": "acam.step",
+}
+
+
+def _response(disposition: str) -> svc_lib.ClassifyResponse:
+    return svc_lib.ClassifyResponse(
+        request_id=1, tenant_id="t0", pred=0, margin=1.0,
+        escalated=disposition == "escalated", energy_j=0.0, latency_s=1e-3,
+        error="deadline exceeded" if disposition == "expired" else None,
+        shed=disposition == "shed")
+
+
+def _host_spans(log_dir) -> list:
+    """[(start_ns, end_ns, name, stats)] of the serving spans in the
+    newest profiler trace under ``log_dir``, in start order."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            out.extend((e.start_ns, e.start_ns + e.duration_ns, e.name,
+                        dict(e.stats)) for e in line.events
+                       if e.name in SPAN_PARENT)
+    return sorted(out, key=lambda ev: ev[0])
+
+
 class TestSpans:
     def test_open_close_conservation(self):
-        rec = SpanRecorder()
-        rec.start(1, "t0", 10.0)
-        rec.start(2, "t0", 10.0)
-        rec.dequeue(1, tick_id=0, t_dequeue=10.5)
-        span = rec.finish(1, "ok", t_done=11.0)
-        assert span.tick_id == 0
-        assert span.queue_ms == pytest.approx(500.0)
-        assert span.service_ms == pytest.approx(500.0)
+        rec = FlightRecorder()
+        rec.record_submit()
+        rec.record_submit()
+        rec.finish_request(_response("ok"), 1e-9, 0.0)
         c = rec.conservation()
         assert c["started"] == 2 and c["finished"] == 1
         assert c["in_flight"] == 1
         assert c["by_disposition"] == {"ok": 1}
-        # a finish pops: the same id cannot close a span twice
-        assert rec.finish(1, "ok") is None
+        assert rec.latency.count == 1
+        rec.finish_request(_response("expired"), 0.0, 0.0)
+        c = rec.conservation()
+        assert c["finished"] == 2 and c["in_flight"] == 0
+        assert c["by_disposition"] == {"ok": 1, "expired": 1}
+        assert rec.latency.count == 1, "expired latency is not service"
 
     def test_unknown_disposition_rejected(self):
+        rec = FlightRecorder()
         with pytest.raises(ValueError, match="disposition"):
-            SpanRecorder().finish(1, "vanished")
+            rec.record_finish("vanished")
+        assert rec.conservation()["finished"] == 0
 
-    def test_sampling_deterministic(self):
-        verdicts = [sampled(i, 0.5) for i in range(512)]
-        assert verdicts == [sampled(i, 0.5) for i in range(512)]
-        assert 0.3 < np.mean(verdicts) < 0.7
-        assert all(sampled(i, 1.0) for i in range(64))
-        assert not any(sampled(i, 0.0) for i in range(64))
+    def test_off_span_is_shared_noop_and_builds_nothing(self, monkeypatch):
+        import jax
 
-    def test_sampled_out_still_counts(self):
-        rec = SpanRecorder(sample_rate=0.0)
-        assert rec.start(7, "t0") is None
-        rec.finish(7, "ok")
-        c = rec.conservation()
-        assert c["started"] == c["finished"] == 1 and c["in_flight"] == 0
+        def refuse(*args, **kwargs):
+            raise AssertionError("TraceAnnotation built with spans off")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        svc, protos = _boot(_spec())
+        assert not svc.obs.profile_annotations
+        assert svc.obs.span("acam.step") is NO_SPAN
+        assert svc.obs.span("sched.tick", tick_id=0, fill=1) is NO_SPAN
+        responses = svc.serve(_mixed_requests(protos, per_tenant=4))
+        assert len(responses) == 4 * N_TENANTS
+        assert svc.metrics()["escalated"] > 0, "escalate span path ran"
+
+    def test_traced_service_emits_nested_serving_spans(self, tmp_path):
+        import jax
+
+        svc, protos = _boot(_spec(obs=spec_lib.ObsSpec(
+            profile_annotations=True, telemetry_dir=str(tmp_path))))
+        reqs = _mixed_requests(protos)
+        svc.serve(reqs)  # compiles the tick and the escalation buckets
+        svc.reset_metrics()
+        seq0 = svc.obs.tick_seq
+        jax.profiler.start_trace(str(tmp_path / "trace"))
+        try:
+            responses = svc.serve(reqs)
+        finally:
+            jax.profiler.stop_trace()
+        m = svc.metrics()
+        spans = _host_spans(tmp_path / "trace")
+        by_name = {n: [ev for ev in spans if ev[2] == n]
+                   for n in SPAN_PARENT}
+        # one sched.tick per tick, one acam.step and acam.finalize per step
+        ticks = by_name["sched.tick"]
+        assert len(ticks) == m["ticks"] == len(by_name["acam.step"]) > 1
+        for name in ("sched.pack", "acam_fused_dispatch", "sched.launch",
+                     "sched.fetch", "acam.finalize"):
+            assert len(by_name[name]) == len(ticks), name
+        # the tick's stats are the registry's tick ids and fills, and the
+        # event log's tick lines carry the same ids
+        assert [ev[3]["tick_id"] for ev in ticks] == \
+            list(range(seq0, svc.obs.tick_seq))
+        logged = [e["tick_id"] for e in read_events(tmp_path / "events.jsonl")
+                  if e["kind"] == "tick"]
+        assert logged[-len(ticks):] == [ev[3]["tick_id"] for ev in ticks]
+        assert [ev[3]["fill"] for ev in ticks] == \
+            list(svc.obs.fill_window)[-len(ticks):]
+        assert sum(ev[3]["fill"] for ev in ticks) == len(responses)
+        esc = by_name["acam.escalate"]
+        assert len(esc) == m["escalation_dispatches"] > 0
+        assert sum(ev[3]["n"] for ev in esc) == m["escalated"]
+        assert all(ev[3]["bucket"] >= ev[3]["n"] for ev in esc)
+        # every span lies inside one span of its parent's name
+        for s, e, name, _ in spans:
+            parent = SPAN_PARENT[name]
+            if parent is not None:
+                assert any(ps <= s and e <= pe
+                           for ps, pe, _, _ in by_name[parent]), name
 
 
 # ---------------------------------------------------------------------------
@@ -317,15 +404,13 @@ class TestFlightRecorder:
     def test_obs_spec_validation(self):
         with pytest.raises(ValueError, match="increasing"):
             _spec(obs=spec_lib.ObsSpec(latency_buckets_ms=(2.0, 1.0)))
-        with pytest.raises(ValueError, match="span_sample"):
-            _spec(obs=spec_lib.ObsSpec(span_sample=1.5))
         with pytest.raises(ValueError, match="latency_window"):
             _spec(obs=spec_lib.ObsSpec(latency_window=0))
 
     def test_obs_spec_json_roundtrip(self):
         spec = _spec(obs=spec_lib.ObsSpec(
             latency_buckets_ms=(1.0, 5.0, 25.0), latency_window=64,
-            span_sample=0.25))
+            profile_annotations=True))
         again = spec_lib.ServiceSpec.from_dict(json.loads(spec.to_json()))
         assert again == spec
         # snapshots written before the flight recorder existed still load
@@ -333,6 +418,10 @@ class TestFlightRecorder:
         del legacy["obs"]
         assert spec_lib.ServiceSpec.from_dict(legacy).obs == \
             spec_lib.ObsSpec()
+        # ... and so do those written while spans were sampled per request
+        sampled = spec.to_dict()
+        sampled["obs"]["span_sample"] = 0.25
+        assert spec_lib.ServiceSpec.from_dict(sampled) == spec
 
     def test_straggler_sink_feeds_health(self):
         svc, _ = _boot(_spec())
@@ -371,7 +460,7 @@ class TestServiceTelemetry:
 
     def test_span_counts_equal_request_counts(self, served):
         svc, reqs, responses = served
-        c = svc.obs.spans.conservation()
+        c = svc.obs.conservation()
         m = svc.metrics()
         assert c["started"] == m["submitted"] == len(reqs)
         assert c["finished"] == m["completed"] == len(responses)
@@ -380,15 +469,32 @@ class TestServiceTelemetry:
         assert c["by_disposition"].get("escalated", 0) == m["escalated"] > 0
         assert sum(c["by_disposition"].values()) == c["finished"]
 
-    def test_finished_spans_carry_tick_attribution(self, served):
-        svc, _, _ = served
-        spans = list(svc.obs.spans.finished)
-        assert spans
-        for s in spans:
-            assert s.tick_id >= 0
-            assert s.disposition in ("ok", "escalated")
-            assert s.total_ms >= s.service_ms >= 0.0
-            assert s.dispatch_ms > 0.0
+    def test_queue_wait_counts_every_dispatched_request(self, served):
+        svc, reqs, _ = served
+        wait = svc.obs.queue_wait
+        m = svc.metrics()
+        assert wait.count == m["served"] == len(reqs)
+        assert wait.sum >= 0.0
+        # a burst served tick by tick: later ticks' requests waited longer
+        assert wait.quantile(1.0, window=False) > 0.0
+        assert "acam_queue_wait_ms_count" in svc.obs.render_prometheus()
+
+    def test_conservation_survives_reset(self):
+        svc, protos = _boot(_spec())
+        reqs = _mixed_requests(protos, per_tenant=4)
+        for r in reqs[:SLOTS + 3]:
+            svc.submit(r)
+        svc.step()  # one tick: 3 requests stay queued
+        before = svc.obs.conservation()
+        assert before["started"] == SLOTS + 3
+        assert before["in_flight"] == svc.scheduler.qsize == 3
+        svc.reset_metrics()
+        assert svc.metrics()["submitted"] == 0, "the counter clears"
+        assert svc.obs.conservation() == before, "the totals do not"
+        svc.drain()
+        after = svc.obs.conservation()
+        assert after["started"] == after["finished"] == SLOTS + 3
+        assert after["in_flight"] == 0
 
     def test_metrics_and_shed_check_read_same_quantile(self, served):
         svc, _, _ = served
@@ -407,7 +513,7 @@ class TestServiceTelemetry:
         # runs LAST against the shared service: it mutates counters
         svc, _, _ = served
         svc.obs.queue_depth.set(7)  # pretend depth; must survive
-        before = svc.obs.spans.conservation()
+        before = svc.obs.conservation()
         tick_seq = svc.obs.tick_seq
         assert svc.metrics()["completed"] > 0
         p50_window = svc.obs.latency_quantile_ms(0.5)
@@ -421,18 +527,18 @@ class TestServiceTelemetry:
             assert not m[key], (key, m[key])
         assert svc.obs.latency.count == 0
         assert svc.scheduler.stats.ticks == 0  # legacy mirror follows
-        # SURVIVING: gauges, rolling window, span totals, tick sequence
+        # SURVIVING: gauges, rolling window, conservation totals, tick ids
         assert svc.obs.queue_depth.value() == 7
         assert svc.obs.latency_quantile_ms(0.5) == p50_window, \
             "reset must never blind the shed_p99_ms overload signal"
         assert m["latency_p50_ms"] == round(p50_window, 3)
-        assert svc.obs.spans.conservation() == before
+        assert svc.obs.conservation() == before
         assert svc.obs.tick_seq == tick_seq
 
 
 class TestBurstyTraceTelemetry:
-    """Span/energy/event accounting under the bursty Zipf trace with the
-    overload policy armed — every disposition in one run."""
+    """Conservation/energy/event accounting under the bursty Zipf trace
+    with the overload policy armed — every disposition in one run."""
 
     @pytest.fixture(scope="class")
     def replayed(self, tmp_path_factory):
@@ -454,7 +560,7 @@ class TestBurstyTraceTelemetry:
 
     def test_conservation_across_dispositions(self, replayed):
         svc, stats, _ = replayed
-        c = svc.obs.spans.conservation()
+        c = svc.obs.conservation()
         m = svc.metrics()
         assert c["started"] == m["submitted"] == stats["submitted"]
         assert c["finished"] == m["completed"] == stats["completed"]
@@ -499,7 +605,7 @@ class TestDeadlineTelemetry:
         responses = svc.drain()
         assert all(r.error is not None and "deadline" in r.error
                    for r in responses)
-        c = svc.obs.spans.conservation()
+        c = svc.obs.conservation()
         assert c["by_disposition"] == {"expired": len(reqs)}
         assert c["in_flight"] == 0
         m = svc.metrics()
@@ -512,16 +618,17 @@ class TestDeadlineTelemetry:
         svc, protos = _boot(_spec())
         with pytest.raises(AdmissionError):
             svc.submit(ClassifyRequest("nobody", np.zeros(N_FEATURES)))
-        c = svc.obs.spans.conservation()
+        c = svc.obs.conservation()
         assert c["started"] == 0
         assert svc.metrics()["rejected"] == 1
 
 
 class TestTelemetryOverhead:
     def test_bit_identical_and_under_five_pct(self):
-        """Telemetry observes, never steers: the full recorder (all spans
-        + JSONL sink) must serve bit-identical preds/margins/escalations
-        and cost <5% per-request latency vs spans-off/no-sink. Passes are
+        """Telemetry observes, never steers: the full recorder (every
+        serving span on + JSONL sink) must serve bit-identical
+        preds/margins/escalations and cost <5% per-request latency vs
+        spans-off/no-sink. Passes are
         INTERLEAVED (base, telemetry, base, telemetry, ...) and best-of-5
         so clock drift across the run — CPU frequency, GC pressure from
         earlier suite tests — hits both arms equally instead of reading
@@ -546,10 +653,10 @@ class TestTelemetryOverhead:
             # per-response telemetry bookkeeping under measurement
             return svc.obs.busy_seconds.value(), sig
 
-        base_svc, base_reqs = build(spec_lib.ObsSpec(span_sample=0.0))
+        base_svc, base_reqs = build(spec_lib.ObsSpec())
         with tempfile.TemporaryDirectory() as td:
-            tel_svc, tel_reqs = build(
-                spec_lib.ObsSpec(telemetry_dir=td, span_sample=1.0))
+            tel_svc, tel_reqs = build(spec_lib.ObsSpec(
+                telemetry_dir=td, profile_annotations=True))
             base_sig = tel_sig = None
             best = None
             # true overhead is ~2% (the BENCH row tracks it), so scheduler
