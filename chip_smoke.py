@@ -194,13 +194,12 @@ def tick_hlo(svc) -> str:
     import jax.numpy as jnp
 
     from repro.distributed import context
-    from repro.serve.scheduler import _batched_classify
+    from repro.serve.scheduler import _batched_classify, _packed_tick
 
-    z = jnp.zeros((SLOTS,), jnp.int32)
-    return _batched_classify.lower(
+    return _packed_tick.lower(
         svc.registry.device_bank(), svc.registry.thresholds_table(),
-        jnp.zeros((SLOTS, NUM_FEATURES), jnp.float32), z, z, z,
-        jnp.zeros((SLOTS,), jnp.float32), config=svc.scheduler.engine_config,
+        jnp.zeros((SLOTS, NUM_FEATURES + 4), jnp.int32),
+        classify=_batched_classify, config=svc.scheduler.engine_config,
         mesh_gen=context.generation()).compile().as_text()
 
 

@@ -40,10 +40,20 @@ can shed load or shrink the mesh before latency collapses.
 With `ObsSpec.profile_annotations` on, every tick is traced in spans of the
 flight recorder (`FlightRecorder.span`): ``sched.tick`` around the tick
 (stats ``tick_id`` and ``fill``, the requests dequeued), holding
-``sched.pack`` (pop, placement lookup, numpy operands) and
-``acam_fused_dispatch``, which holds ``sched.launch`` (the uploads and the
-jitted call) and ``sched.fetch`` (the wait for the device and the four
-copies back).
+``sched.pack`` (pop, placement lookup, the packed operand buffer) and
+``acam_fused_dispatch``, which holds ``sched.launch`` (one upload and the
+jitted call) and ``sched.fetch`` (the wait for the device and one copy
+back); both carry the bytes they move as the span stat ``bytes``.
+
+Each tick crosses the host-device boundary once each way. `_pack` writes
+the tick's operands into one int32 ``(slots, N + 4)`` buffer — the f32
+features and tau as their bit patterns, then tenant slot and class window —
+uploaded by one `jax.device_put`; `_packed_tick` unpacks it on the device,
+runs `_batched_classify`, picks each slot's winner score out of
+``per_class`` there, and returns one int32 ``(4, slots)`` array (pred,
+escalate, and the bit patterns of margin and score) fetched by one
+`jax.device_get`. ``per_class`` never leaves the device. The counters
+`SchedulerStats.h2d_transfers` / ``d2h_transfers`` / ``bytes_back`` show it.
 """
 from __future__ import annotations
 
@@ -56,6 +66,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import match as match_lib
 from repro.ft.elastic import StragglerMonitor
@@ -108,6 +119,9 @@ class SchedulerStats:
     tick_time_s: float = 0.0  # summed dispatch wall time
     slow_ticks: int = 0  # ticks flagged by the straggler monitor
     expired: int = 0  # requests expired past their queue deadline
+    h2d_transfers: int = 0  # host-to-device uploads (one per dispatch)
+    d2h_transfers: int = 0  # device-to-host copies back (one per dispatch)
+    bytes_back: int = 0  # bytes copied back, summed over ticks
 
     def record_tick(self, fill: int, *, dt_s: float = 0.0,
                     slow: bool = False) -> None:
@@ -140,6 +154,9 @@ class SchedulerStats:
             "tick_time_s": round(self.tick_time_s, 6),
             "slow_ticks": self.slow_ticks,
             "expired": self.expired,
+            "h2d_transfers": self.h2d_transfers,
+            "d2h_transfers": self.d2h_transfers,
+            "bytes_back": self.bytes_back,
         }
 
 
@@ -167,6 +184,40 @@ def _batched_classify(bank, thr_table, feats, tenant_slot, class_lo, class_hi,
     eng = match_lib.engine_from_config(config)
     return eng.classify_serve(feats, thr_table, tenant_slot, bank, class_lo,
                               class_hi, tau)
+
+
+def _as_f32(bits):
+    return lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _as_i32(x):
+    return lax.bitcast_convert_type(x, jnp.int32)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("classify", "config", "mesh_gen"))
+def _packed_tick(bank, thr_table, packed, *, classify, config, mesh_gen: int):
+    """The tick between one upload and one copy back. ``packed`` is the
+    int32 ``(slots, N + 4)`` buffer of `MicroBatchScheduler._pack`:
+    features and tau as f32 bit patterns, tenant slot, window lo and hi.
+    -> int32 ``(4, slots)``: pred, escalate, and the bit patterns of the
+    margin and of the winner's score ``per_class[i, pred[i]]``, a
+    non-finite one (an unprogrammed class row's -inf) clamped to 0.0.
+    ``per_class`` is logically (slots, C_cap) under every plan, so the
+    gather is the same whatever the sharding.
+
+    ``classify`` is `_batched_classify` as the module holds it at call time
+    (static: replacing it keys a fresh trace); ``config`` and ``mesh_gen``
+    are passed through to it, static here too."""
+    n = packed.shape[1] - 4
+    pred, per_class, margin, esc = classify(
+        bank, thr_table, _as_f32(packed[:, :n]), packed[:, n + 1],
+        packed[:, n + 2], packed[:, n + 3], _as_f32(packed[:, n]),
+        config=config, mesh_gen=mesh_gen)
+    score = jnp.take_along_axis(per_class, pred[:, None], axis=1)[:, 0]
+    score = jnp.where(jnp.isfinite(score), score, 0.0)
+    return jnp.stack([pred.astype(jnp.int32), esc.astype(jnp.int32),
+                      _as_i32(margin), _as_i32(score)])
 
 
 class MicroBatchScheduler:
@@ -272,7 +323,7 @@ class MicroBatchScheduler:
         n = min(self.slots, len(self._queue))
         with obs.span("sched.tick", tick_id=obs.next_tick_id(), fill=n):
             with obs.span("sched.pack"):
-                dead, batch, operands = self._pack(n, t0)
+                dead, batch, packed = self._pack(n, t0)
             if not batch:
                 return dead
             from repro.distributed import context
@@ -280,26 +331,26 @@ class MicroBatchScheduler:
             cfg = self.engine_config._replace(
                 backend=self.backend or match_lib.default_backend())
             with obs.span("acam_fused_dispatch"):
-                with obs.span("sched.launch"):
-                    out = _batched_classify(
+                with obs.span("sched.launch", bytes=packed.nbytes):
+                    dev = jax.device_put(packed)
+                    self.stats.h2d_transfers += 1
+                    out = _packed_tick(
                         self.registry.device_bank(),
-                        self.registry.thresholds_table(),
-                        *(jnp.asarray(a) for a in operands), config=cfg,
+                        self.registry.thresholds_table(), dev,
+                        classify=_batched_classify, config=cfg,
                         mesh_gen=context.generation())
-                with obs.span("sched.fetch"):
-                    pred, per_class, margin, esc = (np.asarray(a)
-                                                    for a in out)
+                with obs.span("sched.fetch", bytes=out.nbytes):
+                    res = jax.device_get(out)
+                    self.stats.d2h_transfers += 1
+                    self.stats.bytes_back += res.nbytes
             dt = time.perf_counter() - t0
             self.last_verdict = self.monitor.observe(0, dt)
             slow = bool(self.last_verdict["stragglers"])
             self.stats.record_tick(len(batch), dt_s=dt, slow=slow)
             obs.record_tick_dispatch(len(batch), dt, slow)
 
-            # winner's absolute score: per_class is logically (slots, C_cap)
-            # under every plan, so per_class[i, pred[i]] is uniform. An
-            # empty window's pred is 0 and its score -inf; clamp to 0.0.
-            score = per_class[np.arange(len(batch)), pred[:len(batch)]]
-            score = np.where(np.isfinite(score), score, 0.0)
+            pred, esc = res[0], res[1]
+            margin, score = res[2].view(np.float32), res[3].view(np.float32)
             return dead + [
                 SlotResult(item=item, entry=entry,
                            pred_local=int(pred[i]) - entry.offset,
@@ -308,9 +359,10 @@ class MicroBatchScheduler:
                 for i, (item, entry) in enumerate(batch)]
 
     def _pack(self, n: int, t0: float):
-        """Pop ``n`` requests and build the tick's numpy operands. ->
-        (results of requests whose tenant is gone, [(item, entry)] to
-        serve, (feats, tenant slot, window lo, window hi, tau))."""
+        """Pop ``n`` requests and pack the tick's operands. -> (results of
+        requests whose tenant is gone, [(item, entry)] to serve, the int32
+        ``(slots, N + 4)`` buffer: features, tau, tenant slot, window lo,
+        window hi; f32 columns as their bit patterns)."""
         popped = [self._queue.popleft() for _ in range(n)]
         self.recorder.record_queue_wait(popped, t0)
         # resolve placements NOW: queued requests must see the tenant's
@@ -326,22 +378,22 @@ class MicroBatchScheduler:
             else:
                 batch.append((item, entry))
         if not batch:
-            return dead, batch, ()
-        feats = np.zeros((self.slots, self.registry.num_features),
-                         np.float32)
-        slot_idx = np.zeros((self.slots,), np.int32)
-        lo = np.zeros((self.slots,), np.int32)
-        hi = np.zeros((self.slots,), np.int32)  # padding: empty window [0, 0)
-        tau = np.full((self.slots,), -np.inf, np.float32)  # never escalate
+            return dead, batch, None
+        nf = self.registry.num_features
+        # padding rows: zero features, never escalate, empty window [0, 0)
+        packed = np.zeros((self.slots, nf + 4), np.int32)
+        feats = packed[:, :nf].view(np.float32)
+        tau = packed[:, nf].view(np.float32)
+        tau[:] = -np.inf
         for i, (item, entry) in enumerate(batch):
             feats[i] = item.features
-            slot_idx[i] = entry.slot
-            lo[i], hi[i] = entry.window
+            packed[i, nf + 1] = entry.slot
+            packed[i, nf + 2:] = entry.window
             if self.tau_fn is not None:
                 t = self.tau_fn(item.tenant_id)
                 if t is not None:
                     tau[i] = t
-        return dead, batch, (feats, slot_idx, lo, hi, tau)
+        return dead, batch, packed
 
     def drain(self) -> list[SlotResult]:
         out: list[SlotResult] = []

@@ -280,13 +280,13 @@ class TestApplyManifest:
         reqs = _requests(_protos())
         base = _signature(svc.serve(reqs))  # compiles every bucket shape
         gen0 = svc.registry.generation
-        size0 = sched_lib._batched_classify._cache_size()
+        size0 = sched_lib._packed_tick._cache_size()
         rep = svc.apply_manifest(_manifest())  # equal manifest, re-applied
         assert rep.empty
         assert rep.added == rep.evicted == rep.updated == rep.retuned == ()
         assert svc.registry.generation == gen0  # no device-cache bump
         assert _signature(svc.serve(reqs)) == base
-        assert sched_lib._batched_classify._cache_size() == size0
+        assert sched_lib._packed_tick._cache_size() == size0
 
     def test_tau_unit_change_retunes_without_reload(self, no_mesh):
         svc = HybridService.from_spec(_spec())

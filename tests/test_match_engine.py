@@ -429,15 +429,15 @@ class TestMeshGenerationRetrace:
                                                   backend="reference")
             feats, _ = svc_lib.sample_tenant_queries(3, protos, 1)
             out0 = tick_once(sched, feats[0])
-            size0 = sched_lib._batched_classify._cache_size()
+            size0 = sched_lib._packed_tick._cache_size()
             tick_once(sched, feats[0])  # same generation: cache hit
-            assert sched_lib._batched_classify._cache_size() == size0
+            assert sched_lib._packed_tick._cache_size() == size0
             from repro.launch.mesh import make_mesh
             mesh = make_mesh((1, 1), ("data", "model"))
             context.set_mesh_axes("data", "model", mesh)
             out1 = tick_once(sched, feats[0])
             # mesh_gen is a static jit arg: a new mesh keys a fresh trace
-            assert sched_lib._batched_classify._cache_size() == size0 + 1
+            assert sched_lib._packed_tick._cache_size() == size0 + 1
             assert out1 == out0
         finally:
             context.clear()

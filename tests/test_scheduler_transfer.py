@@ -1,0 +1,212 @@
+"""The scheduler tick crosses the host-device boundary once each way.
+
+  * the packed tick (`scheduler._packed_tick`: one int32 operand buffer in,
+    one int32 (4, slots) result out) gives pred, escalate, margin and winner
+    score bit-equal to `scheduler._batched_classify` called directly on the
+    unpacked operands — full and ragged ticks, padding slots (empty window,
+    score clamped to 0.0), tau = -inf, the reference, kernel and device
+    backends, and a bank-sharded plan on four CPU devices;
+  * a tick makes exactly one `jax.device_put` and one `jax.device_get`, of
+    the expected sizes, and the jitted tick takes one per-tick array and
+    returns one.
+"""
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.distributed import context
+from repro.serve import acam_service as svc_lib
+from repro.serve import scheduler as sched_lib
+from repro.serve.registry import TemplateBankRegistry
+from test_bank_sharding import run_sub
+
+N = 64
+SLOTS = 8
+
+
+def _fleet(backend, tau=None, registry_kw=None):
+    reg = TemplateBankRegistry(N, class_bucket=8, **(registry_kw or {}))
+    protos = {}
+    for t in range(4):
+        bank, _, p = svc_lib.make_synthetic_tenant(
+            300 + t, num_classes=5 + t, num_features=N)
+        reg.register(f"t{t}", bank)
+        protos[f"t{t}"] = p
+    # class row 0 left unprogrammed: a padding slot (empty window, pred 0)
+    # reads a -inf score there, which the tick clamps to 0.0
+    reg.evict("t0")
+    del protos["t0"]
+    sched = sched_lib.MicroBatchScheduler(reg, slots=SLOTS, backend=backend)
+    if tau is not None:  # t1 has no head: its tau stays -inf
+        sched.tau_fn = lambda tid: None if tid == "t1" else tau
+    return reg, sched, protos
+
+
+def _items(protos, fill, seed=5):
+    tids = sorted(protos)
+    out = []
+    for i in range(fill):
+        tid = tids[i % len(tids)]
+        f, _ = svc_lib.sample_tenant_queries(seed + i, protos[tid], 1,
+                                             noise=0.9)
+        out.append(sched_lib.WorkItem(i, tid, np.asarray(f[0]),
+                                      time.perf_counter()))
+    return out
+
+
+def _unpacked(reg, sched, items):
+    """The tick's operands built one array each, as before packing, and
+    `_batched_classify` on them -> (pred, esc, margin, score) per slot."""
+    feats = np.zeros((SLOTS, N), np.float32)
+    slot, lo, hi = (np.zeros((SLOTS,), np.int32) for _ in range(3))
+    tau = np.full((SLOTS,), -np.inf, np.float32)
+    for i, item in enumerate(items):
+        e = reg.lookup(item.tenant_id)
+        feats[i], slot[i] = item.features, e.slot
+        lo[i], hi[i] = e.window
+        t = sched.tau_fn(item.tenant_id) if sched.tau_fn else None
+        if t is not None:
+            tau[i] = t
+    pred, per_class, margin, esc = (np.asarray(a) for a in
+                                    sched_lib._batched_classify(
+        reg.device_bank(), reg.thresholds_table(), jnp.asarray(feats),
+        jnp.asarray(slot), jnp.asarray(lo), jnp.asarray(hi),
+        jnp.asarray(tau), config=sched.engine_config,
+        mesh_gen=context.generation()))
+    score = per_class[np.arange(SLOTS), pred]
+    score = np.where(np.isfinite(score), score, 0.0).astype(np.float32)
+    return pred, esc.astype(np.int32), margin.view(np.int32), \
+        score.view(np.int32)
+
+
+def _packed(reg, sched, items):
+    for item in items:
+        sched.submit(item)
+    dead, batch, packed = sched._pack(len(items), time.perf_counter())
+    assert not dead and len(batch) == len(items)
+    assert packed.shape == (SLOTS, N + 4) and packed.dtype == np.int32
+    out = sched_lib._packed_tick(
+        reg.device_bank(), reg.thresholds_table(), jax.device_put(packed),
+        classify=sched_lib._batched_classify, config=sched.engine_config,
+        mesh_gen=context.generation())
+    return np.asarray(jax.device_get(out))
+
+
+@pytest.mark.parametrize("tau", [None, 10.0], ids=["tau-inf", "tau10"])
+@pytest.mark.parametrize("fill", [SLOTS, SLOTS - 3], ids=["full", "ragged"])
+@pytest.mark.parametrize("backend", ["reference", "kernel", "device"])
+def test_packed_tick_bit_equal_to_unpacked(backend, fill, tau):
+    reg, sched, protos = _fleet(backend, tau)
+    items = _items(protos, fill)
+    pred, esc, margin, score = _unpacked(reg, sched, items)
+    got = _packed(reg, sched, items)
+    np.testing.assert_array_equal(got, np.stack([pred, esc, margin, score]))
+    # padding slots: empty window, never escalated, score clamped to 0.0
+    pad = slice(fill, SLOTS)
+    assert (got[1, pad] == 0).all()
+    assert (got[3, pad] == np.float32(0.0).view(np.int32)).all()
+    heads = [i for i, it in enumerate(items) if it.tenant_id != "t1"]
+    if tau is None:
+        assert not esc.any()
+    else:  # slots with a head escalate below tau, the others never
+        assert esc[heads].any()
+        assert not np.delete(esc, heads).any()
+
+    # and the served results of a whole tick carry the same bits
+    for item in items:
+        sched.submit(item)
+    res = sched.tick()
+    assert [r.item.request_id for r in res] == list(range(fill))
+    for i, r in enumerate(res):
+        assert r.pred_local + r.entry.offset == pred[i]
+        assert r.escalate == bool(esc[i])
+        assert np.float32(r.margin).view(np.int32) == margin[i]
+        assert np.float32(r.score).view(np.int32) == score[i]
+
+
+def test_one_upload_one_copy_back_per_tick(monkeypatch):
+    reg, sched, protos = _fleet("reference", tau=10.0)
+    items = _items(protos, 3 * SLOTS - 2)
+    for item in items[:SLOTS]:
+        sched.submit(item)
+    sched.tick()  # compile outside the spied ticks
+    puts, gets, calls = [], [], []
+    put, get, tick = jax.device_put, jax.device_get, sched_lib._packed_tick
+
+    def spy_put(x, *a, **k):
+        puts.append(x)
+        return put(x, *a, **k)
+
+    def spy_get(x, *a, **k):
+        out = get(x, *a, **k)
+        gets.append(out)
+        return out
+
+    def spy_tick(*args, **kw):
+        out = tick(*args, **kw)
+        calls.append((args, out))
+        return out
+
+    monkeypatch.setattr(jax, "device_put", spy_put)
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    monkeypatch.setattr(sched_lib, "_packed_tick", spy_tick)
+    before = dict(sched.stats.as_dict())
+    for item in items[SLOTS:]:
+        sched.submit(item)
+    assert len(sched.drain()) == 2 * SLOTS - 2
+    ticks = 2
+    assert len(puts) == len(gets) == len(calls) == ticks
+    for x in puts:
+        assert isinstance(x, np.ndarray) and x.nbytes == SLOTS * (N + 4) * 4
+    for y in gets:
+        assert isinstance(y, np.ndarray) and y.nbytes == 16 * SLOTS
+    for args, out in calls:
+        # the bank and thresholds are the registry's cached device arrays:
+        # the one per-tick operand is the uploaded buffer
+        assert len(args) == 3
+        assert args[0] is reg.device_bank()
+        assert args[1] is reg.thresholds_table()
+        assert isinstance(args[2], jax.Array)
+        assert args[2].shape == (SLOTS, N + 4)
+        assert isinstance(out, jax.Array)
+        assert out.shape == (4, SLOTS) and out.dtype == jnp.int32
+    after = sched.stats.as_dict()
+    for key in ("classify_dispatches", "h2d_transfers", "d2h_transfers"):
+        assert after[key] - before[key] == ticks, key
+    assert after["bytes_back"] - before["bytes_back"] == 16 * SLOTS * ticks
+
+
+def test_packed_tick_bit_equal_bank_sharded():
+    """Four CPU devices, a 2x2 (data, model) mesh, the super-bank split
+    over the model axis: the packed tick still gives the unpacked bits."""
+    out = run_sub("""
+        import os, sys
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        sys.path.insert(0, %r)
+        import numpy as np
+        from repro import match
+        from repro.distributed import context
+        from repro.launch.mesh import make_mesh
+        import test_scheduler_transfer as t
+
+        mesh = make_mesh((2, 2), ("data", "model"))
+        context.set_mesh_axes("data", "model", mesh)
+        for tau in (None, 10.0):
+            reg, sched, protos = t._fleet("kernel", tau,
+                                          dict(bank_shards=2))
+            assert reg.bank_shards == 2
+            c = reg.device_bank().templates.shape[0]
+            plan, _ = match.plan_for(batch=t.SLOTS, num_classes=c)
+            assert plan.bank_sharded, plan
+            for fill in (t.SLOTS, t.SLOTS - 3):
+                items = t._items(protos, fill)
+                want = np.stack(t._unpacked(reg, sched, items))
+                got = t._packed(reg, sched, items)
+                assert np.array_equal(got, want), (tau, fill)
+                print("OK", tau, fill)
+        """ % os.path.dirname(__file__))
+    assert out.count("OK") == 4

@@ -333,14 +333,14 @@ class TestReconfigure:
 
         svc, reqs = self._boot()
         base = _signature(svc.serve(reqs))
-        size0 = sched_lib._batched_classify._cache_size()
+        size0 = sched_lib._packed_tick._cache_size()
         report = svc.reconfigure(svc.spec._replace(
             engine=svc.spec.engine._replace(backend="kernel")))
         assert any("engine" in a for a in report.actions)
         assert _signature(svc.serve(reqs)) == base  # kernel == reference
         # the new EngineConfig is a fresh static jit key: exactly one new
         # trace, not a silent replay of the reference executable
-        assert sched_lib._batched_classify._cache_size() == size0 + 1
+        assert sched_lib._packed_tick._cache_size() == size0 + 1
 
     def test_tau_retune_moves_the_cascade(self, no_mesh):
         svc, reqs = self._boot()
