@@ -24,7 +24,8 @@ compiled for the chip (never the Pallas interpreter).
 
 ``--four-chips`` runs only the bank-sharded path: the same tenants and
 requests served with ``bank_shards`` 4 (a 1x4 mesh) and 2 (2x2), each
-compared with the ``bank_shards=1`` service on the same four chips.
+compared with the ``bank_shards=1`` service on the same four chips, and
+each service's super-bank placed on its mesh once.
 
 Findings go to standard output. The last line is one JSON object,
 ``{"ok": true, "device": {...}}``, printed only when every phase passed;
@@ -184,23 +185,27 @@ def bank_regime(svc, bank_shards: int) -> tuple[int, int, int]:
     from repro.kernels import layout
     from repro.match.backends import MAX_FUSED_ROWS
 
-    c, k, _ = svc.registry.device_bank().templates.shape
+    c, k = svc.registry.capacity_classes, svc.registry.k_max
     cp = layout.padded_classes(c // bank_shards)
     return cp, k * cp, cp // layout.class_chunk(cp, k, MAX_FUSED_ROWS)
 
 
 def tick_hlo(svc) -> str:
-    """Compiled text of the service's scheduler tick at its live shapes."""
-    import jax.numpy as jnp
+    """Compiled text of the service's scheduler tick at its live shapes and
+    placements (the arrays the last tick was given)."""
+    import jax
+    import numpy as np
 
-    from repro.distributed import context
     from repro.serve.scheduler import _batched_classify, _packed_tick
 
+    sched, reg = svc.scheduler, svc.registry
+    place, mesh_gen = sched._placement(sched.engine_config)
+    packed = jax.device_put(np.zeros((SLOTS, NUM_FEATURES + 4), np.int32),
+                            None if place is None else place.batch)
     return _packed_tick.lower(
-        svc.registry.device_bank(), svc.registry.thresholds_table(),
-        jnp.zeros((SLOTS, NUM_FEATURES + 4), jnp.int32),
-        classify=_batched_classify, config=svc.scheduler.engine_config,
-        mesh_gen=context.generation()).compile().as_text()
+        reg.device_bank(place), reg.thresholds_table(place), packed,
+        classify=_batched_classify, config=sched.engine_config,
+        mesh_gen=mesh_gen).compile().as_text()
 
 
 def compare(name: str, got: dict, want: dict) -> bool:
@@ -294,12 +299,14 @@ def phase_four_chips(params, train, test, dev) -> bool:
                 cp, rows, nj = bank_regime(svc, shards)
                 hlo = tick_hlo(svc)
                 mesh = dict(context.get_mesh().shape)
+                placed = svc.registry.bank_placements
                 print(f"  {method} {bank} bank_shards={shards} mesh {mesh}: "
                       f"{cp} classes/shard x k={K} = {rows} rows/shard, "
                       f"{nj} chunk(s); tpu_custom_call "
                       f"{'tpu_custom_call' in hlo}, all-gather "
-                      f"{'all-gather' in hlo}", flush=True)
-                ok &= "tpu_custom_call" in hlo
+                      f"{'all-gather' in hlo}; bank placed {placed} time(s)",
+                      flush=True)
+                ok &= "tpu_custom_call" in hlo and placed == 1
             for shards in (2, 4):
                 ok &= compare(f"{method} {bank} bank_shards={shards} vs 1",
                               out[shards], out[1])

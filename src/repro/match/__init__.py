@@ -35,8 +35,8 @@ from repro.match.engine import (MatchEngine, bank_specs, batch_specs,
                                 engine_from_config, set_default_backend,
                                 use_backend)
 from repro.match.plan import (REPLICATED, TREE_REDUCE_MIN_SHARDS,
-                              PartitionPlan, bank_shards_in_mesh, plan_for,
-                              reduce_strategy)
+                              PartitionPlan, Placement, bank_shards_in_mesh,
+                              placement_for, plan_for, reduce_strategy)
 
 __all__ = [
     "MAX_FUSED_ROWS", "TINY_ELEMENTS", "TINY_ELEMENTS_SIMILARITY",
@@ -47,6 +47,6 @@ __all__ = [
     "winner_take_all", "EngineConfig", "MatchEngine", "bank_specs",
     "batch_specs", "default_backend", "dp_axes_in_mesh", "engine_for",
     "engine_from_config", "set_default_backend", "use_backend", "REPLICATED",
-    "TREE_REDUCE_MIN_SHARDS", "PartitionPlan", "bank_shards_in_mesh",
-    "plan_for", "reduce_strategy",
+    "TREE_REDUCE_MIN_SHARDS", "PartitionPlan", "Placement",
+    "bank_shards_in_mesh", "placement_for", "plan_for", "reduce_strategy",
 ]

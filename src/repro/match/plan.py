@@ -32,7 +32,11 @@ Who consumes the plan:
 
     repro.match.engine        builds the 2D shard_map specs + reduces from it
     repro.serve.registry      aligns tenant class buckets to shard boundaries
-                              (`TemplateBankRegistry(bank_shards=...)`)
+                              (`TemplateBankRegistry(bank_shards=...)`) and
+                              places the super-bank on the plan's shardings
+                              (`placement_for`)
+    repro.serve.scheduler     uploads each tick's operands on the plan's
+                              batch sharding (`placement_for`)
     repro.serve.acam_service  infers `bank_shards` via `bank_shards_in_mesh`
     repro.launch.serve        installs the mesh (`--bank-shards`)
 """
@@ -42,6 +46,7 @@ import math
 import os
 from typing import NamedTuple
 
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 #: below this many bank shards the sequential all-gather fold wins: the
@@ -183,6 +188,40 @@ def plan_for(*, batch: int, num_classes: int,
     if not plan.sharded:
         return REPLICATED, None
     return plan, mesh
+
+
+class Placement(NamedTuple):
+    """Where a plan's operands live before the call: the shardings the
+    engine's `shard_map` in-specs ask for, so that a jitted caller whose
+    arguments already carry them moves nothing between devices.
+
+    bank:   class-row-leading arrays of the super-bank (`class_spec`)
+    table:  the per-tenant thresholds table and the bank's shared
+            thresholds vector, whole on every device (``P()``)
+    batch:  batch-leading operands (`batch_spec`)
+    shards: the plan's bank shards (1 when the bank is replicated)
+    """
+
+    bank: NamedSharding
+    table: NamedSharding
+    batch: NamedSharding
+    shards: int
+
+
+def placement_for(*, batch: int, num_classes: int,
+                  bank_shardable: bool = True) -> Placement | None:
+    """The `Placement` of a call with static shapes (batch, num_classes),
+    from the same plan the engine derives (`plan_for`). None when the call
+    runs unsharded (no mesh, a one-device mesh, nothing divides): its
+    operands then stay uncommitted, on the default device."""
+    plan, mesh = plan_for(batch=batch, num_classes=num_classes,
+                          bank_shardable=bank_shardable)
+    if mesh is None:
+        return None
+    return Placement(bank=NamedSharding(mesh, plan.class_spec()),
+                     table=NamedSharding(mesh, P()),
+                     batch=NamedSharding(mesh, plan.batch_spec()),
+                     shards=plan.bank_shards)
 
 
 def bank_shards_in_mesh() -> int:

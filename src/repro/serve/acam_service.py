@@ -672,6 +672,10 @@ class ACAMService:
             "capacity_classes": stats["capacity_classes"],
             "rows_per_shard": rows,
             "shard_rows_used": self.registry.shard_rows_used(),
+            # super-bank placements on the mesh: one per registry or mesh
+            # generation that served, none per tick
+            "bank_placements": stats["bank_placements"],
+            "bank_bytes_placed": stats["bank_bytes_placed"],
             # the resident serve kernel holds k_max * padded(rows/shard)
             # template rows in VMEM; past MAX_FUSED_ROWS it falls back to
             # the class-chunked path — headroom is the policy's VMEM signal

@@ -21,8 +21,12 @@ super-bank:
 
 Host-side numpy mirrors hold the authoritative state; device arrays are
 rebuilt lazily (`device_bank`, `thresholds_table`) and cached per
-`generation`, so an unchanged registry never re-uploads and the scheduler's
-"one bank gather per tick" stays a gather, not a transfer.
+`generation` and mesh generation, so an unchanged registry never re-uploads
+and the scheduler's "one bank gather per tick" stays a gather, not a
+transfer. Under a sharded plan the scheduler hands over the plan's
+`Placement`, and each array goes from host memory straight onto its
+sharding (class rows over the model axis), once: the counters
+``bank_placements`` / ``bank_bytes_placed`` (`stats`) count them.
 
 The fused margins kernel keeps all ``K_max * padded_classes(C_cap)``
 template rows VMEM-resident; past `repro.match.MAX_FUSED_ROWS` the kernel
@@ -53,10 +57,11 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from repro.core.templates import TemplateBank
+from repro.match.plan import Placement
 
 
 class RegistryError(ValueError):
@@ -130,8 +135,11 @@ class TemplateBankRegistry:
         self._slot_used = np.zeros(self._t_cap, bool)
         self._tenants: dict[str, TenantEntry] = {}
         self.generation = 0
-        self._device_cache: tuple[int, TemplateBank] | None = None
-        self._thr_cache: tuple[int, jnp.ndarray] | None = None
+        #: (placement key, bank, thresholds table): `device_bank`
+        self._device_cache: tuple | None = None
+        #: placements made (one per generation that served) and their bytes
+        self.bank_placements = 0
+        self.bank_bytes_placed = 0
 
     # -- introspection ------------------------------------------------------
 
@@ -186,6 +194,8 @@ class TemplateBankRegistry:
             "programmed_rows": int(self._valid.sum()),
             "bank_shards": self.bank_shards,
             "rows_per_shard": self.rows_per_shard,
+            "bank_placements": self.bank_placements,
+            "bank_bytes_placed": self.bank_bytes_placed,
         }
 
     def shard_rows_used(self) -> list[int]:
@@ -283,7 +293,6 @@ class TemplateBankRegistry:
     def _bump(self) -> None:
         self.generation += 1
         self._device_cache = None
-        self._thr_cache = None
 
     def register(self, tenant_id: str, bank: TemplateBank) -> TenantEntry:
         """Hot-register a tenant's bank: allocate a bucketed class range,
@@ -554,28 +563,63 @@ class TemplateBankRegistry:
 
     # -- device views -------------------------------------------------------
 
-    def device_bank(self) -> TemplateBank:
+    def device_bank(self, placement: Placement | None = None
+                    ) -> TemplateBank:
         """The (C_cap, K_max, N) super-bank as a device-resident
-        `TemplateBank`, cached per generation.
+        `TemplateBank`, placed once per (generation, mesh generation,
+        placement).
 
         `thresholds` is the shared zero vector: per-tenant thresholds are
         applied by *shifting the query features* (scheduler), which keeps
         the fused kernel's binarisation tenant-agnostic.
-        """
-        if self._device_cache is None or \
-                self._device_cache[0] != self.generation:
-            bank = TemplateBank(
-                templates=jnp.asarray(self._templates),
-                lower=jnp.asarray(self._lower),
-                upper=jnp.asarray(self._upper),
-                valid=jnp.asarray(self._valid),
-                thresholds=jnp.zeros((self.num_features,), jnp.float32),
-            )
-            self._device_cache = (self.generation, bank)
-        return self._device_cache[1]
 
-    def thresholds_table(self) -> jnp.ndarray:
-        """(T_cap, N) per-tenant binarisation thresholds, cached."""
-        if self._thr_cache is None or self._thr_cache[0] != self.generation:
-            self._thr_cache = (self.generation, jnp.asarray(self._thr))
-        return self._thr_cache[1]
+        ``placement`` (`repro.match.placement_for`, the scheduler's) puts
+        each array from host memory straight onto the plan's sharding:
+        class rows over the model axis, the thresholds whole on every
+        device, so a tick under a sharded plan moves none of it. None (no
+        sharded plan) leaves the arrays uncommitted on the default device.
+        """
+        return self._placed(placement)[0]
+
+    def thresholds_table(self, placement: Placement | None = None
+                         ) -> jax.Array:
+        """(T_cap, N) per-tenant binarisation thresholds, placed with the
+        super-bank (`device_bank`)."""
+        return self._placed(placement)[1]
+
+    def is_placed(self, placement: Placement | None = None) -> bool:
+        """Whether `device_bank` / `thresholds_table` under ``placement``
+        are cached: False after a registry or mesh generation change."""
+        return self._device_cache is not None and \
+            self._device_cache[0] == self._placement_key(placement)
+
+    def placement_bytes(self) -> int:
+        """Bytes a placement moves: the bank's four arrays, its shared
+        thresholds vector and the thresholds table (one copy of each)."""
+        return sum(a.nbytes for a in (self._templates, self._lower,
+                                      self._upper, self._valid, self._thr)) \
+            + 4 * self.num_features
+
+    def _placement_key(self, placement):
+        from repro.distributed import context
+
+        return self.generation, context.generation(), placement
+
+    def _placed(self, placement):
+        key = self._placement_key(placement)
+        if self._device_cache is None or self._device_cache[0] != key:
+            row = table = None  # uncommitted, on the default device
+            if placement is not None:
+                row, table = placement.bank, placement.table
+            bank = TemplateBank(
+                templates=jax.device_put(self._templates, row),
+                lower=jax.device_put(self._lower, row),
+                upper=jax.device_put(self._upper, row),
+                valid=jax.device_put(self._valid, row),
+                thresholds=jax.device_put(
+                    np.zeros((self.num_features,), np.float32), table),
+            )
+            self._device_cache = (key, bank, jax.device_put(self._thr, table))
+            self.bank_placements += 1
+            self.bank_bytes_placed += self.placement_bytes()
+        return self._device_cache[1:]

@@ -43,7 +43,10 @@ flight recorder (`FlightRecorder.span`): ``sched.tick`` around the tick
 ``sched.pack`` (pop, placement lookup, the packed operand buffer) and
 ``acam_fused_dispatch``, which holds ``sched.launch`` (one upload and the
 jitted call) and ``sched.fetch`` (the wait for the device and one copy
-back); both carry the bytes they move as the span stat ``bytes``.
+back); both carry the bytes they move as the span stat ``bytes``. On the
+first tick after a registry or mesh generation change ``sched.launch``
+also holds ``registry.place`` (stats ``bytes`` and ``shards``): the
+super-bank and thresholds table placed on the plan's shardings.
 
 Each tick crosses the host-device boundary once each way. `_pack` writes
 the tick's operands into one int32 ``(slots, N + 4)`` buffer — the f32
@@ -54,6 +57,9 @@ runs `_batched_classify`, picks each slot's winner score out of
 escalate, and the bit patterns of margin and score) fetched by one
 `jax.device_get`. ``per_class`` never leaves the device. The counters
 `SchedulerStats.h2d_transfers` / ``d2h_transfers`` / ``bytes_back`` show it.
+Under a sharded plan the buffer is uploaded on the plan's batch sharding
+and the registry's arrays already sit on theirs (`repro.match.Placement`),
+so the jitted tick reshards nothing between devices.
 """
 from __future__ import annotations
 
@@ -268,6 +274,8 @@ class MicroBatchScheduler:
         #: None (or a None return) pins tau to -inf: never escalate.
         self.tau_fn = None
         self._queue: deque[WorkItem] = deque()
+        self._place_key = None
+        self._place = None
 
     @property
     def method(self) -> str:
@@ -312,7 +320,8 @@ class MicroBatchScheduler:
         Contract the double-buffered reshard (`repro.fleet.reshard`)
         leans on: every tick re-resolves placements via
         `registry.lookup` and re-reads `device_bank()` /
-        `thresholds_table()` (generation-cached), and queued `WorkItem`s
+        `thresholds_table()` (placed once per registry and mesh
+        generation), and queued `WorkItem`s
         hold only tenant ids — so swapping the registry's arrays +
         offsets BETWEEN two ticks is invisible to queued work, and a
         bank flip needs no drain."""
@@ -326,19 +335,24 @@ class MicroBatchScheduler:
                 dead, batch, packed = self._pack(n, t0)
             if not batch:
                 return dead
-            from repro.distributed import context
-
             cfg = self.engine_config._replace(
                 backend=self.backend or match_lib.default_backend())
+            place, mesh_gen = self._placement(cfg)
+            reg = self.registry
             with obs.span("acam_fused_dispatch"):
                 with obs.span("sched.launch", bytes=packed.nbytes):
-                    dev = jax.device_put(packed)
+                    if not reg.is_placed(place):
+                        shards = 1 if place is None else place.shards
+                        with obs.span("registry.place", shards=shards,
+                                      bytes=reg.placement_bytes()):
+                            reg.device_bank(place)
+                    dev = jax.device_put(
+                        packed, None if place is None else place.batch)
                     self.stats.h2d_transfers += 1
                     out = _packed_tick(
-                        self.registry.device_bank(),
-                        self.registry.thresholds_table(), dev,
-                        classify=_batched_classify, config=cfg,
-                        mesh_gen=context.generation())
+                        reg.device_bank(place), reg.thresholds_table(place),
+                        dev, classify=_batched_classify, config=cfg,
+                        mesh_gen=mesh_gen)
                 with obs.span("sched.fetch", bytes=out.nbytes):
                     res = jax.device_get(out)
                     self.stats.d2h_transfers += 1
@@ -357,6 +371,23 @@ class MicroBatchScheduler:
                            margin=float(margin[i]), escalate=bool(esc[i]),
                            score=float(score[i]))
                 for i, (item, entry) in enumerate(batch)]
+
+    def _placement(self, cfg: match_lib.EngineConfig):
+        """(the tick's `repro.match.Placement` or None, mesh generation):
+        where the super-bank, the thresholds table and the packed buffer
+        must live for the engine's plan under ``cfg``, derived again only
+        when the config, the mesh or the bank's capacity changes."""
+        from repro.distributed import context
+
+        gen = context.generation()
+        key = (cfg, gen, self.registry.capacity_classes)
+        if self._place_key != key:
+            be = match_lib.engine_from_config(cfg).backend()
+            self._place = match_lib.placement_for(
+                batch=self.slots, num_classes=key[2],
+                bank_shardable=be.supports_bank_sharding)
+            self._place_key = key
+        return self._place, gen
 
     def _pack(self, n: int, t0: float):
         """Pop ``n`` requests and pack the tick's operands. -> (results of

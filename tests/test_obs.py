@@ -188,6 +188,7 @@ SPAN_PARENT = {
     "acam_fused_dispatch": "sched.tick",
     "sched.launch": "acam_fused_dispatch",
     "sched.fetch": "acam_fused_dispatch",
+    "registry.place": "sched.launch",
     "acam.escalate": "acam.step",
     "acam.finalize": "acam.step",
 }
@@ -300,6 +301,39 @@ class TestSpans:
                 assert any(ps <= s and e <= pe
                            for ps, pe, _, _ in by_name[parent]), name
 
+
+    def test_registry_place_span_once_per_generation(self, tmp_path):
+        """``registry.place`` opens inside the first tick's ``sched.launch``
+        after a generation change, with the bytes placed and the shard
+        count, and in no other tick."""
+        import jax
+
+        svc, protos = _boot(_spec(obs=spec_lib.ObsSpec(
+            profile_annotations=True)))
+        reqs = _mixed_requests(protos, per_tenant=8)  # three full ticks
+        reg = svc.registry
+        placements = reg.bank_placements
+        jax.profiler.start_trace(str(tmp_path / "trace"))
+        try:
+            svc.serve(reqs)
+            first = reg.placement_bytes()
+            bank, head, _ = svc_lib.make_synthetic_tenant(
+                299, num_classes=N_CLASSES, num_features=N_FEATURES)
+            svc.register_tenant("late", bank, head=head)
+            svc.serve(reqs)
+        finally:
+            jax.profiler.stop_trace()
+        spans = _host_spans(tmp_path / "trace")
+        place = [ev for ev in spans if ev[2] == "registry.place"]
+        assert reg.bank_placements - placements == len(place) == 2
+        assert [ev[3]["bytes"] for ev in place] == \
+            [first, reg.placement_bytes()]
+        assert all(ev[3]["shards"] == 1 for ev in place)
+        # each lies in the launch of its serve's first tick
+        ticks = [ev for ev in spans if ev[2] == "sched.tick"]
+        assert len(ticks) == 2 * len(reqs) // SLOTS
+        for p, tick in zip(place, (ticks[0], ticks[len(ticks) // 2])):
+            assert tick[0] <= p[0] and p[1] <= tick[1]
 
 # ---------------------------------------------------------------------------
 # Energy ledger

@@ -8,7 +8,14 @@
     backends, and a bank-sharded plan on four CPU devices;
   * a tick makes exactly one `jax.device_put` and one `jax.device_get`, of
     the expected sizes, and the jitted tick takes one per-tick array and
-    returns one.
+    returns one;
+  * under a bank-sharded plan on four CPU devices (1x4 and 2x2 meshes) the
+    super-bank and thresholds table carry the plan's shardings, the packed
+    buffer is uploaded on its batch sharding, and warm ticks run under
+    ``jax.transfer_guard("disallow")`` with answers bit-equal to one bank
+    shard; the registry places its arrays once per generation (fleet
+    registration, a drained reshard, a rolling reshard's flip) and never
+    per tick.
 """
 import os
 import time
@@ -210,3 +217,159 @@ def test_packed_tick_bit_equal_bank_sharded():
                 print("OK", tau, fill)
         """ % os.path.dirname(__file__))
     assert out.count("OK") == 4
+
+
+SHARDED_TICKS = """
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, %r)
+import jax
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.distributed import context
+from repro.launch.mesh import make_mesh
+import test_scheduler_transfer as t
+
+DATA, SHARDS = %d, %d
+
+
+def serve(sched, items, ticks):
+    out = []
+    for _ in range(ticks):
+        for item in items:
+            sched.submit(item)
+        out.append([(r.item.tenant_id, r.pred_local, r.escalate,
+                      np.float32(r.margin).view(np.int32),
+                      np.float32(r.score).view(np.int32))
+                     for r in sched.tick()])
+    return out
+
+
+_, one, protos = t._fleet("kernel", 10.0)
+items = t._items(protos, t.SLOTS)
+want = serve(one, items, 1)[0]
+
+mesh = make_mesh((DATA, SHARDS), ("data", "model"))
+context.set_mesh_axes("data", "model", mesh)
+reg, sched, _ = t._fleet("kernel", 10.0, dict(bank_shards=SHARDS))
+assert serve(sched, items, 1) == [want]  # compiles and places
+assert reg.bank_placements == 1
+place, _ = sched._placement(sched.engine_config)
+assert place.shards == SHARDS
+assert place.bank.is_equivalent_to(NamedSharding(mesh, P("model")), 3)
+assert place.table.is_equivalent_to(NamedSharding(mesh, P()), 2)
+assert place.batch.is_equivalent_to(
+    NamedSharding(mesh, P("data" if DATA > 1 else None)), 2)
+bank = reg.device_bank(place)
+for a in (bank.templates, bank.lower, bank.upper, bank.valid):
+    assert a.sharding == place.bank
+assert bank.thresholds.sharding == place.table
+assert reg.thresholds_table(place).sharding == place.table
+
+uploads = []
+put = jax.device_put
+
+
+def spy_put(x, *a, **k):
+    out = put(x, *a, **k)
+    uploads.append(out)
+    return out
+
+
+jax.device_put = spy_put
+with jax.transfer_guard("disallow"):
+    got = serve(sched, items, 20)
+jax.device_put = put
+assert got == [want] * 20
+assert len(uploads) == 20
+assert all(u.sharding == place.batch for u in uploads)
+assert reg.bank_placements == 1
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("data,shards", [(1, 4), (2, 2)],
+                         ids=["1x4", "2x2"])
+def test_sharded_tick_moves_only_operands_and_results(data, shards):
+    """After warm-up a bank-sharded tick moves its packed buffer in and its
+    result out and nothing else: the bank, the thresholds table and the
+    buffer already sit on the engine's shardings."""
+    out = run_sub(SHARDED_TICKS % (os.path.dirname(__file__), data, shards))
+    assert out.strip().endswith("OK")
+
+
+def test_bank_placed_once_per_generation():
+    """Four CPU devices, a service built from its spec: registering the
+    fleet places nothing; the first tick places the bank on the mesh; 20
+    more ticks place nothing; a drained reshard and a rolling reshard's
+    flip each place once more. Answers stay those of one bank shard."""
+    out = run_sub("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import numpy as np
+        from repro.match.config import EngineConfig
+        from repro.serve import acam_service as svc_lib
+        from repro.serve import spec as spec_lib
+        from repro.serve.control import HybridService
+
+        def spec(shards):
+            return spec_lib.ServiceSpec(
+                registry=spec_lib.RegistrySpec(num_features=64,
+                                               class_bucket=8,
+                                               initial_classes=64),
+                engine=EngineConfig(backend="kernel", margin=True),
+                mesh=spec_lib.MeshSpec(bank_shards=shards),
+                scheduler=spec_lib.SchedulerSpec(slots=8),
+                cascade=spec_lib.CascadeSpec(tau=6.0, tau_units="count"))
+
+        def boot(shards):
+            svc = HybridService.from_spec(spec(shards))
+            protos = {}
+            for t in range(4):
+                bank, head, p = svc_lib.make_synthetic_tenant(
+                    400 + t, num_classes=5 + t, num_features=64)
+                svc.register_tenant(f"t{t}", bank, head=head)
+                protos[f"t{t}"] = p
+            reqs = []
+            for i, (tid, p) in enumerate(sorted(protos.items())):
+                f, _ = svc_lib.sample_tenant_queries(7 + i, p, 40,
+                                                     noise=0.9)
+                reqs += [svc_lib.ClassifyRequest(tid, f[j])
+                         for j in range(40)]
+            return svc, reqs
+
+        def answers(svc, reqs):
+            return [(r.tenant_id, r.pred, r.escalated,
+                     np.float32(r.margin).view(np.int32))
+                    for r in svc.serve(reqs)]
+
+        def placed(svc):
+            h, s = svc.health(), svc.registry.stats()
+            assert h["bank_placements"] == s["bank_placements"]
+            assert h["bank_bytes_placed"] == s["bank_bytes_placed"]
+            return h["bank_placements"], h["bank_bytes_placed"]
+
+        one, reqs = boot(1)
+        want = answers(one, reqs)
+        svc, _ = boot(2)
+        reg = svc.registry
+        assert placed(svc) == (0, 0)  # registration places nothing
+        ticks = svc.scheduler.stats.ticks
+        assert answers(svc, reqs) == want
+        assert svc.scheduler.stats.ticks - ticks == 20
+        assert placed(svc) == (1, reg.placement_bytes())
+        assert answers(svc, reqs) == want  # 20 more ticks
+        assert placed(svc) == (1, reg.placement_bytes())
+        svc.reconfigure(svc.spec._replace(mesh=spec_lib.MeshSpec(
+            bank_shards=4)))
+        assert reg.bank_shards == 4 and placed(svc)[0] == 1
+        assert answers(svc, reqs) == want
+        n, b = placed(svc)
+        assert n == 2
+        svc.rolling_reshard(svc.spec._replace(mesh=spec_lib.MeshSpec(
+            bank_shards=2)))
+        assert answers(svc, reqs) == want
+        assert placed(svc) == (3, b + reg.placement_bytes())
+        print("OK")
+        """)
+    assert out.strip().endswith("OK")
