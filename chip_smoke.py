@@ -200,11 +200,13 @@ def tick_hlo(svc) -> str:
 
     sched, reg = svc.scheduler, svc.registry
     place, mesh_gen = sched._placement(sched.engine_config)
+    head = sched.head_fn(place) if sched.head_fn else None
     packed = jax.device_put(np.zeros((SLOTS, NUM_FEATURES + 4), np.int32),
                             None if place is None else place.batch)
     return _packed_tick.lower(
         reg.device_bank(place), reg.thresholds_table(place), packed,
-        classify=_batched_classify, config=sched.engine_config,
+        *(head[1:] if head else ()), classify=_batched_classify,
+        head=head[0] if head else None, config=sched.engine_config,
         mesh_gen=mesh_gen).compile().as_text()
 
 

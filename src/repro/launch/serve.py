@@ -212,7 +212,8 @@ def run_acam(args) -> dict:
           f"(occupancy {m['occupancy']:.2f}), accuracy {acc:.4f}")
     print(f"  escalation rate {m['escalation_rate']:.3f} "
           f"({m['escalated']} escalated, "
-          f"{m['escalation_dispatches']} head dispatches), "
+          f"{m['escalation_dispatches']} escalating ticks, "
+          f"{m['head_in_tick']} answered in the tick), "
           f"{m['nj_per_request']:.2f} nJ/request, "
           f"{m['requests_per_s']:.1f} req/s, "
           f"p50 {m['latency_p50_ms']:.1f} ms / p99 {m['latency_p99_ms']:.1f} ms")

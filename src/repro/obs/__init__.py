@@ -123,8 +123,11 @@ class FlightRecorder:
             "frontend=CNN head) and tenant")
         self.esc_dispatches = r.counter(
             "acam_escalation_dispatches_total",
-            "coalesced dense-head dispatches (one per tick with "
-            "escalations)")
+            "ticks with escalations (their dense head runs inside the "
+            "tick's one dispatch)")
+        self.head_in_tick = r.counter(
+            "acam_head_in_tick_total",
+            "escalated answers taken from the tick's dense-head row")
         self.cache_events = r.counter(
             "acam_semantic_cache_events_total",
             "semantic-cache router outcomes "
@@ -270,6 +273,9 @@ class FlightRecorder:
 
     def record_escalation_dispatch(self) -> None:
         self.esc_dispatches.inc()
+
+    def record_head_in_tick(self, n: int) -> None:
+        self.head_in_tick.inc(n)
 
     def finish_request(self, resp, backend_j: float,
                        frontend_j: float) -> None:

@@ -34,14 +34,17 @@ event. The shed_p99_ms overload check reads the SAME histogram quantile
 With `obs.profile_annotations` on, a step is traced in the recorder's spans
 (`FlightRecorder.span`): ``acam.step`` around the whole step, holding the
 scheduler's ``sched.tick`` (`repro.serve.scheduler`), ``acam.escalate``
-(the escalation's pack, head tables, dispatch and copy-back; stats ``n``
-escalated and ``bucket``) and ``acam.finalize`` (the per-request
-accounting). What ``acam.step`` holds outside them is the cascade loop and
-the building of the responses.
+(the escalated answers read from the tick's result; stat ``n`` escalated)
+and ``acam.finalize`` (the per-request accounting). What ``acam.step``
+holds outside them is the cascade loop and the building of the responses.
 
-Escalated slots from one tick are themselves coalesced into one dense-head
-dispatch (padded to power-of-two buckets so the escalation path compiles a
-handful of shapes, ever). Tenants without a registered head never escalate.
+The dense head is answered inside the scheduler's tick: the service
+installs ``head_fn`` beside ``tau_fn``, and the tick's one jitted call
+computes every slot's head class over the features it already holds
+(`SlotResult.head_pred`), so a step that escalates makes one upload, one
+call and one copy back, as one that does not. The stacked head tables are
+placed once per head generation and mesh placement. Tenants without a
+registered head never escalate.
 
 `make_synthetic_tenant` / `sample_tenant_queries` build deterministic
 per-tenant banks + matching nearest-centroid heads without training a CNN —
@@ -125,17 +128,10 @@ class _TenantRuntime:
     backend_j: float  # Eq. 14 energy of this tenant's programmed rows
 
 
-def _bucket(n: int, cap: int) -> int:
-    """Next power-of-two >= n, capped (escalation batch shape buckets)."""
-    b = 1
-    while b < n:
-        b *= 2
-    return min(b, cap)
-
-
 @jax.jit
 def _escalate_heads(w_table, b_table, feats, head_slot, n_classes):
-    """One dense-head dispatch for all escalated slots of a tick.
+    """The dense head over a batch of slots: traced into the scheduler's
+    tick (`MicroBatchScheduler.head_fn`), or called alone.
 
     Gathers each slot's tenant head from the stacked table and masks class
     columns beyond the tenant's true class count.
@@ -218,6 +214,7 @@ class ACAMService:
         # this per dispatched request and the margin < tau compare happens
         # in the fused dispatch (SlotResult.escalate), not here in python
         self.scheduler.tau_fn = self._margin_tau_of
+        self.scheduler.head_fn = self._head_of_tick
         self.scheduler.monitor.sink = self.obs.record_straggler
         self.obs.slots_gauge.set(spec.scheduler.slots)
         #: control-plane failure state (simulated device loss): None = every
@@ -228,8 +225,13 @@ class ACAMService:
         self._tenants: dict[str, _TenantRuntime] = {}
         self._head_w: np.ndarray | None = None  # (T_cap, N, C_head)
         self._head_b: np.ndarray | None = None  # (T_cap, C_head)
-        self._head_cache: tuple[int, jnp.ndarray, jnp.ndarray] | None = None
+        #: ((head generation, mesh generation), placement, (W, b)): the one
+        #: copy of the head tables on the device
+        self._head_cache: tuple | None = None
         self._head_gen = 0
+        #: head-table placements on the device: one per head generation,
+        #: mesh generation and placement that served, none per tick
+        self.head_placements = 0
         self._next_id = 0
         self._apply_cascade(spec)
 
@@ -360,11 +362,33 @@ class ACAMService:
         self._head_gen += 1
         self._head_cache = None
 
-    def _head_tables(self) -> tuple[jnp.ndarray, jnp.ndarray]:
-        if self._head_cache is None or self._head_cache[0] != self._head_gen:
-            self._head_cache = (self._head_gen, jnp.asarray(self._head_w),
-                                jnp.asarray(self._head_b))
-        return self._head_cache[1], self._head_cache[2]
+    def _head_tables(self, placement=None) -> tuple[jax.Array, jax.Array]:
+        """The stacked (W, b) head tables on the device, put from host
+        memory once per head generation and mesh generation: whole on every
+        device of a sharded plan (``placement.table``), else uncommitted on
+        the default device. The device holds one copy: a call without a
+        placement is given the copy already placed, and a new placement
+        replaces the old copy."""
+        from repro.distributed import context
+
+        key = (self._head_gen, context.generation())
+        cache = self._head_cache
+        if cache is None or cache[0] != key or \
+                placement not in (None, cache[1]):
+            table = None if placement is None else placement.table
+            cache = self._head_cache = (
+                key, placement, (jax.device_put(self._head_w, table),
+                                 jax.device_put(self._head_b, table)))
+            self.head_placements += 1
+        return cache[2]
+
+    def _head_of_tick(self, placement):
+        """The scheduler's `head_fn`: the dense head (this module's
+        `_escalate_heads` as it stands at call time) and its tables on the
+        tick's placement; None while no tenant has registered a head."""
+        if self._head_w is None:
+            return None
+        return (_escalate_heads, *self._head_tables(placement))
 
     # -- request path -------------------------------------------------------
 
@@ -562,23 +586,21 @@ class ACAMService:
         return self._frontend_j
 
     def _run_escalation(self, escalate: list[SlotResult]) -> dict[int, int]:
-        """Coalesce a tick's escalated slots into one dense-head dispatch
-        (span ``acam.escalate``)."""
-        size = _bucket(len(escalate), self.config.slots)
-        with self.obs.span("acam.escalate", n=len(escalate), bucket=size):
-            feats = np.zeros((size, self.registry.num_features), np.float32)
-            slot = np.zeros((size,), np.int32)
-            ncls = np.ones((size,), np.int32)
-            for i, r in enumerate(escalate):
-                feats[i] = r.item.features
-                slot[i] = r.entry.slot
-                ncls[i] = r.entry.num_classes
-            w_table, b_table = self._head_tables()
-            pred = np.asarray(_escalate_heads(
-                w_table, b_table, jnp.asarray(feats), jnp.asarray(slot),
-                jnp.asarray(ncls)))
-            return {r.item.request_id: int(pred[i])
-                    for i, r in enumerate(escalate)}
+        """The escalated slots' dense-head answers, read from the tick's
+        head row (span ``acam.escalate``, stat ``n``).
+
+        Every escalated slot has one: a slot escalates only if its tenant
+        had a head when the tick packed it, so the head table existed when
+        the same tick asked ``head_fn`` for it."""
+        with self.obs.span("acam.escalate", n=len(escalate)):
+            missing = [r.item.request_id for r in escalate
+                       if r.head_pred < 0]
+            if missing:
+                raise RuntimeError(
+                    f"escalated requests {missing} have no head row: the "
+                    "scheduler ran its tick without the service's head_fn")
+            self.obs.record_head_in_tick(len(escalate))
+            return {r.item.request_id: r.head_pred for r in escalate}
 
     def drain(self) -> list[ClassifyResponse]:
         """Run ticks until the queue empties (the control plane's quiesce
@@ -623,6 +645,7 @@ class ACAMService:
             "shed_rate": round(shed / done, 4),
             "load_shed_ticks": int(o.load_shed_ticks.value()),
             "escalation_dispatches": int(o.esc_dispatches.value()),
+            "head_in_tick": int(o.head_in_tick.value()),
             "requests_per_s": round(completed / busy, 2) if busy else 0.0,
             "latency_p50_ms": round(o.latency_quantile_ms(0.50), 3),
             "latency_p99_ms": round(o.latency_quantile_ms(0.99), 3),
@@ -676,6 +699,9 @@ class ACAMService:
             # generation that served, none per tick
             "bank_placements": stats["bank_placements"],
             "bank_bytes_placed": stats["bank_bytes_placed"],
+            # stacked head tables on the device: one per head generation
+            # and mesh placement, none per tick
+            "head_placements": self.head_placements,
             # the resident serve kernel holds k_max * padded(rows/shard)
             # template rows in VMEM; past MAX_FUSED_ROWS it falls back to
             # the class-chunked path — headroom is the policy's VMEM signal

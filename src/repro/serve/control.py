@@ -208,6 +208,7 @@ class HybridService(ACAMService):
                 engine=new_spec.engine, monitor=self.scheduler.monitor,
                 recorder=self.obs)
             self.scheduler.tau_fn = self._margin_tau_of
+            self.scheduler.head_fn = self._head_of_tick
             stats.slots = new_spec.scheduler.slots
             self.scheduler.stats = stats
             self.obs.slots_gauge.set(new_spec.scheduler.slots)

@@ -57,6 +57,12 @@ runs `_batched_classify`, picks each slot's winner score out of
 escalate, and the bit patterns of margin and score) fetched by one
 `jax.device_get`. ``per_class`` never leaves the device. The counters
 `SchedulerStats.h2d_transfers` / ``d2h_transfers`` / ``bytes_back`` show it.
+The cascade's dense head rides in the same call: the service installs a
+``head_fn`` (placement -> the head and its stacked tables, or None with no
+head table) and `_packed_tick` then computes every slot's head class over
+the features it has unpacked, as a fifth result row (`SlotResult.head_pred`)
+— a tick that escalates crosses the boundary no more often than one that
+does not.
 Under a sharded plan the buffer is uploaded on the plan's batch sharding
 and the registry's arrays already sit on theirs (`repro.match.Placement`),
 so the jitted tick reshards nothing between devices.
@@ -111,6 +117,9 @@ class SlotResult:
     #: relative — a one-row class window clamps it to the cap — so absolute
     #: acceptance floors (the semantic cache's hit_score) read this.
     score: float = 0.0
+    #: the dense head's tenant-local class for this slot, computed in the
+    #: tick (`MicroBatchScheduler.head_fn`); -1 when the tick ran no head
+    head_pred: int = -1
 
 
 @dataclasses.dataclass
@@ -200,9 +209,10 @@ def _as_i32(x):
     return lax.bitcast_convert_type(x, jnp.int32)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("classify", "config", "mesh_gen"))
-def _packed_tick(bank, thr_table, packed, *, classify, config, mesh_gen: int):
+@functools.partial(jax.jit, static_argnames=("classify", "head", "config",
+                                             "mesh_gen"))
+def _packed_tick(bank, thr_table, packed, w_table=None, b_table=None, *,
+                 classify, head=None, config, mesh_gen: int):
     """The tick between one upload and one copy back. ``packed`` is the
     int32 ``(slots, N + 4)`` buffer of `MicroBatchScheduler._pack`:
     features and tau as f32 bit patterns, tenant slot, window lo and hi.
@@ -214,16 +224,27 @@ def _packed_tick(bank, thr_table, packed, *, classify, config, mesh_gen: int):
 
     ``classify`` is `_batched_classify` as the module holds it at call time
     (static: replacing it keys a fresh trace); ``config`` and ``mesh_gen``
-    are passed through to it, static here too."""
+    are passed through to it, static here too. With a ``head`` (static
+    too: the service's `_escalate_heads` as its module holds it) and its
+    stacked tables, every slot's dense-head class over the same features
+    comes back as a fifth row, ``(5, slots)``: the head of the slot's
+    tenant, over the tenant's class count (the window's width; 1 for a
+    padding slot's empty window)."""
     n = packed.shape[1] - 4
+    feats = _as_f32(packed[:, :n])
     pred, per_class, margin, esc = classify(
-        bank, thr_table, _as_f32(packed[:, :n]), packed[:, n + 1],
-        packed[:, n + 2], packed[:, n + 3], _as_f32(packed[:, n]),
-        config=config, mesh_gen=mesh_gen)
+        bank, thr_table, feats, packed[:, n + 1], packed[:, n + 2],
+        packed[:, n + 3], _as_f32(packed[:, n]), config=config,
+        mesh_gen=mesh_gen)
     score = jnp.take_along_axis(per_class, pred[:, None], axis=1)[:, 0]
     score = jnp.where(jnp.isfinite(score), score, 0.0)
-    return jnp.stack([pred.astype(jnp.int32), esc.astype(jnp.int32),
-                      _as_i32(margin), _as_i32(score)])
+    rows = [pred.astype(jnp.int32), esc.astype(jnp.int32), _as_i32(margin),
+            _as_i32(score)]
+    if head is not None:
+        ncls = jnp.maximum(packed[:, n + 3] - packed[:, n + 2], 1)
+        rows.append(head(w_table, b_table, feats, packed[:, n + 1],
+                         ncls).astype(jnp.int32))
+    return jnp.stack(rows)
 
 
 class MicroBatchScheduler:
@@ -273,6 +294,12 @@ class MicroBatchScheduler:
         #: cascade's ``margin < tau`` compare runs inside the serve kernel.
         #: None (or a None return) pins tau to -inf: never escalate.
         self.tau_fn = None
+        #: optional placement -> (head, w_table, b_table) or None: the
+        #: cascade's dense head and its stacked tables on the placement's
+        #: table sharding, installed by the service layer beside
+        #: ``tau_fn``. When it gives a head, the tick computes every slot's
+        #: head class in the same call (`SlotResult.head_pred`).
+        self.head_fn = None
         self._queue: deque[WorkItem] = deque()
         self._place_key = None
         self._place = None
@@ -346,12 +373,16 @@ class MicroBatchScheduler:
                         with obs.span("registry.place", shards=shards,
                                       bytes=reg.placement_bytes()):
                             reg.device_bank(place)
+                    head = None if self.head_fn is None \
+                        else self.head_fn(place)
                     dev = jax.device_put(
                         packed, None if place is None else place.batch)
                     self.stats.h2d_transfers += 1
                     out = _packed_tick(
                         reg.device_bank(place), reg.thresholds_table(place),
-                        dev, classify=_batched_classify, config=cfg,
+                        dev, *(head[1:] if head else ()),
+                        classify=_batched_classify,
+                        head=head[0] if head else None, config=cfg,
                         mesh_gen=mesh_gen)
                 with obs.span("sched.fetch", bytes=out.nbytes):
                     res = jax.device_get(out)
@@ -365,11 +396,12 @@ class MicroBatchScheduler:
 
             pred, esc = res[0], res[1]
             margin, score = res[2].view(np.float32), res[3].view(np.float32)
+            head_pred = res[4] if len(res) > 4 else np.full_like(pred, -1)
             return dead + [
                 SlotResult(item=item, entry=entry,
                            pred_local=int(pred[i]) - entry.offset,
                            margin=float(margin[i]), escalate=bool(esc[i]),
-                           score=float(score[i]))
+                           score=float(score[i]), head_pred=int(head_pred[i]))
                 for i, (item, entry) in enumerate(batch)]
 
     def _placement(self, cfg: match_lib.EngineConfig):

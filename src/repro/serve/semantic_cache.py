@@ -378,7 +378,7 @@ class SemanticCacheService(HybridService):
         """Route a tick's escalations: job-backed slots (LM prompts) decode
         through ONE `Engine.generate` call in slot order; anything else
         (classify tenants sharing the service) falls through to the base
-        dense-head dispatch."""
+        cascade, which reads the dense head's answer from the tick."""
         misses = [r for r in escalate if r.item.request_id in self._jobs]
         rest = [r for r in escalate if r.item.request_id not in self._jobs]
         out: dict[int, int] = {}

@@ -70,10 +70,11 @@ class TestServiceEndToEnd:
         assert stats.ticks == expected_ticks
         # one fused dispatch per tick, and each crosses the host-device
         # boundary once each way: one upload of the packed operands, one
-        # copy back of 16 bytes per slot (pred, escalate, margin, score)
+        # copy back of 20 bytes per slot (pred, escalate, margin, score and
+        # the dense head's class: every tenant here has a head)
         assert stats.classify_dispatches == stats.h2d_transfers == \
             stats.d2h_transfers == expected_ticks
-        assert stats.bytes_back == 16 * SLOTS * expected_ticks
+        assert stats.bytes_back == 20 * SLOTS * expected_ticks
         assert len(responses) == len(reqs)
 
     def test_per_tenant_predictions_match_reference(self, served):

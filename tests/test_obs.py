@@ -290,10 +290,12 @@ class TestSpans:
         assert [ev[3]["fill"] for ev in ticks] == \
             list(svc.obs.fill_window)[-len(ticks):]
         assert sum(ev[3]["fill"] for ev in ticks) == len(responses)
+        # one acam.escalate per escalating tick, each a read of the tick's
+        # head row: every escalated answer came from the tick itself
         esc = by_name["acam.escalate"]
         assert len(esc) == m["escalation_dispatches"] > 0
         assert sum(ev[3]["n"] for ev in esc) == m["escalated"]
-        assert all(ev[3]["bucket"] >= ev[3]["n"] for ev in esc)
+        assert m["head_in_tick"] == m["escalated"]
         # every span lies inside one span of its parent's name
         for s, e, name, _ in spans:
             parent = SPAN_PARENT[name]

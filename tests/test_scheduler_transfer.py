@@ -15,7 +15,11 @@
     ``jax.transfer_guard("disallow")`` with answers bit-equal to one bank
     shard; the registry places its arrays once per generation (fleet
     registration, a drained reshard, a rolling reshard's flip) and never
-    per tick.
+    per tick;
+  * a dense-head service answers escalations from the tick's fifth row:
+    the answers of `_escalate_heads` called directly, one upload and one
+    copy back per escalating step, the head tables placed once per head
+    generation (whole on every device under a bank sharding, 1x4 and 2x2).
 """
 import os
 import time
@@ -372,4 +376,255 @@ def test_bank_placed_once_per_generation():
         assert placed(svc) == (3, b + reg.placement_bytes())
         print("OK")
         """)
+    assert out.strip().endswith("OK")
+
+
+# -- the dense head inside the tick -----------------------------------------
+
+HEAD_SLOTS = 64
+
+
+def _head_service(backend, shards=1):
+    """A dense-head `HybridService` over four tenants with a head and one
+    ("bare") without -> (service, requests mixing all five)."""
+    from repro.match.config import EngineConfig
+    from repro.serve import spec as spec_lib
+    from repro.serve.control import HybridService
+
+    svc = HybridService.from_spec(spec_lib.ServiceSpec(
+        registry=spec_lib.RegistrySpec(num_features=N, class_bucket=8,
+                                       initial_classes=64),
+        engine=EngineConfig(backend=backend, margin=True),
+        mesh=spec_lib.MeshSpec(bank_shards=shards),
+        scheduler=spec_lib.SchedulerSpec(slots=HEAD_SLOTS),
+        cascade=spec_lib.CascadeSpec(tau=10.0, tau_units="count")))
+    reqs = []
+    for t, tid in enumerate(["t0", "t1", "t2", "t3", "bare"]):
+        bank, head, p = svc_lib.make_synthetic_tenant(
+            500 + t, num_classes=5 + t, num_features=N)
+        svc.register_tenant(tid, bank, head=None if tid == "bare" else head)
+        f, _ = svc_lib.sample_tenant_queries(11 + t, p, 60, noise=0.9)
+        reqs += [svc_lib.ClassifyRequest(tid, f[j]) for j in range(60)]
+    order = np.random.RandomState(0).permutation(len(reqs))
+    return svc, [reqs[i] for i in order]
+
+
+def _recorded_ticks(svc):
+    """Wrap the scheduler's tick: every tick's `SlotResult`s, in order."""
+    ticks, tick = [], svc.scheduler.tick
+
+    def recorded():
+        out = tick()
+        ticks.append(out)
+        return out
+
+    svc.scheduler.tick = recorded
+    return ticks
+
+
+@pytest.mark.parametrize("fill", [HEAD_SLOTS, 37], ids=["full", "ragged"])
+@pytest.mark.parametrize("backend", ["reference", "kernel"])
+def test_head_in_tick_equals_head_called_directly(backend, fill):
+    """Each response's pred, escalated and margin are what the tick's
+    winner and `_escalate_heads`, called directly on the tick's escalated
+    slots, give; every escalated answer was taken from the tick's row."""
+    svc, reqs = _head_service(backend)
+    ticks = _recorded_ticks(svc)
+    responses = []
+    for i in range(0, 2 * fill, fill):
+        for r in reqs[i:i + fill]:
+            svc.submit(r)
+        responses += svc.step()
+    assert [len(t) for t in ticks] == [fill, fill]
+    by_id = {r.request_id: r for r in responses}
+    w, b = svc._head_tables()
+    n_esc = 0
+    for tick in ticks:
+        assert any(r.item.tenant_id == "bare" for r in tick)
+        esc = [r for r in tick if r.escalate]
+        assert esc and all(r.item.tenant_id != "bare" for r in esc)
+        head = np.asarray(svc_lib._escalate_heads(
+            w, b, jnp.asarray(np.stack([r.item.features for r in esc])),
+            jnp.asarray([r.entry.slot for r in esc], jnp.int32),
+            jnp.asarray([r.entry.num_classes for r in esc], jnp.int32)))
+        want = {r.item.request_id: int(h) for r, h in zip(esc, head)}
+        for r in tick:
+            got = by_id[r.item.request_id]
+            assert got.escalated == r.escalate
+            assert got.pred == want.get(r.item.request_id, r.pred_local)
+            assert np.float32(got.margin).view(np.int32) == \
+                np.float32(r.margin).view(np.int32)
+        n_esc += len(esc)
+    m = svc.metrics()
+    assert m["escalated"] == n_esc
+    assert m["head_in_tick"] == m["escalated"]
+    assert m["escalation_dispatches"] == 2
+
+
+def test_escalating_step_one_upload_one_copy_back(monkeypatch):
+    """A step whose tick escalates makes one `jax.device_put` and one
+    `jax.device_get`, and calls the dense head nowhere but inside the
+    traced tick."""
+    svc, reqs = _head_service("reference")
+    calls = []
+    head = svc_lib._escalate_heads
+
+    def spy_head(*args):
+        calls.append(any(isinstance(a, jax.core.Tracer) for a in args))
+        return head(*args)
+
+    monkeypatch.setattr(svc_lib, "_escalate_heads", spy_head)
+    for r in reqs[:HEAD_SLOTS]:
+        svc.submit(r)
+    svc.step()  # compiles the tick with the head and places its tables
+    assert calls == [True]
+    puts, gets = [], []
+    put, get = jax.device_put, jax.device_get
+
+    def spy_put(x, *a, **k):
+        puts.append(x)
+        return put(x, *a, **k)
+
+    def spy_get(x, *a, **k):
+        gets.append(x)
+        return get(x, *a, **k)
+
+    monkeypatch.setattr(jax, "device_put", spy_put)
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    svc.reset_metrics()
+    steps = 0
+    for i in range(HEAD_SLOTS, len(reqs) - HEAD_SLOTS, HEAD_SLOTS):
+        for r in reqs[i:i + HEAD_SLOTS]:
+            svc.submit(r)
+        del puts[:], gets[:]
+        responses = svc.step()
+        assert any(r.escalated for r in responses)
+        assert len(puts) == len(gets) == 1
+        assert puts[0].shape == (HEAD_SLOTS, N + 4)
+        assert gets[0].shape == (5, HEAD_SLOTS)
+        steps += 1
+    assert calls == [True]  # no call outside the one trace
+    m = svc.metrics()
+    assert m["escalation_dispatches"] == steps > 1
+    assert m["head_in_tick"] == m["escalated"] > 0
+    st = svc.scheduler.stats
+    assert st.h2d_transfers == st.d2h_transfers == st.ticks == steps
+    assert st.bytes_back == 20 * HEAD_SLOTS * steps
+
+
+def test_escalation_without_head_row_fails():
+    """A scheduler driven without the service's ``head_fn`` returns no
+    head row (``head_pred`` -1); the service refuses to answer such an
+    escalation rather than dispatch the head a second time."""
+    svc, reqs = _head_service("reference")
+    svc.scheduler.head_fn = None
+    ticks = _recorded_ticks(svc)
+    for r in reqs[:HEAD_SLOTS]:
+        svc.submit(r)
+    with pytest.raises(RuntimeError, match="no head row"):
+        svc.step()
+    assert any(r.escalate for r in ticks[0])
+    assert all(r.head_pred == -1 for r in ticks[0])
+    assert svc.metrics()["head_in_tick"] == 0
+
+
+HEAD_SHARDED = """
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, %r)
+import jax
+import numpy as np
+from repro.serve import acam_service as svc_lib
+import test_scheduler_transfer as t
+
+SHARDS = %d
+one, reqs = t._head_service("kernel")
+
+
+def steps(s, n):
+    out = []
+    for i in range(n):
+        for r in reqs[(i * 37) %% 200:(i * 37) %% 200 + t.HEAD_SLOTS]:
+            s.submit(r)
+        out.append([(r.tenant_id, r.pred, r.escalated,
+                     np.float32(r.margin).view(np.int32))
+                    for r in s.step()])
+    return out
+
+
+def placed(s):
+    return s.health()["head_placements"], s.registry.bank_placements
+
+
+want = steps(one, 20)
+svc, _ = t._head_service("kernel", shards=SHARDS)
+place, _ = svc.scheduler._placement(svc.scheduler.engine_config)
+assert place.shards == SHARDS
+assert place.batch.spec == jax.sharding.PartitionSpec(
+    "data" if SHARDS < 4 else None)
+assert steps(svc, 1) == want[:1]  # compiles, places bank and heads
+assert placed(svc) == (1, 1)
+w, b = svc._head_tables(place)
+assert w.sharding == b.sharding == place.table
+# a call without a placement is given the placed copy, not a second one
+assert svc._head_tables()[0] is w and placed(svc) == (1, 1)
+
+moved = []
+put, get = jax.device_put, jax.device_get
+
+
+def spy_put(x, *a, **k):
+    moved.append("put")
+    return put(x, *a, **k)
+
+
+def spy_get(x, *a, **k):
+    moved.append("get")
+    return get(x, *a, **k)
+
+
+def guarded(n):
+    del moved[:]
+    jax.device_put, jax.device_get = spy_put, spy_get
+    try:
+        with jax.transfer_guard("disallow"):
+            got = steps(svc, n)
+    finally:
+        jax.device_put, jax.device_get = put, get
+    assert moved == ["put", "get"] * n, moved
+    assert all(any(r[2] for r in step) for step in got)
+    return got
+
+
+assert guarded(20) == want
+assert placed(svc) == (1, 1)
+m = svc.metrics()
+assert m["head_in_tick"] == m["escalated"] > 0
+
+# a new tenant's head places the tables once more, and only once
+bank, head, p = svc_lib.make_synthetic_tenant(599, num_classes=7,
+                                              num_features=t.N)
+svc.register_tenant("late", bank, head=head)
+steps(svc, 1)
+assert placed(svc) == (2, 2)
+guarded(20)
+assert placed(svc) == (2, 2)
+svc.update_tenant("late", bank, head=head)
+steps(svc, 1)
+assert placed(svc) == (3, 3)
+guarded(20)
+assert placed(svc) == (3, 3)
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("shards", [4, 2], ids=["1x4", "2x2"])
+def test_sharded_head_tables_placed_once(shards):
+    """Four CPU devices, the bank sharded four ways (1x4 mesh) or two ways
+    with a batch-sharded buffer (2x2 mesh): 20 escalating steps under
+    ``jax.transfer_guard("disallow")`` move the packed buffer in and the
+    result out and nothing else, with the answers of one shard; the head
+    tables sit whole on every device, placed once, and once more after a
+    tenant's head is registered or updated."""
+    out = run_sub(HEAD_SHARDED % (os.path.dirname(__file__), shards))
     assert out.strip().endswith("OK")
