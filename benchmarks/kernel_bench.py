@@ -15,17 +15,16 @@ BENCH_kernels.json schema::
     {"backend": "cpu" | "tpu",
      "interpret": bool,            # kernels ran via the pallas interpreter
      "entries": [
-       {"kernel": "acam_match",    # | acam_similarity | *_classify_fused
+       {"kernel": "acam_match",    # | acam_similarity
                                    # | acam_device_classify (RRAM physics)
                                    # | acam_match_serve /
-                                   #   acam_similarity_serve (the resident
-                                   #   serving mega-kernel; ref_us = the
-                                   #   pre-megakernel compose path, so
-                                   #   speedup IS the fusion win)
-                                   # | acam_similarity_classify_chunked
+                                   #   acam_similarity_serve (the serving
+                                   #   mega-kernel; ref_us = the jnp
+                                   #   reference's classify_serve)
+                                   # | acam_similarity_face_chunked
                                    #   (big-bank single-dispatch similarity;
                                    #   ref_us = jnp oracle)
-                                   # | acam_match_classify_sharded
+                                   # | acam_match_face_sharded
                                    #   (bank rows sharded over the model
                                    #   axis; ref_us = replicated engine,
                                    #   kernel_us = sharded engine, extra
@@ -41,10 +40,10 @@ BENCH_kernels.json schema::
         "ref_cell_matches_per_us": ...,    # b*m*n / us
         "kernel_cell_matches_per_us": ...}]}
 
-The raw ``acam_match``/``acam_similarity`` rows time the two-stage Pallas
+The raw ``acam_match``/``acam_similarity`` rows time the raw-scores Pallas
 kernels directly against their jnp oracles (kernel micro-benchmarks); the
-``*_classify*`` rows go through `repro.match.MatchEngine` — the exact path
-production callers execute.
+``*_classify*`` and ``*_serve`` rows go through `repro.match.MatchEngine` —
+the exact path production callers execute.
 
 ``--tune`` grid-searches kernel block sizes first (repro.kernels.tuning —
 the winners persist to the v2 JSON cache keyed by
@@ -104,7 +103,8 @@ def _compare_entry(kernel: str, b: int, m: int, n: int, ref_us: float,
 
 
 def compare_kernels(batches=BENCH_SHAPES, *, iters=10) -> list[dict]:
-    """Ref-vs-kernel timing entries for both ACAM kernels + the fused path."""
+    """Ref-vs-kernel timing entries for both raw-scores ACAM kernels + the
+    device-physics classify."""
     from repro.core import templates as T
     from repro.kernels.acam_match import ops as match_ops
     from repro.kernels.acam_match.ref import acam_match_ref
@@ -140,21 +140,14 @@ def compare_kernels(batches=BENCH_SHAPES, *, iters=10) -> list[dict]:
                                       ker_us))
 
         # end-to-end classify through the engine layer (what production
-        # callers execute): reference vs kernel (fused binarize->match->WTA)
-        # vs the RRAM-device-physics backend
+        # callers execute): reference vs the RRAM-device-physics backend
         from repro import match
 
         eng_ref = match.engine_for(backend="reference")
-        eng_ker = match.engine_for(backend="kernel")
         eng_dev = match.engine_for(backend="device")
 
         ref_us = _time(jax.jit(lambda feats: eng_ref.classify_features(
             feats, bank)), f, iters=it)
-        ker_us = _time(jax.jit(lambda feats: eng_ker.classify_features(
-            feats, bank)), f, iters=it)
-        entries.append(_compare_entry("acam_match_classify_fused", b, M, N,
-                                      ref_us, ker_us))
-
         dev_us = _time(jax.jit(lambda feats: eng_dev.classify_features(
             feats, bank)), f, iters=it)
         entries.append(_compare_entry("acam_device_classify", b, M, N,
@@ -163,14 +156,12 @@ def compare_kernels(batches=BENCH_SHAPES, *, iters=10) -> list[dict]:
 
 
 def serve_entries(batches=BENCH_SHAPES, *, iters: int = 10) -> list[dict]:
-    """Mega-kernel vs compose rows for the multi-tenant serve path.
+    """Serve-kernel rows for the multi-tenant serve path.
 
-    Times `MatchEngine.classify_serve` (the scheduler tick's dispatch) with
-    ``serve_fusion="mega"`` (ONE resident pallas_call) against
-    ``serve_fusion="compose"`` (jnp gather/shift + fused margins kernel +
-    jnp tau compare) — same kernel backend both sides, so the speedup
-    column IS the fusion win. Plus the big-bank chunked-similarity row
-    against its jnp oracle (the coverage the similarity method gained)."""
+    Times `MatchEngine.classify_serve` (the scheduler tick's dispatch) on
+    the kernel backend (ONE pallas_call) against the reference backend's
+    jnp composition. Plus the big-bank chunked-similarity row against its
+    jnp oracle."""
     from repro import match
     from repro.core import templates as T
 
@@ -195,15 +186,15 @@ def serve_entries(batches=BENCH_SHAPES, *, iters: int = 10) -> list[dict]:
         it = max(3, iters // 4) if b >= 4096 else iters
         for method, name in (("feature_count", "acam_match_serve"),
                              ("similarity", "acam_similarity_serve")):
-            mega = match.engine_from_config(match.EngineConfig(
-                method=method, backend="kernel", serve_fusion="mega"))
-            comp = match.engine_from_config(match.EngineConfig(
-                method=method, backend="kernel", serve_fusion="compose"))
-            comp_us = _time(jax.jit(lambda x, s, t, e=comp: e.classify_serve(
+            ker = match.engine_from_config(match.EngineConfig(
+                method=method, backend="kernel"))
+            ref = match.engine_from_config(match.EngineConfig(
+                method=method, backend="reference"))
+            ref_us = _time(jax.jit(lambda x, s, t, e=ref: e.classify_serve(
                 x, thr_table, s, bank, tau=t)), f, slot, tau, iters=it)
-            mega_us = _time(jax.jit(lambda x, s, t, e=mega: e.classify_serve(
+            ker_us = _time(jax.jit(lambda x, s, t, e=ker: e.classify_serve(
                 x, thr_table, s, bank, tau=t)), f, slot, tau, iters=it)
-            entries.append(_compare_entry(name, b, M, N, comp_us, mega_us))
+            entries.append(_compare_entry(name, b, M, N, ref_us, ker_us))
 
     # big-bank chunked similarity: C=1100, K=2 exceeds the fused budget
     c_big, k_big = 1100, 2
@@ -222,7 +213,7 @@ def serve_entries(batches=BENCH_SHAPES, *, iters: int = 10) -> list[dict]:
             x, big_bank)), f, iters=max(3, iters // 2))
         ker_us = _time(jax.jit(lambda x: eng_ker.classify_features_margin(
             x, big_bank)), f, iters=max(3, iters // 2))
-        entries.append(_compare_entry("acam_similarity_classify_chunked", b,
+        entries.append(_compare_entry("acam_similarity_face_chunked", b,
                                       c_big * k_big, N, ref_us, ker_us))
     return entries
 
@@ -279,7 +270,7 @@ def sharded_classify_entries(batches=BENCH_SHAPES, *, classes: int = 512,
         context.clear()
         rep_us = _time(jax.jit(
             lambda x: eng.classify_features(x, bank)), f, iters=it)
-        e = _compare_entry("acam_match_classify_sharded", b, classes, N,
+        e = _compare_entry("acam_match_face_sharded", b, classes, N,
                            rep_us, sharded_us)
         e["bank_sharding"] = shards
         e["reduce"] = plan.reduce  # cross-shard strategy the plan selected

@@ -59,12 +59,6 @@ every serving span on — asserts preds/margins/escalations are bit-identical
 either way, and records ``telemetry_overhead_pct`` (the tests hold the
 same comparison under 5%).
 
-The **mega-kernel row** (`megakernel_bench`, name ``serving_megakernel``)
-uses the same twice-served protocol to price the resident serve kernel:
-``serve_fusion="compose"`` (the pre-fusion tick) vs ``"mega"`` (ONE
-pallas_call per tick) at tenants=8, slots=32, bit-identity asserted,
-``megakernel_speedup_pct`` + both us/request medians recorded.
-
 The **LM semantic-cache rows** (`lm_cache_bench`) price the ACAM tier as
 a router in front of the continuous-batching decode engine:
 ``serving_lm_decode_only`` (marker ``lm_baseline``) is the bare
@@ -404,87 +398,6 @@ def telemetry_overhead_bench(*, smoke: bool = False, seed: int = 0) -> dict:
     }
     print(f"telemetry overhead: {entry['telemetry_overhead_pct']:+.2f}% "
           f"({base_us:.1f} -> {tel_us:.1f} us/request, bit-identical "
-          "results)")
-    return entry
-
-
-def megakernel_bench(*, smoke: bool = False, seed: int = 0) -> dict:
-    """The resident serve mega-kernel's win over the composed tick.
-
-    Serves the IDENTICAL request stream through two spec-built services
-    that differ only in ``EngineConfig.serve_fusion`` — "compose" (the
-    pre-megakernel jnp gather/shift + fused margins kernel + jnp tau
-    compare) vs "mega" (gather, binarize, match, windowed margin and the
-    escalation mask in ONE resident pallas_call) — at tenants=8, slots=32.
-    Interleaved passes + per-arm median us/request, same protocol as
-    `telemetry_overhead_bench`; preds/margins/escalations must be
-    bit-identical (the fusion is a pure execution change)."""
-    from repro.serve import acam_service as svc_lib
-    from repro.serve.control import HybridService
-
-    tenants, slots = 8, 32
-    requests = 256 if smoke else 1024
-
-    def build(serve_fusion):
-        spec = make_spec(slots, requests=requests)
-        spec = spec._replace(engine=spec.engine._replace(
-            serve_fusion=serve_fusion))
-        svc = HybridService.from_spec(spec)
-        protos = []
-        for t in range(tenants):
-            bank, head, p = svc_lib.make_synthetic_tenant(
-                seed * 1000 + t, num_classes=NUM_CLASSES,
-                num_features=NUM_FEATURES)
-            svc.register_tenant(f"t{t}", bank, head=head)
-            protos.append(p)
-        rng = np.random.RandomState(seed)
-        reqs = []
-        for i, t in enumerate(rng.randint(0, tenants, size=requests)):
-            feats, _ = svc_lib.sample_tenant_queries(seed + i, protos[t], 1,
-                                                     noise=0.8)
-            reqs.append(svc_lib.ClassifyRequest(f"t{t}", feats[0]))
-        svc.serve(reqs)  # full-stream warmup: compile every batch shape
-        return svc, reqs
-
-    def measure(svc, reqs):
-        svc.reset_metrics()
-        sig = [(r.tenant_id, r.pred, r.escalated, round(r.margin, 6))
-               for r in svc.serve(reqs)]
-        return svc.metrics(), sig
-
-    comp_svc, comp_reqs = build("compose")
-    mega_svc, mega_reqs = build("mega")
-    comp_us_all, mega_us_all = [], []
-    comp_sig = mega_sig = mega_m = None
-    for _ in range(9):
-        m, comp_sig = measure(comp_svc, comp_reqs)
-        comp_us_all.append(1e6 / m["requests_per_s"])
-        m, mega_sig = measure(mega_svc, mega_reqs)
-        mega_us_all.append(1e6 / m["requests_per_s"])
-        if mega_m is None or m["requests_per_s"] > mega_m["requests_per_s"]:
-            mega_m = m
-    assert mega_sig == comp_sig, \
-        "mega-kernel changed served results (must be a pure fusion)"
-    comp_us = float(np.median(comp_us_all))
-    mega_us = float(np.median(mega_us_all))
-    entry = {
-        "tenants": tenants, "slots": slots, "requests": requests,
-        "classes": NUM_CLASSES, "matching_backend": "default",
-        "bank_sharding": 1,
-        "megakernel_speedup_pct": round(100.0 * (comp_us - mega_us)
-                                        / comp_us, 2),
-        "compose_us_per_request": round(comp_us, 3),
-        "mega_us_per_request": round(mega_us, 3),
-        "requests_per_s": mega_m["requests_per_s"],
-        "latency_p50_ms": mega_m["latency_p50_ms"],
-        "latency_p99_ms": mega_m["latency_p99_ms"],
-        "escalation_rate": mega_m["escalation_rate"],
-        "nj_per_request": mega_m["nj_per_request"],
-        "occupancy": mega_m["occupancy"],
-        "classify_dispatches": mega_m["classify_dispatches"],
-    }
-    print(f"serve mega-kernel: {entry['megakernel_speedup_pct']:+.2f}% "
-          f"({comp_us:.1f} -> {mega_us:.1f} us/request, bit-identical "
           "results)")
     return entry
 
@@ -1051,8 +964,6 @@ def sweep(*, smoke: bool = False, seed: int = 0) -> list[dict]:
         entries.append(pilot_row)
     # telemetry tax: sinks-off vs full recorder on one identical stream
     entries.append(telemetry_overhead_bench(smoke=smoke, seed=seed))
-    # serve fusion win: composed tick vs the resident mega-kernel
-    entries.append(megakernel_bench(smoke=smoke, seed=seed))
     # ACAM-as-semantic-cache in front of LM decode: hit-rate sweep +
     # decode-only baseline (hit-path AND amortisation-bounded mean ratios)
     entries.extend(lm_cache_bench(smoke=smoke, seed=seed))
@@ -1093,8 +1004,6 @@ def _row_name(e: dict) -> str:
         return "serving_lm_decode_only"
     if "hit_rate" in e:
         return f"serving_lm_cache_h{int(round(e['hit_rate'] * 100))}"
-    if "megakernel_speedup_pct" in e:
-        return "serving_megakernel"
     if "telemetry_overhead_pct" in e:
         return "serving_telemetry_overhead"
     if "flip_downtime_ms" in e:
@@ -1122,10 +1031,6 @@ def _row_derived(e: dict) -> str:
                    f"x{e['hit_path_energy_ratio']}nJ"
                    if e["hit_path_speedup"] else "no-hits")
                 + f",{e['nj_per_request']:.1f}nJ/req")
-    if "megakernel_speedup_pct" in e:
-        return (f"speedup={e['megakernel_speedup_pct']}%,"
-                f"compose={e['compose_us_per_request']}us,"
-                f"mega={e['mega_us_per_request']}us")
     if "telemetry_overhead_pct" in e:
         return (f"overhead={e['telemetry_overhead_pct']}%,"
                 f"base={e['base_us_per_request']}us,"
@@ -1171,12 +1076,6 @@ def main() -> None:
                          "snapshot, assert bit-identity vs a clean build, "
                          "and append the recovery-time row to "
                          "BENCH_serving.json")
-    ap.add_argument("--megakernel", action="store_true",
-                    help="run ONLY the serve mega-kernel A/B: interleaved "
-                         "serve_fusion=mega vs =compose passes over the "
-                         "same request stream (bit-identical signatures "
-                         "asserted), then append/replace the "
-                         "serving_megakernel row in BENCH_serving.json")
     ap.add_argument("--lm-cache", action="store_true",
                     help="run ONLY the ACAM-semantic-cache-vs-LM-decode "
                          "sweep: decode-only baseline plus exact hit "
@@ -1252,21 +1151,6 @@ def main() -> None:
         else:
             write_bench_json(rows, path)
         print("appended lm semantic-cache rows to BENCH_serving.json")
-        return
-    if args.megakernel:
-        entry = megakernel_bench(smoke=args.smoke)
-        path = "BENCH_serving.json"
-        if os.path.exists(path):
-            with open(path) as f:
-                payload = json.load(f)
-            payload["entries"] = [
-                e for e in payload["entries"]
-                if "megakernel_speedup_pct" not in e] + [entry]
-            with open(path, "w") as f:
-                json.dump(payload, f, indent=1)
-        else:
-            write_bench_json([entry], path)
-        print("appended serve mega-kernel row to BENCH_serving.json")
         return
     if args.smoke:
         os.environ["REPRO_BENCH_FAST"] = "1"

@@ -2,7 +2,7 @@
 
 The matching implementation lives in **`repro.match`** (engine layer): a
 `MatchEngine` built from a hashable `EngineConfig`, a backend registry
-(`reference` jnp oracles / `kernel` Pallas fused+two-stage paths /
+(`reference` jnp oracles / `kernel` Pallas serve kernels /
 `device` RRAM-CMOS physics from `repro.core.acam`), and mesh-sharded
 execution over the data-parallel axes when `repro.distributed.context`
 holds a mesh. New code should use it directly:

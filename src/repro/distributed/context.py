@@ -10,7 +10,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 class MeshAxes(NamedTuple):
@@ -63,13 +63,17 @@ def constrain(x, *spec) -> jax.Array:
     """Apply a sharding constraint if a mesh context is active.
 
     spec entries: "dp", "model", or None — translated via the active axes.
+    When a mesh was installed with the axes, the constraint is bound to it,
+    so it holds outside any ``with mesh:`` block too.
     """
     ax = get()
     if ax is None:
         return x
-    resolved = tuple(ax.dp if s == "dp" else (ax.model if s == "model" else None)
-                     for s in spec)
-    return jax.lax.with_sharding_constraint(x, P(*resolved))
+    resolved = P(*(ax.dp if s == "dp" else (ax.model if s == "model" else None)
+                   for s in spec))
+    if _MESH is not None:
+        resolved = NamedSharding(_MESH, resolved)
+    return jax.lax.with_sharding_constraint(x, resolved)
 
 
 # --- ZeRO-3 gather-at-use -------------------------------------------------

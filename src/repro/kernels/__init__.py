@@ -4,8 +4,8 @@ Each kernel package ships <name>.py (the pallas_call), ops.py (public
 wrappers: interpret mode on CPU, compiled on TPU, block sizes resolved via
 the `repro.kernels.tuning` autotuner cache) and ref.py (pure-jnp oracle).
 
-`repro.core.matching` dispatches the ACAM hot path here by default; the
-fused classify variants use the K-major bank layout in
-`repro.kernels.layout`. Ref-vs-kernel timings are tracked in
-BENCH_kernels.json (benchmarks/kernel_bench.py).
+`repro.match` dispatches the ACAM hot path here by default; the serve
+kernels take the (K, Cp, N) bank stack of `repro.kernels.layout`.
+Ref-vs-kernel timings are tracked in BENCH_kernels.json
+(benchmarks/kernel_bench.py).
 """

@@ -23,49 +23,30 @@ resolves a tuned `(bm, bn, bk)` from its persistent JSON cache
 (``$REPRO_TUNING_CACHE``, default ``~/.cache/repro/pallas_blocks.json``,
 keyed ``kernel|backend|shape|dtype``) and falls back to `DEFAULT_BLOCK`.
 
-Three entry points:
+Two entry points:
 
-  `acam_match`          -> (B, M) match-count scores (two-stage path).
-  `acam_match_classify` -> fused binarize->match->valid-mask->per-class max
-                           ->argmax/WTA (Eq. 12) in ONE pallas_call: the
-                           (B, M) score matrix never round-trips to HBM.
-                           Templates arrive K-major (`repro.kernels.layout`)
-                           so the per-class max is K contiguous lane-aligned
-                           slices of the score row.
-  `acam_match_classify_margins`
-                        -> the serving variant: same fused pipeline, plus a
-                           per-row class *window* [class_lo, class_hi) (the
-                           tenant's contiguous class range in a multi-tenant
-                           super-bank) and the Eq. 12 winner-vs-runner-up
-                           **margin** — the confidence signal the hybrid
-                           cascade thresholds to decide accept-at-ACAM vs
-                           escalate to the CNN head.
-  `acam_match_classify_margins_chunked`
-                        -> the big-bank margins variant: the template rows
-                           arrive as a (K, Cp, N) stack and the grid tiles
-                           the *class* dimension in ``cc``-column chunks, so
-                           only K * cc template rows are VMEM-resident at a
-                           time while the per-class running max accumulates
-                           in a revisited (bm, Cp) block. Banks past the
-                           fused-row budget (`repro.match.MAX_FUSED_ROWS`)
-                           stay a SINGLE pallas_call instead of falling back
-                           to the two-stage kernel + jnp margin epilogue.
-  `acam_match_serve`    -> the resident serving mega-kernel: the whole
-                           multi-tenant scheduler tick in ONE pallas_call.
-                           On top of the chunked margins pipeline it folds
-                           the per-slot tenant *threshold-row gather* (a
-                           one-hot MXU select from the (T, N) thresholds
-                           table — exact under HIGHEST precision) and the
-                           cascade's escalation mask (margin < tau) into the
-                           kernel, so the tick's super-bank path never
-                           leaves VMEM and never runs a jnp epilogue. The
-                           class chunk degenerates to the full padded class
-                           count for banks inside the fused-row budget, so
-                           one kernel covers both resident and chunked
-                           regimes.
+  `acam_match`        -> (B, M) match-count scores, behind the engine's
+                         explicit `*_scores` entry points.
+  `acam_match_serve`  -> the classify kernel: threshold-row gather ->
+                         binarize -> match -> valid mask -> per-class max ->
+                         WTA (Eq. 12) -> windowed winner-vs-runner-up margin
+                         -> escalation mask, in one dispatch. The per-slot
+                         tenant thresholds come from a (T, N) table by a
+                         one-hot MXU select (exact under HIGHEST precision);
+                         the margin is the confidence signal the hybrid
+                         cascade thresholds to accept at the ACAM or
+                         escalate to the CNN head. Templates arrive as a
+                         (K, Cp, N) stack (`repro.kernels.layout.stack_kcp`)
+                         and the grid walks the class dimension in
+                         ``chunk``-column tiles, so only K * chunk template
+                         rows sit in VMEM at a time; the chunk is the full
+                         padded class count for banks inside the row budget
+                         (`repro.match.MAX_FUSED_ROWS`), so one kernel
+                         covers resident and class-chunked banks. A
+                         single-tenant classify is its T = 1 face
+                         (`ops.classify_fused_margins`).
 
-`repro.core.matching` dispatches to these by default (see its docstring for
-the backend-selection API); the jnp references remain as oracles.
+The jnp references in `repro.match.backends` remain as oracles.
 """
 from __future__ import annotations
 
@@ -142,305 +123,6 @@ def acam_match(features: jax.Array, thresholds: jax.Array,
     return scores
 
 
-def _classify_kernel(f_ref, thr_ref, t_ref, vrow_ref, acc_ref, pc_ref,
-                     pred_ref, *, nk: int, n_true: int, num_k: int, cp: int):
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q_pm = jnp.where(f_ref[...] > thr_ref[...], 1.0, -1.0).astype(jnp.bfloat16)
-    t_pm = (2.0 * t_ref[...] - 1.0).astype(jnp.bfloat16)
-    acc_ref[...] += jax.lax.dot_general(
-        q_pm, t_pm, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        from repro.kernels.layout import wta_epilogue
-
-        np_ = float(nk * f_ref.shape[-1])
-        # bipolar identity + padded-column correction (same as acam_match)
-        scores = (np_ + acc_ref[...]) * 0.5 - (np_ - n_true)
-        per_class, pred = wta_epilogue(scores, vrow_ref[...], cp, num_k)
-        pc_ref[...] = per_class
-        pred_ref[...] = jnp.broadcast_to(pred[:, None], pred_ref.shape)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_classes", "block", "interpret"))
-def acam_match_classify(features: jax.Array, thresholds: jax.Array,
-                        templates_kmajor: jax.Array, valid_row: jax.Array,
-                        num_classes: int, *, block=DEFAULT_BLOCK,
-                        interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Fused Eq. 8 + Eq. 12: one pallas_call from raw features to WTA.
-
-    features:         (B, N) float — raw front-end feature maps
-    thresholds:       (N,) binarisation thresholds
-    templates_kmajor: (K * Cp, N) {0,1}, K-major layout (repro.kernels.layout)
-    valid_row:        (K * Cp,) float {0,1} row validity
-    num_classes:      true C (Cp = padded lane multiple)
-
-    Returns (pred (B,) int32, per_class (B, C) f32). Only `bm`/`bk` of
-    `block` are used — the template dimension is resident in full.
-    """
-    b, n = features.shape
-    mk, _ = templates_kmajor.shape
-    from repro.kernels.layout import padded_classes
-    cp = padded_classes(num_classes)
-    num_k = mk // cp
-    assert num_k * cp == mk, "templates must be K-major with padded classes"
-    bm, _, bk = block
-    bp, np_ = (-(-b // bm) * bm, -(-n // bk) * bk)
-
-    f = jnp.pad(features, ((0, bp - b), (0, np_ - n)))
-    thr = jnp.pad(thresholds, (0, np_ - n), constant_values=jnp.inf)[None, :]
-    t = jnp.pad(templates_kmajor, ((0, 0), (0, np_ - n)))
-    vrow = valid_row[None, :]
-
-    nk = np_ // bk
-    grid = (bp // bm, nk)
-    _, per_class, pred = pl.pallas_call(
-        functools.partial(_classify_kernel, nk=nk, n_true=n, num_k=num_k,
-                          cp=cp),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((1, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((mk, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((1, mk), lambda i, k: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, mk), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, cp), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, k: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bp, mk), jnp.float32),  # score accumulator
-            jax.ShapeDtypeStruct((bp, cp), jnp.float32),  # per-class max
-            jax.ShapeDtypeStruct((bp, PRED_LANES), jnp.int32),  # WTA index
-        ],
-        interpret=interpret,
-    )(f, thr, t, vrow)
-    return pred[:b, 0], per_class[:b, :num_classes]
-
-
-def _classify_margins_kernel(f_ref, thr_ref, t_ref, vrow_ref, lo_ref, hi_ref,
-                             acc_ref, pc_ref, pred_ref, margin_ref, *,
-                             nk: int, n_true: int, num_k: int, cp: int):
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q_pm = jnp.where(f_ref[...] > thr_ref[...], 1.0, -1.0).astype(jnp.bfloat16)
-    t_pm = (2.0 * t_ref[...] - 1.0).astype(jnp.bfloat16)
-    acc_ref[...] += jax.lax.dot_general(
-        q_pm, t_pm, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        from repro.kernels.layout import windowed_margin, wta_epilogue
-
-        np_ = float(nk * f_ref.shape[-1])
-        scores = (np_ + acc_ref[...]) * 0.5 - (np_ - n_true)
-        per_class, _ = wta_epilogue(scores, vrow_ref[...], cp, num_k)
-        pred, margin = windowed_margin(per_class, lo_ref[..., :1],
-                                       hi_ref[..., :1], float(n_true))
-        pc_ref[...] = per_class
-        pred_ref[...] = jnp.broadcast_to(pred[:, None], pred_ref.shape)
-        margin_ref[...] = jnp.broadcast_to(margin[:, None], margin_ref.shape)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_classes", "block", "interpret"))
-def acam_match_classify_margins(
-        features: jax.Array, thresholds: jax.Array,
-        templates_kmajor: jax.Array, valid_row: jax.Array,
-        class_lo: jax.Array, class_hi: jax.Array, num_classes: int, *,
-        block=DEFAULT_BLOCK, interpret: bool = False
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused Eq. 8 + windowed Eq. 12 + confidence margin, one pallas_call.
-
-    Operands as `acam_match_classify`, plus per-row class windows:
-
-    class_lo/class_hi: (B,) int32 — each row's decision is restricted to
-        classes ``[class_lo, class_hi)`` of the (shared, multi-tenant) bank;
-        pass 0 / num_classes for the single-tenant case.
-
-    Returns (pred (B,) int32 *global* class index, per_class (B, C) f32,
-    margin (B,) f32 winner-vs-runner-up gap clamped to N). Rows whose window
-    is empty (lo == hi, e.g. scheduler slot padding) get pred 0, margin 0.
-    """
-    b, n = features.shape
-    mk, _ = templates_kmajor.shape
-    from repro.kernels.layout import padded_classes
-    cp = padded_classes(num_classes)
-    num_k = mk // cp
-    assert num_k * cp == mk, "templates must be K-major with padded classes"
-    bm, _, bk = block
-    bp, np_ = (-(-b // bm) * bm, -(-n // bk) * bk)
-
-    f = jnp.pad(features, ((0, bp - b), (0, np_ - n)))
-    thr = jnp.pad(thresholds, (0, np_ - n), constant_values=jnp.inf)[None, :]
-    t = jnp.pad(templates_kmajor, ((0, 0), (0, np_ - n)))
-    vrow = valid_row[None, :]
-    # windows ride in lane-aligned (B, PRED_LANES) int32 carriers (col 0 is
-    # the payload); batch padding rows get the empty window [0, 0)
-    lo = jnp.broadcast_to(
-        jnp.pad(class_lo.astype(jnp.int32), (0, bp - b))[:, None],
-        (bp, PRED_LANES))
-    hi = jnp.broadcast_to(
-        jnp.pad(class_hi.astype(jnp.int32), (0, bp - b))[:, None],
-        (bp, PRED_LANES))
-
-    nk = np_ // bk
-    grid = (bp // bm, nk)
-    _, per_class, pred, margin = pl.pallas_call(
-        functools.partial(_classify_margins_kernel, nk=nk, n_true=n,
-                          num_k=num_k, cp=cp),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((1, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((mk, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((1, mk), lambda i, k: (0, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, k: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, mk), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, cp), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, k: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bp, mk), jnp.float32),  # score accumulator
-            jax.ShapeDtypeStruct((bp, cp), jnp.float32),  # per-class max
-            jax.ShapeDtypeStruct((bp, PRED_LANES), jnp.int32),  # WTA index
-            jax.ShapeDtypeStruct((bp, PRED_LANES), jnp.float32),  # margin
-        ],
-        interpret=interpret,
-    )(f, thr, t, vrow, lo, hi)
-    return pred[:b, 0], per_class[:b, :num_classes], margin[:b, 0]
-
-
-def _classify_margins_chunked_kernel(f_ref, thr_ref, t_ref, v_ref, lo_ref,
-                                     hi_ref, acc_ref, pc_ref, pred_ref,
-                                     margin_ref, *, nj: int, nk: int,
-                                     n_true: int, num_k: int, cc: int):
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q_pm = jnp.where(f_ref[...] > thr_ref[...], 1.0, -1.0).astype(jnp.bfloat16)
-    # this chunk's K * cc template rows, flattened K-major: row kk*cc + c
-    t = t_ref[...].reshape(num_k * cc, t_ref.shape[-1])
-    t_pm = (2.0 * t - 1.0).astype(jnp.bfloat16)
-    acc_ref[...] += jax.lax.dot_general(
-        q_pm, t_pm, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _chunk_epilogue():
-        from repro.kernels.layout import store_class_chunk, windowed_margin
-
-        np_ = float(nk * f_ref.shape[-1])
-        scores = (np_ + acc_ref[...]) * 0.5 - (np_ - n_true)
-        vrow = v_ref[...].reshape(1, num_k * cc)
-        s = jnp.where(vrow > 0, scores, -jnp.inf)
-        chunk_pc = s[:, :cc]
-        for kk in range(1, num_k):
-            chunk_pc = jnp.maximum(chunk_pc, s[:, kk * cc:(kk + 1) * cc])
-        # per-class max of this chunk lands in its columns of the revisited
-        # (bm, Cp) block; the nj chunks tile Cp, so every column is written
-        # before the last chunk reads the block back
-        store_class_chunk(pc_ref, chunk_pc, j)
-
-        @pl.when(j == nj - 1)
-        def _final():
-            pc = pc_ref[...]
-            pred, margin = windowed_margin(pc, lo_ref[..., :1],
-                                           hi_ref[..., :1], float(n_true))
-            pred_ref[...] = jnp.broadcast_to(pred[:, None], pred_ref.shape)
-            margin_ref[...] = jnp.broadcast_to(margin[:, None],
-                                               margin_ref.shape)
-
-
-@functools.partial(jax.jit, static_argnames=("num_classes", "chunk", "block",
-                                             "interpret"))
-def acam_match_classify_margins_chunked(
-        features: jax.Array, thresholds: jax.Array,
-        templates_kcp: jax.Array, valid_kcp: jax.Array,
-        class_lo: jax.Array, class_hi: jax.Array, num_classes: int, *,
-        chunk: int, block=DEFAULT_BLOCK, interpret: bool = False
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Class-chunked `acam_match_classify_margins` for big banks.
-
-    Same contract and outputs as the fused margins kernel, but the
-    templates arrive as a (K, Cp, N) stack (`repro.kernels.layout.stack_kcp`)
-    and the grid walks the class dimension in ``chunk``-column tiles
-    (``chunk`` a lane-multiple divisor of Cp, `layout.class_chunk`): at any
-    moment only K * chunk template rows sit in VMEM, the Eq. 12 per-class
-    max accumulates across chunks in a revisited (bm, Cp) output block, and
-    the windowed-margin epilogue runs once at the last chunk — ONE
-    pallas_call at any bank size.
-    """
-    b, n = features.shape
-    num_k, cp, _ = templates_kcp.shape
-    assert cp % chunk == 0, "chunk must divide the padded class count"
-    bm, _, bk = block
-    bp, np_ = (-(-b // bm) * bm, -(-n // bk) * bk)
-
-    f = jnp.pad(features, ((0, bp - b), (0, np_ - n)))
-    thr = jnp.pad(thresholds, (0, np_ - n), constant_values=jnp.inf)[None, :]
-    t = jnp.pad(templates_kcp, ((0, 0), (0, 0), (0, np_ - n)))
-    lo = jnp.broadcast_to(
-        jnp.pad(class_lo.astype(jnp.int32), (0, bp - b))[:, None],
-        (bp, PRED_LANES))
-    hi = jnp.broadcast_to(
-        jnp.pad(class_hi.astype(jnp.int32), (0, bp - b))[:, None],
-        (bp, PRED_LANES))
-
-    nj = cp // chunk
-    nk = np_ // bk
-    grid = (bp // bm, nj, nk)
-    _, per_class, pred, margin = pl.pallas_call(
-        functools.partial(_classify_margins_chunked_kernel, nj=nj, nk=nk,
-                          n_true=n, num_k=num_k, cc=chunk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, bk), lambda i, j, k: (0, k)),
-            pl.BlockSpec((num_k, chunk, bk), lambda i, j, k: (0, j, k)),
-            pl.BlockSpec((num_k, chunk), lambda i, j, k: (0, j)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, j, k: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, num_k * chunk), lambda i, j, k: (i, j)),
-            pl.BlockSpec((bm, cp), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, j, k: (i, 0)),
-        ],
-        out_shape=[
-            # per-chunk score accumulator (K * cc live rows per grid step)
-            jax.ShapeDtypeStruct((bp, num_k * cp), jnp.float32),
-            jax.ShapeDtypeStruct((bp, cp), jnp.float32),  # running per-class
-            jax.ShapeDtypeStruct((bp, PRED_LANES), jnp.int32),  # WTA index
-            jax.ShapeDtypeStruct((bp, PRED_LANES), jnp.float32),  # margin
-        ],
-        interpret=interpret,
-    )(f, thr, t, valid_kcp, lo, hi)
-    return pred[:b, 0], per_class[:b, :num_classes], margin[:b, 0]
-
-
 def _serve_kernel(f_ref, slot_ref, thr_ref, t_ref, v_ref, lo_ref, hi_ref,
                   tau_ref, acc_ref, pc_ref, pred_ref, margin_ref, esc_ref, *,
                   nj: int, nk: int, n_true: int, num_k: int, cc: int):
@@ -510,9 +192,9 @@ def acam_match_serve(
         num_classes: int, *, chunk: int, block=DEFAULT_BLOCK,
         interpret: bool = False
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """The resident serving mega-kernel: gather -> binarize -> match ->
-    per-class max -> WTA -> windowed Eq. 12 margin -> escalation mask, ONE
-    pallas_call over the multi-tenant super-bank.
+    """The serving mega-kernel: gather -> binarize -> match -> per-class
+    max -> WTA -> windowed Eq. 12 margin -> escalation mask, in one
+    dispatch over the multi-tenant super-bank.
 
     features:    (B, N) raw per-slot front-end feature maps (UNshifted)
     thr_table:   (T, N) per-tenant binarisation threshold rows

@@ -1,13 +1,10 @@
-"""Public wrappers for the acam_match kernel.
+"""Public wrappers for the acam_match kernels.
 
-`match_scores` runs the Pallas kernel (interpret=True on CPU, compiled on
-TPU); `classify` adds the WTA argmax epilogue (Eq. 12) with multi-template
-max-pooling, mirroring repro.core.matching.classify semantics;
-`classify_fused` is the single-pallas_call binarize->match->WTA path over a
-K-major bank layout (no (B, M) score round-trip); `classify_fused_margins`
-additionally returns the Eq. 12 winner-vs-runner-up confidence margin and
-accepts per-row class windows — the multi-tenant serving entry point
-(`repro.serve`).
+`match_scores` runs the raw-scores kernel (interpret=True on CPU, compiled
+on TPU); `serve_classify` is the multi-tenant serving mega-kernel (per-slot
+threshold gather, binarize, match, Eq. 12 WTA, windowed margin and
+escalation mask in one dispatch); `classify_fused_margins` is its
+single-tenant face — one shared thresholds row, no escalation.
 
 Block sizes: when ``block`` is omitted the wrapper resolves a tuned
 ``(bm, bn, bk)`` via `repro.kernels.tuning.get_block` (persistent JSON cache
@@ -23,9 +20,7 @@ import jax.numpy as jnp
 
 from repro.kernels import layout, tuning
 from repro.kernels.acam_match.acam_match import (
-    DEFAULT_BLOCK, acam_match, acam_match_classify,
-    acam_match_classify_margins, acam_match_classify_margins_chunked,
-    acam_match_serve)
+    DEFAULT_BLOCK, acam_match, acam_match_serve)
 
 
 _on_cpu = tuning.interpret_mode
@@ -37,100 +32,6 @@ def match_scores(features: jax.Array, thresholds: jax.Array,
     block = _resolve(features, templates.shape[0], block)
     return acam_match(features, thresholds, templates, block=block,
                       interpret=_on_cpu())
-
-
-@functools.partial(jax.jit, static_argnames=("num_classes", "block",
-                                             "interpret"))
-def _classify_two_stage(features, thresholds, templates_flat, valid_flat,
-                        num_classes, *, block, interpret):
-    scores = acam_match(features, thresholds, templates_flat, block=block,
-                        interpret=interpret)
-    scores = jnp.where(valid_flat[None, :], scores, -jnp.inf)
-    k = templates_flat.shape[0] // num_classes
-    per_class = jnp.max(scores.reshape(scores.shape[0], num_classes, k),
-                        axis=-1)
-    return jnp.argmax(per_class, axis=-1), per_class
-
-
-def classify(features: jax.Array, thresholds: jax.Array,
-             templates_flat: jax.Array, valid_flat: jax.Array,
-             num_classes: int, *, block=None) -> tuple[jax.Array, jax.Array]:
-    """templates_flat: (C*K, N) class-major; valid_flat: (C*K,) bool.
-
-    Returns (pred (B,), per_class (B, C))."""
-    block = _resolve(features, templates_flat.shape[0], block)
-    return _classify_two_stage(features, thresholds, templates_flat,
-                               valid_flat, num_classes, block=block,
-                               interpret=_on_cpu())
-
-
-def classify_fused(features: jax.Array, thresholds: jax.Array,
-                   templates_ck: jax.Array, valid_ck: jax.Array, *,
-                   block=None) -> tuple[jax.Array, jax.Array]:
-    """Single-pallas_call Eq. 8 + Eq. 12 over a (C, K, N) bank.
-
-    Flattens the bank K-major (repro.kernels.layout) and runs
-    `acam_match_classify`. Returns (pred (B,) int32, per_class (B, C))."""
-    c, k, n = templates_ck.shape
-    block = _resolve(features, c * k, block)
-    t_km = layout.flatten_kmajor(templates_ck, c)
-    v_km = layout.valid_kmajor(valid_ck, c)
-    return acam_match_classify(features, thresholds, t_km, v_km, c,
-                               block=block, interpret=_on_cpu())
-
-
-def classify_fused_margins(features: jax.Array, thresholds: jax.Array,
-                           templates_ck: jax.Array, valid_ck: jax.Array,
-                           class_lo: jax.Array | None = None,
-                           class_hi: jax.Array | None = None, *,
-                           block=None) -> tuple[jax.Array, jax.Array,
-                                                jax.Array]:
-    """Return-margins variant of `classify_fused` (the serving path).
-
-    Adds per-row class windows ``[class_lo, class_hi)`` (int32 (B,); defaults
-    to the whole bank) and returns ``(pred, per_class, margin)`` where
-    ``margin`` is the Eq. 12 winner-vs-runner-up gap inside the window — the
-    confidence cascade's accept/escalate signal. Still ONE pallas_call."""
-    c, k, n = templates_ck.shape
-    b = features.shape[0]
-    if class_lo is None:
-        class_lo = jnp.zeros((b,), jnp.int32)
-    if class_hi is None:
-        class_hi = jnp.full((b,), c, jnp.int32)
-    block = _resolve(features, c * k, block)
-    t_km = layout.flatten_kmajor(templates_ck, c)
-    v_km = layout.valid_kmajor(valid_ck, c)
-    return acam_match_classify_margins(features, thresholds, t_km, v_km,
-                                       class_lo, class_hi, c, block=block,
-                                       interpret=_on_cpu())
-
-
-def classify_fused_margins_chunked(
-        features: jax.Array, thresholds: jax.Array, templates_ck: jax.Array,
-        valid_ck: jax.Array, class_lo: jax.Array | None = None,
-        class_hi: jax.Array | None = None, *, max_rows: int,
-        block=None) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """`classify_fused_margins` for banks past the fused-row budget.
-
-    Stacks the bank (K, Cp, N) (`layout.stack_kcp`) and tiles the class
-    dimension in `layout.class_chunk(..., max_rows)`-column chunks, keeping
-    the big-bank serving path a SINGLE pallas_call (no two-stage kernel +
-    jnp margin epilogue). Same contract/outputs as `classify_fused_margins`.
-    """
-    c, k, n = templates_ck.shape
-    b = features.shape[0]
-    if class_lo is None:
-        class_lo = jnp.zeros((b,), jnp.int32)
-    if class_hi is None:
-        class_hi = jnp.full((b,), c, jnp.int32)
-    block = _resolve(features, c * k, block)
-    cp = layout.padded_classes(c)
-    chunk = layout.class_chunk(cp, k, max_rows)
-    t_kcp = layout.stack_kcp(templates_ck, c)
-    v_kcp = layout.valid_kcp(valid_ck, c)
-    return acam_match_classify_margins_chunked(
-        features, thresholds, t_kcp, v_kcp, class_lo, class_hi, c,
-        chunk=chunk, block=block, interpret=_on_cpu())
 
 
 def serve_classify(
@@ -169,3 +70,23 @@ def serve_classify(
     return acam_match_serve(features, thr_table, tenant_slot, t_kcp, v_kcp,
                             class_lo, class_hi, tau, c, chunk=chunk,
                             block=block, interpret=_on_cpu())
+
+
+def classify_fused_margins(
+        features: jax.Array, thresholds: jax.Array, templates_ck: jax.Array,
+        valid_ck: jax.Array, class_lo: jax.Array | None = None,
+        class_hi: jax.Array | None = None, *, max_rows: int, block=None
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Single-dispatch Eq. 8 + Eq. 12 + windowed margin (any bank size).
+
+    The single-tenant face of the serve kernel: ONE shared thresholds row
+    (T = 1, every query binarises against it) and tau pinned to -inf, with
+    the escalation mask dropped. Returns (pred, per_class, margin) — the
+    feature-count twin of `acam_similarity.ops.classify_fused_margins`.
+    """
+    b = features.shape[0]
+    pred, per_class, margin, _ = serve_classify(
+        features, thresholds[None, :], jnp.zeros((b,), jnp.int32),
+        templates_ck, valid_ck, class_lo, class_hi, None,
+        max_rows=max_rows, block=block)
+    return pred, per_class, margin

@@ -20,24 +20,20 @@ Block sizes come from `repro.kernels.tuning` (persistent JSON cache at
 ``kernel|backend|shape|dtype``) with `DEFAULT_BLOCK` as the untuned
 fallback. Two entry points:
 
-  `acam_similarity`          -> (B, M) Eq. 11 scores (two-stage path).
-  `acam_similarity_classify` -> fused binarize->window-match->valid-mask->
-                                per-class max->argmax/WTA (Eq. 12) in ONE
-                                pallas_call over a K-major template layout
-                                (`repro.kernels.layout`); no (B, M) score
-                                round-trip.
-  `acam_similarity_serve`    -> the symmetric serving/margins kernel: the
-                                (K, Cp, N) class-chunked scheme of
-                                `acam_match_serve` for the similarity
-                                method — per-slot tenant threshold gather,
-                                binarize, Eq. 9-11 window match with D/H
-                                chunk accumulators, running per-class max,
-                                windowed Eq. 12 margin and the escalation
-                                mask, ONE pallas_call at any bank size (the
-                                chunk degenerates to Cp for resident banks).
+  `acam_similarity`        -> (B, M) Eq. 11 scores, behind the engine's
+                              explicit `*_scores` entry points.
+  `acam_similarity_serve`  -> the classify kernel: the (K, Cp, N)
+                              class-chunked scheme of `acam_match_serve`
+                              for the similarity method — per-slot tenant
+                              threshold gather, binarize, Eq. 9-11 window
+                              match with D/H chunk accumulators, running
+                              per-class max, windowed Eq. 12 margin and the
+                              escalation mask, in one dispatch at any bank
+                              size (the chunk degenerates to Cp for
+                              resident banks). A single-tenant classify is
+                              its T = 1 face (`ops.classify_fused_margins`).
 
-`repro.core.matching` dispatches here by default; the jnp reference stays
-as the oracle.
+The jnp references in `repro.match.backends` remain as oracles.
 """
 from __future__ import annotations
 
@@ -132,104 +128,6 @@ def acam_similarity(queries: jax.Array, lower: jax.Array, upper: jax.Array,
     return s[:b, :m]
 
 
-def _classify_kernel(f_ref, thr_ref, lo_ref, hi_ref, vrow_ref, d_ref, h_ref,
-                     pc_ref, pred_ref, *, nk: int, alpha: float, n_true: int,
-                     num_k: int, cp: int):
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        d_ref[...] = jnp.zeros_like(d_ref)
-        h_ref[...] = jnp.zeros_like(h_ref)
-
-    # fused binarisation (paper §II-C): padded columns carry thr=+inf -> q=0,
-    # matching the zero-padded windows, corrected in the epilogue.
-    q = jnp.where(f_ref[...] > thr_ref[...], 1.0, 0.0)[:, None, :]
-    lo = lo_ref[...][None, :, :]
-    hi = hi_ref[...][None, :, :]
-
-    above = jnp.maximum(q - hi, 0.0)
-    below = jnp.maximum(lo - q, 0.0)
-    d_ref[...] += jnp.sum(above * above + below * below, axis=-1)
-    hit = jnp.logical_and(q >= lo, q <= hi)
-    h_ref[...] += jnp.sum(hit.astype(jnp.float32), axis=-1)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        from repro.kernels.layout import wta_epilogue
-
-        pad_hits = float(nk * f_ref.shape[-1] - n_true)
-        s = eq11(h_ref[...] - pad_hits, d_ref[...], n_true, alpha)
-        per_class, pred = wta_epilogue(s, vrow_ref[...], cp, num_k)
-        pc_ref[...] = per_class
-        pred_ref[...] = jnp.broadcast_to(pred[:, None], pred_ref.shape)
-
-
-@functools.partial(jax.jit, static_argnames=("num_classes", "alpha", "block",
-                                             "interpret"))
-def acam_similarity_classify(features: jax.Array, thresholds: jax.Array,
-                             lower_kmajor: jax.Array, upper_kmajor: jax.Array,
-                             valid_row: jax.Array, num_classes: int, *,
-                             alpha: float = 1.0, block=DEFAULT_BLOCK,
-                             interpret: bool = False
-                             ) -> tuple[jax.Array, jax.Array]:
-    """Fused Eq. 9-12: raw features -> binarize -> window match -> WTA.
-
-    features:      (B, N) raw front-end feature maps
-    thresholds:    (N,) binarisation thresholds
-    lower/upper:   (K * Cp, N) K-major window bank (repro.kernels.layout)
-    valid_row:     (K * Cp,) float {0,1}
-    Returns (pred (B,) int32, per_class (B, C) f32). Only bm/bk of `block`
-    are used; bm is shrunk if the (bm, K*Cp, bk) tile would bust VMEM.
-    """
-    b, n = features.shape
-    mk = lower_kmajor.shape[0]
-    from repro.kernels.layout import padded_classes
-    cp = padded_classes(num_classes)
-    num_k = mk // cp
-    assert num_k * cp == mk, "windows must be K-major with padded classes"
-    bm, _, bk = block
-    while bm > 8 and bm * mk * bk * 4 > 8 * 1024 * 1024:
-        bm //= 2
-    bp, np_ = (-(-b // bm) * bm, -(-n // bk) * bk)
-
-    f = jnp.pad(features.astype(jnp.float32), ((0, bp - b), (0, np_ - n)))
-    thr = jnp.pad(thresholds.astype(jnp.float32), (0, np_ - n),
-                  constant_values=jnp.inf)[None, :]
-    lo = jnp.pad(lower_kmajor.astype(jnp.float32), ((0, 0), (0, np_ - n)))
-    hi = jnp.pad(upper_kmajor.astype(jnp.float32), ((0, 0), (0, np_ - n)))
-    vrow = valid_row[None, :]
-
-    nk = np_ // bk
-    grid = (bp // bm, nk)
-    _, _, per_class, pred = pl.pallas_call(
-        functools.partial(_classify_kernel, nk=nk, alpha=alpha, n_true=n,
-                          num_k=num_k, cp=cp),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((1, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((mk, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((mk, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((1, mk), lambda i, k: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, mk), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, mk), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, cp), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm, PRED_LANES), lambda i, k: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bp, mk), jnp.float32),  # D accumulator
-            jax.ShapeDtypeStruct((bp, mk), jnp.float32),  # H accumulator
-            jax.ShapeDtypeStruct((bp, cp), jnp.float32),  # per-class max
-            jax.ShapeDtypeStruct((bp, PRED_LANES), jnp.int32),  # WTA index
-        ],
-        interpret=interpret,
-    )(f, thr, lo, hi, vrow)
-    return pred[:b, 0], per_class[:b, :num_classes]
-
-
 def _serve_kernel(f_ref, slot_ref, thr_ref, lo_ref, hi_ref, v_ref, wlo_ref,
                   whi_ref, tau_ref, d_ref, h_ref, pc_ref, pred_ref,
                   margin_ref, esc_ref, *, nj: int, nk: int, alpha: float,
@@ -301,7 +199,7 @@ def acam_similarity_serve(
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Serving mega-kernel for the similarity method: gather -> binarize ->
     Eq. 9-11 window match -> per-class max -> windowed Eq. 12 margin ->
-    escalation mask, ONE pallas_call at any bank size.
+    escalation mask, in one dispatch at any bank size.
 
     Operand contract mirrors `acam_match.acam_match_serve`, with the bank as
     (K, Cp, N) lower/upper window stacks (`repro.kernels.layout.stack_kcp`).

@@ -1,13 +1,10 @@
 """Public wrappers for the acam_similarity kernel.
 
-`similarity_scores` runs the two-stage Pallas kernel; `classify` adds the
-Eq. 12 epilogue in jnp; `classify_fused` is the single-pallas_call
-binarize->window-match->WTA path over a K-major bank layout;
-`classify_fused_margins` is the margins variant (class-chunked past
-``max_rows``, so any bank size stays ONE pallas_call); `serve_classify` is
-the multi-tenant serving mega-kernel (per-slot threshold gather + margins +
-escalation mask in VMEM) — the similarity twin of
-`repro.kernels.acam_match.ops.serve_classify`.
+`similarity_scores` runs the raw-scores kernel; `serve_classify` is the
+multi-tenant serving mega-kernel (per-slot threshold gather + margins +
+escalation mask in VMEM, class-chunked past ``max_rows``) — the similarity
+twin of `repro.kernels.acam_match.ops.serve_classify`;
+`classify_fused_margins` is its single-tenant face.
 
 Blocks resolve through `repro.kernels.tuning.get_block` (persistent JSON
 cache, `DEFAULT_BLOCK` fallback) when ``block`` is omitted — a pure lookup,
@@ -22,8 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import layout, tuning
 from repro.kernels.acam_similarity.acam_similarity import (
-    DEFAULT_BLOCK, acam_similarity, acam_similarity_classify,
-    acam_similarity_serve)
+    DEFAULT_BLOCK, acam_similarity, acam_similarity_serve)
 
 
 _on_cpu = tuning.interpret_mode
@@ -35,43 +31,6 @@ def similarity_scores(queries: jax.Array, lower: jax.Array, upper: jax.Array,
     block = _resolve(queries, lower.shape[0], block)
     return acam_similarity(queries, lower, upper, alpha=alpha, block=block,
                            interpret=_on_cpu())
-
-
-@functools.partial(jax.jit, static_argnames=("num_classes", "alpha", "block",
-                                             "interpret"))
-def _classify_two_stage(queries, lower_flat, upper_flat, valid_flat,
-                        num_classes, *, alpha, block, interpret):
-    s = acam_similarity(queries, lower_flat, upper_flat, alpha=alpha,
-                        block=block, interpret=interpret)
-    s = jnp.where(valid_flat[None, :], s, -jnp.inf)
-    k = lower_flat.shape[0] // num_classes
-    per_class = jnp.max(s.reshape(s.shape[0], num_classes, k), axis=-1)
-    return jnp.argmax(per_class, axis=-1), per_class
-
-
-def classify(queries: jax.Array, lower_flat: jax.Array, upper_flat: jax.Array,
-             valid_flat: jax.Array, num_classes: int, *, alpha: float = 1.0,
-             block=None) -> tuple[jax.Array, jax.Array]:
-    """Eq. 12 decision over a class-major flattened window-template bank."""
-    block = _resolve(queries, lower_flat.shape[0], block)
-    return _classify_two_stage(queries, lower_flat, upper_flat, valid_flat,
-                               num_classes, alpha=alpha, block=block,
-                               interpret=_on_cpu())
-
-
-def classify_fused(features: jax.Array, thresholds: jax.Array,
-                   lower_ck: jax.Array, upper_ck: jax.Array,
-                   valid_ck: jax.Array, *, alpha: float = 1.0,
-                   block=None) -> tuple[jax.Array, jax.Array]:
-    """Single-pallas_call Eq. 9-12 over a (C, K, N) window bank."""
-    c, k, n = lower_ck.shape
-    block = _resolve(features, c * k, block)
-    lo_km = layout.flatten_kmajor(lower_ck, c)
-    hi_km = layout.flatten_kmajor(upper_ck, c)
-    v_km = layout.valid_kmajor(valid_ck, c)
-    return acam_similarity_classify(features, thresholds, lo_km, hi_km, v_km,
-                                    c, alpha=alpha, block=block,
-                                    interpret=_on_cpu())
 
 
 def serve_classify(
@@ -117,12 +76,12 @@ def classify_fused_margins(
         class_hi: jax.Array | None = None, *, alpha: float = 1.0,
         max_rows: int, block=None
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Single-pallas_call Eq. 9-12 + windowed margin (any bank size).
+    """Single-dispatch Eq. 9-12 + windowed margin (any bank size).
 
     The single-tenant face of the serve kernel: ONE shared thresholds row
     (T = 1, every query binarises against it) and tau pinned to -inf, with
     the escalation mask dropped. Returns (pred, per_class, margin) — the
-    similarity twin of `acam_match.ops.classify_fused_margins[_chunked]`.
+    similarity twin of `acam_match.ops.classify_fused_margins`.
     """
     b = features.shape[0]
     pred, per_class, margin, _ = serve_classify(

@@ -1,15 +1,15 @@
-"""Template-bank layouts shared by the fused ACAM classify kernels.
+"""Template-bank layout shared by the ACAM serve kernels.
 
-The bank is stored class-major ``(C, K, N)`` (class c, template k). The fused
-binarize->match->WTA kernels need the Eq. 12 per-class max to be computable
-from *contiguous, lane-aligned* slices of the score row, so they use a
-**K-major** flattening: template row ``kk * Cp + c`` holds ``bank[c, kk]``,
-with C padded up to ``Cp`` (a lane multiple, 128). The per-class max is then
+The bank is stored class-major ``(C, K, N)`` (class c, template k). The
+serve kernels take it **stacked** ``(K, Cp, N)``: row ``[kk, c]`` holds
+``bank[c, kk]``, with C padded up to ``Cp`` (a lane multiple, 128). A chunk
+of ``cc`` class columns is then a contiguous (K, cc, N) block, and the
+Eq. 12 per-class max of a chunk is
 
-    per_class = max_kk scores[:, kk*Cp : (kk+1)*Cp]          # K static slices
+    per_class = max_kk scores[:, kk*cc : (kk+1)*cc]          # K static slices
 
-— no strided gather, no in-kernel reshape. Padded class columns and invalid
-templates carry ``valid_row = 0`` and are driven to -inf before the max.
+— no strided gather. Padded class columns and invalid templates carry
+``valid = 0`` and are driven to -inf before the max.
 """
 from __future__ import annotations
 
@@ -24,29 +24,9 @@ def padded_classes(num_classes: int, lane: int = LANE) -> int:
     return -(-num_classes // lane) * lane
 
 
-def flatten_kmajor(arr: jax.Array, num_classes: int) -> jax.Array:
-    """(C, K, N) -> (K * Cp, N), row kk*Cp + c = arr[c, kk], zero-padded."""
-    c, k, n = arr.shape
-    assert c == num_classes
-    cp = padded_classes(c)
-    out = jnp.zeros((k, cp, n), arr.dtype).at[:, :c, :].set(
-        jnp.swapaxes(arr, 0, 1))
-    return out.reshape(k * cp, n)
-
-
-def valid_kmajor(valid: jax.Array, num_classes: int) -> jax.Array:
-    """(C, K) bool -> (K * Cp,) float {0,1}; padded classes are invalid."""
-    c, k = valid.shape
-    assert c == num_classes
-    cp = padded_classes(c)
-    out = jnp.zeros((k, cp), jnp.float32).at[:, :c].set(
-        jnp.swapaxes(valid.astype(jnp.float32), 0, 1))
-    return out.reshape(k * cp)
-
-
 def stack_kcp(arr: jax.Array, num_classes: int) -> jax.Array:
     """(C, K, N) -> (K, Cp, N), zero-padded: the 3D operand of the
-    class-chunked margins kernel. Row [kk, c] = arr[c, kk]; a chunk of
+    class-chunked serve kernels. Row [kk, c] = arr[c, kk]; a chunk of
     ``cc`` class columns is a contiguous (K, cc, N) block, so the kernel
     tiles the class dimension with a plain BlockSpec instead of keeping
     all K * Cp rows VMEM-resident."""
@@ -67,7 +47,7 @@ def valid_kcp(valid: jax.Array, num_classes: int) -> jax.Array:
 
 
 def class_chunk(cp: int, num_k: int, max_rows: int, lane: int = LANE) -> int:
-    """Class columns per chunk for the chunked margins kernel: the largest
+    """Class columns per chunk for the serve kernels: the largest
     lane-multiple divisor of ``Cp`` whose ``num_k * cc`` template rows fit
     the fused-row budget; ``lane`` when even one K-slice of a single lane
     tile exceeds it (the budget is a VMEM policy, not a hard limit)."""
@@ -99,22 +79,6 @@ def first_argmax(x: jax.Array) -> jax.Array:
     iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
     top = jnp.max(x, axis=-1, keepdims=True)
     return jnp.min(jnp.where(x == top, iota, x.shape[-1]), axis=-1)
-
-
-def wta_epilogue(scores: jax.Array, valid_row: jax.Array, cp: int,
-                 num_k: int) -> tuple[jax.Array, jax.Array]:
-    """Shared fused-kernel epilogue over K-major scores (pure jnp, runs
-    inside both classify kernels): valid mask -> Eq. 12 per-class max over
-    the K contiguous class slices -> WTA argmax.
-
-    scores: (bm, K * Cp); valid_row: (1, K * Cp) float {0,1}.
-    Returns (per_class (bm, Cp), pred (bm,) int32).
-    """
-    s = jnp.where(valid_row > 0, scores, -jnp.inf)
-    per_class = s[:, :cp]
-    for kk in range(1, num_k):
-        per_class = jnp.maximum(per_class, s[:, kk * cp:(kk + 1) * cp])
-    return per_class, first_argmax(per_class)
 
 
 def windowed_margin(per_class: jax.Array, class_lo: jax.Array,

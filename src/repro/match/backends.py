@@ -16,16 +16,18 @@ Backends:
   reference  pure-jnp oracles — the parity baseline and the tiny-shape
              fast path (XLA fuses them well below the kernels' padding/
              launch overhead).
-  kernel     the Pallas paths: fused binarize->match->valid-mask->per-class
-             max->WTA [+windowed margins] in ONE pallas_call for both
-             methods at ANY bank size — banks inside `MAX_FUSED_ROWS` keep
-             every template row VMEM-resident, bigger banks walk the class
-             dimension in chunks (same single dispatch, running per-class
-             max in a revisited block). The serve path adds the per-slot
-             threshold gather and escalation mask in-kernel
-             (`acam_match_serve` / `acam_similarity_serve`). Blocks resolve
-             through the `repro.kernels.tuning` autotuner unless the engine
-             config pins them.
+  kernel     one Pallas classify kernel per method (`acam_match_serve` /
+             `acam_similarity_serve`): per-slot threshold gather ->
+             binarize -> match -> valid-mask -> per-class max -> WTA ->
+             windowed margin -> escalation mask in ONE pallas_call at ANY
+             bank size — banks inside `MAX_FUSED_ROWS` keep every template
+             row VMEM-resident, bigger banks walk the class dimension in
+             chunks (same single dispatch, running per-class max in a
+             revisited block). `classify`, `classify_features` and
+             `classify_features_margin` run its single-tenant face (T = 1,
+             no escalation). Blocks resolve through the
+             `repro.kernels.tuning` autotuner unless the engine config pins
+             them.
   device     the RRAM-CMOS physics models from `repro.core.acam` (§III):
              the bank is programmed into a (C*K)-row TXL array (point
              templates become lower == upper windows), optionally with
@@ -77,9 +79,9 @@ def tiny_cutoff(method: str) -> int:
         else TINY_ELEMENTS
 
 
-#: fused classify keeps all K * Cp template rows VMEM-resident; past this
-#: row count the kernel backend walks the bank in class-column chunks
-#: (still a single pallas_call — `layout.class_chunk`).
+#: the serve kernels keep all K * Cp template rows VMEM-resident up to this
+#: row count and walk the bank in class-column chunks past it (still a
+#: single pallas_call — `layout.class_chunk`).
 MAX_FUSED_ROWS = 2048
 
 
@@ -136,9 +138,9 @@ def window_margin(per_class: Array, class_lo: Array | None = None,
                   cap: float) -> tuple[Array, Array]:
     """Eq. 12 decision + winner-vs-runner-up margin inside class windows.
 
-    jnp oracle for the fused margins kernel, and the fallback used by the
-    reference/two-stage/similarity/device paths. ``per_class`` is (B, C)
-    with -inf for invalid classes; windows default to the full class range.
+    jnp oracle for the serve kernels' margin, and the epilogue of the
+    reference and device paths. ``per_class`` is (B, C) with -inf for
+    invalid classes; windows default to the full class range.
     Returns (pred (B,) int32 global class index, margin (B,) f32 clamped to
     cap).
     """
@@ -263,7 +265,7 @@ class MatchBackend:
     # escalation compare folded in. The default composes existing pieces
     # (gather + the shift identity binarize(f, thr_t) == binarize(f - thr_t,
     # 0) + classify_features_margin + margin < tau); the kernel backend
-    # overrides it with the resident mega-kernel.
+    # overrides it with the serve kernel.
 
     def classify_serve(
         self, features: Array, thr_table: Array, tenant_slot: Array,
@@ -394,70 +396,28 @@ class KernelBackend(MatchBackend):
             s = jnp.where(valid[None, :, :], s, NEG)
         return s
 
-    def _classify_kernel_path(self, features: Array, thresholds: Array,
-                              bank: TemplateBank) -> tuple[Array, Array]:
-        """ONE pallas_call at any bank size: fully fused when the bank fits
-        the VMEM row budget, class-chunked (same dispatch, running per-class
-        max) past it. The old two-stage kernel + jnp epilogue fallback is
-        gone — the raw-scores kernels remain only behind the explicit
-        `*_scores` entry points."""
-        from repro.kernels import layout
-        from repro.kernels.acam_match import ops as match_ops
-        from repro.kernels.acam_similarity import ops as sim_ops
-
-        method, alpha, block = (self.config.method, self.config.alpha,
-                                self.config.block)
-        c, k, n = bank.templates.shape
-        fused_rows = k * layout.padded_classes(c)
-        if method == "feature_count":
-            if fused_rows <= MAX_FUSED_ROWS:
-                return match_ops.classify_fused(features, thresholds,
-                                                bank.templates, bank.valid,
-                                                block=block)
-            pred, per_class, _ = match_ops.classify_fused_margins_chunked(
-                features.astype(jnp.float32), thresholds, bank.templates,
-                bank.valid, max_rows=MAX_FUSED_ROWS, block=block)
-            return pred, per_class
-        if fused_rows <= MAX_FUSED_ROWS:
-            return sim_ops.classify_fused(features, thresholds, bank.lower,
-                                          bank.upper, bank.valid, alpha=alpha,
-                                          block=block)
-        pred, per_class, _ = sim_ops.classify_fused_margins(
-            features, thresholds, bank.lower, bank.upper, bank.valid,
-            alpha=alpha, max_rows=MAX_FUSED_ROWS, block=block)
-        return pred, per_class
-
     def classify(self, queries, bank):
-        n = queries.shape[-1]
-        return self._classify_kernel_path(queries.astype(jnp.float32),
-                                          _binary_thresholds(n), bank)
+        return self.classify_features(
+            queries.astype(jnp.float32), bank._replace(
+                thresholds=_binary_thresholds(queries.shape[-1])))
 
     def classify_features(self, features, bank):
-        return self._classify_kernel_path(features, bank.thresholds, bank)
+        pred, per_class, _ = self.classify_features_margin(features, bank)
+        return pred, per_class
 
     def classify_features_margin(self, features, bank, class_lo=None,
                                  class_hi=None):
-        from repro.kernels import layout
+        """The serve kernel's single-tenant face (one shared thresholds row,
+        no escalation): ONE pallas_call at any bank size, fully resident
+        inside `MAX_FUSED_ROWS`, class-chunked past it."""
         from repro.kernels.acam_match import ops as match_ops
         from repro.kernels.acam_similarity import ops as sim_ops
 
-        c, k, n = bank.templates.shape
         if self.config.method == "feature_count":
-            # ONE pallas_call either way: binarize -> match -> per-class max
-            # -> WTA -> windowed winner-vs-runner-up margin. Banks whose
-            # K * Cp rows fit the fused budget keep the whole bank VMEM-
-            # resident; bigger banks walk it in class-column chunks.
-            if k * layout.padded_classes(c) <= MAX_FUSED_ROWS:
-                return match_ops.classify_fused_margins(
-                    features.astype(jnp.float32), bank.thresholds,
-                    bank.templates, bank.valid, class_lo, class_hi,
-                    block=self.config.block)
-            return match_ops.classify_fused_margins_chunked(
+            return match_ops.classify_fused_margins(
                 features.astype(jnp.float32), bank.thresholds,
                 bank.templates, bank.valid, class_lo, class_hi,
                 max_rows=MAX_FUSED_ROWS, block=self.config.block)
-        # similarity: the symmetric single-dispatch margins kernel (chunked
-        # past the row budget; no more fused-classify + jnp margin epilogue)
         return sim_ops.classify_fused_margins(
             features, bank.thresholds, bank.lower, bank.upper, bank.valid,
             class_lo, class_hi, alpha=self.config.alpha,
@@ -465,16 +425,9 @@ class KernelBackend(MatchBackend):
 
     def classify_serve(self, features, thr_table, tenant_slot, bank,
                        class_lo, class_hi, tau):
-        """The resident serving mega-kernel: the whole multi-tenant tick —
-        gather, binarize, match, per-class max, WTA, windowed margin,
-        escalation mask — in ONE pallas_call for BOTH methods.
-
-        ``serve_fusion="compose"`` keeps the pre-megakernel composition
-        (jnp gather/shift + fused margins kernel + jnp tau compare) as the
-        bit-identical benchmark baseline."""
-        if self.config.serve_fusion == "compose":
-            return super().classify_serve(features, thr_table, tenant_slot,
-                                          bank, class_lo, class_hi, tau)
+        """The serving mega-kernel: the whole multi-tenant tick — gather,
+        binarize, match, per-class max, WTA, windowed margin, escalation
+        mask — in ONE pallas_call for BOTH methods."""
         from repro.kernels.acam_match import ops as match_ops
         from repro.kernels.acam_similarity import ops as sim_ops
 

@@ -37,17 +37,6 @@ globals, keywords and environment variables:
                           draws its noise from fold_in(PRNGKey(seed), s).
                           Lifts the sharding refusal; an unsharded call is
                           the S = 1 tiling (fold_in(seed, 0)).
-  serve_fusion
-           how the kernel backend executes the multi-tenant serve path
-           (`MatchEngine.classify_serve`, the scheduler tick):
-             "mega"     the resident mega-kernel (`acam_match_serve` /
-                        `acam_similarity_serve`): threshold gather, match,
-                        windowed margin and the escalation mask in ONE
-                        pallas_call — the default.
-             "compose"  the pre-megakernel composition (jnp gather + shift,
-                        then the fused margins kernel, then the jnp
-                        escalation compare) — kept as the bit-identical
-                        before/after benchmark baseline.
 """
 from __future__ import annotations
 
@@ -59,8 +48,6 @@ METHODS = ("feature_count", "similarity")
 
 DEVICE_NOISE_MODES = ("global", "per_shard")
 
-SERVE_FUSION_MODES = ("mega", "compose")
-
 
 class EngineConfig(NamedTuple):
     method: str = "feature_count"
@@ -71,7 +58,6 @@ class EngineConfig(NamedTuple):
     device: ACAMConfig | None = None
     seed: int = 0
     device_noise: str = "global"
-    serve_fusion: str = "mega"
 
 
 def validate(config: EngineConfig, backend_names: tuple[str, ...]) -> None:
@@ -88,7 +74,4 @@ def validate(config: EngineConfig, backend_names: tuple[str, ...]) -> None:
     if config.device_noise not in DEVICE_NOISE_MODES:
         raise ValueError(f"unknown device_noise {config.device_noise!r}; "
                          f"use {DEVICE_NOISE_MODES}")
-    if config.serve_fusion not in SERVE_FUSION_MODES:
-        raise ValueError(f"unknown serve_fusion {config.serve_fusion!r}; "
-                         f"use {SERVE_FUSION_MODES}")
     hash(config)  # fail fast: configs must stay usable as static jit args

@@ -428,12 +428,11 @@ class MatchEngine:
         padding/no-head value). Returns (pred, per_class, margin, escalate).
 
         On the kernel backend the whole thing is ONE pallas_call
-        (`acam_match_serve` / `acam_similarity_serve`) unless
-        ``config.serve_fusion == "compose"``. Under a bank-sharded plan each
-        device serves its class-row shard and `plan.reduce` picks the
-        cross-shard merge (all-gather fold or the XOR-butterfly tree), then
-        the tau compare runs on the reduced margin — all bit-identical to
-        replicated execution.
+        (`acam_match_serve` / `acam_similarity_serve`). Under a bank-sharded
+        plan each device serves its class-row shard and `plan.reduce` picks
+        the cross-shard merge (all-gather fold or the XOR-butterfly tree),
+        then the tau compare runs on the reduced margin — all bit-identical
+        to replicated execution.
         """
         b = features.shape[0]
         c = bank.templates.shape[0]

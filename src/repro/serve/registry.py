@@ -8,8 +8,8 @@ super-bank:
 
   * tenant classes occupy a contiguous row range ``[offset, offset + C)``
     of a shared ``(C_cap, K_max, N)`` bank — the scheduler restricts each
-    request's Eq. 12 decision to its tenant's range via the class-window
-    margins kernel (`repro.kernels.acam_match.ops.classify_fused_margins`);
+    request's Eq. 12 decision to its tenant's range via the class windows
+    of the serve kernel (`repro.kernels.acam_match.ops.serve_classify`);
   * per-tenant binarisation thresholds live in a ``(T_cap, N)`` table; the
     scheduler gathers each slot's row and *shifts the query features* so one
     shared zero-threshold binarisation serves every tenant in the batch;
@@ -28,15 +28,14 @@ transfer. Under a sharded plan the scheduler hands over the plan's
 sharding (class rows over the model axis), once: the counters
 ``bank_placements`` / ``bank_bytes_placed`` (`stats`) count them.
 
-The fused margins kernel keeps all ``K_max * padded_classes(C_cap)``
-template rows VMEM-resident; past `repro.match.MAX_FUSED_ROWS` the kernel
-backend switches to the class-chunked margins kernel — same semantics,
-still one dispatch per tick. The scheduler's dispatch routes through
-`repro.match.MatchEngine`, so the same super-bank also serves the
-`reference` and `device` (RRAM-physics) backends and executes under the
-engine's 2D `PartitionPlan` when a mesh is installed: the batch shards over
-the data-parallel axes and the super-bank's class rows shard over the
-model axis.
+The serve kernel keeps all ``K_max * padded_classes(C_cap)`` template rows
+VMEM-resident up to `repro.match.MAX_FUSED_ROWS` and walks the class
+dimension in chunks past it — same semantics, still one dispatch per tick.
+The scheduler's dispatch routes through `repro.match.MatchEngine`, so the
+same super-bank also serves the `reference` and `device` (RRAM-physics)
+backends and executes under the engine's 2D `PartitionPlan` when a mesh is
+installed: the batch shards over the data-parallel axes and the
+super-bank's class rows shard over the model axis.
 
 Bank sharding is why the registry is **shard-aligned**: constructed with
 ``bank_shards=S`` (the spec path passes `ServiceSpec.mesh.bank_shards`

@@ -181,20 +181,18 @@ def _batched_classify(bank, thr_table, feats, tenant_slot, class_lo, class_hi,
     """The whole tick on device: ONE `MatchEngine.classify_serve` dispatch
     over the multi-tenant super-bank — the per-slot threshold-row gather,
     binarisation, windowed Eq. 12 decision/margin and the ``margin < tau``
-    escalation bit included (a single pallas_call on the kernel backend
-    under ``serve_fusion="mega"``).
+    escalation bit included (a single pallas_call on the kernel backend).
 
     ``config`` is the full `repro.match.EngineConfig`, a *static* argument
     resolved eagerly by `tick()` (never the process default read at trace
     time), so switching backends — or any other engine knob, e.g. the
-    device-physics noise config of a spec-built service or the mega/compose
-    serve fusion — between ticks re-traces instead of replaying a stale
-    executable. ``mesh_gen`` (`distributed.context.generation()`, also
-    static) does the same for the mesh: the engine bakes its
-    `PartitionPlan` — batch over the dp axes, super-bank class rows over
-    the model axis — into this trace, and installing a different mesh
-    between ticks keys a fresh executable instead of silently replaying the
-    stale layout."""
+    device-physics noise config of a spec-built service — between ticks
+    re-traces instead of replaying a stale executable. ``mesh_gen``
+    (`distributed.context.generation()`, also static) does the same for
+    the mesh: the engine bakes its `PartitionPlan` — batch over the dp
+    axes, super-bank class rows over the model axis — into this trace, and
+    installing a different mesh between ticks keys a fresh executable
+    instead of silently replaying the stale layout."""
     del mesh_gen  # cache key only: a new mesh generation forces a re-trace
     eng = match_lib.engine_from_config(config)
     return eng.classify_serve(feats, thr_table, tenant_slot, bank, class_lo,

@@ -6,9 +6,9 @@ Three layers of coverage:
     keeps capacity divisible by S, never places a tenant's bucket run across
     a shard boundary (allocations skip to the next shard instead), and
     preserves alignment across capacity growth and evict/re-register churn;
-  * chunked margins kernel (in-process): banks past `MAX_FUSED_ROWS` stay a
-    single pallas_call and agree bit-for-bit with the resident fused-margins
-    kernel and the jnp oracle;
+  * class-chunked serve kernel (in-process): banks past `MAX_FUSED_ROWS`
+    stay a single pallas_call, and its single-tenant face agrees bit-for-bit
+    with the jnp oracle at resident and chunked bank sizes;
   * forced 2x2 CPU mesh (subprocess, XLA_FLAGS before jax import): the
     bank-sharded engine and the FULL ACAMService tick are bit-identical to
     replicated execution — predictions, margins, escalation set — for
@@ -156,23 +156,32 @@ class TestChunkedMarginsKernel:
         assert float(m_k[0]) == 0.0 and int(p_k[0]) == 0
 
     def test_matches_resident_fused_kernel_bit_for_bit(self):
+        """The feature_count face equals the jnp oracle bit for bit, at a
+        resident bank (one chunk) and a class-chunked one."""
         from repro.kernels.acam_match import ops as match_ops
 
         key = jax.random.PRNGKey(5)
-        c, k, n, b = 1100, 2, 64, 8
-        tmpl = (jax.random.uniform(key, (c, k, n)) > 0.5).astype(jnp.float32)
-        valid = jnp.ones((c, k), bool)
-        thr = jnp.zeros((n,))
-        feats = jax.random.normal(jax.random.fold_in(key, 1), (b, n))
-        lo = jnp.zeros((b,), jnp.int32)
-        hi = jnp.full((b,), c, jnp.int32)
-        p1, pc1, m1 = match_ops.classify_fused_margins(
-            feats, thr, tmpl, valid, lo, hi)
-        p2, pc2, m2 = match_ops.classify_fused_margins_chunked(
-            feats, thr, tmpl, valid, lo, hi, max_rows=match.MAX_FUSED_ROWS)
-        np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
-        np.testing.assert_array_equal(np.asarray(pc1), np.asarray(pc2))
-        np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
+        n, b = 64, 8
+        ref = match.engine_for(backend="reference")
+        for c, k in ((900, 2), (1100, 2)):
+            cp = layout.padded_classes(c)
+            assert (cp // layout.class_chunk(cp, k, match.MAX_FUSED_ROWS)
+                    > 1) == (c == 1100)
+            tmpl = (jax.random.uniform(jax.random.fold_in(key, c),
+                                       (c, k, n)) > 0.5).astype(jnp.float32)
+            valid = jnp.ones((c, k), bool).at[3, 1].set(False)
+            thr = jax.random.normal(jax.random.fold_in(key, 2), (n,)) * 0.1
+            feats = jax.random.normal(jax.random.fold_in(key, 1), (b, n))
+            lo = jnp.zeros((b,), jnp.int32)
+            hi = jnp.full((b,), c, jnp.int32)
+            got = match_ops.classify_fused_margins(
+                feats, thr, tmpl, valid, lo, hi,
+                max_rows=match.MAX_FUSED_ROWS)
+            bank = TemplateBank(tmpl, jnp.zeros_like(tmpl),
+                                jnp.ones_like(tmpl), valid, thr)
+            want = ref.classify_features_margin(feats, bank, lo, hi)
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def run_sub(code: str, timeout=600) -> str:

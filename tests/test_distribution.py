@@ -104,6 +104,26 @@ class TestMultiDevice:
         loss = float(out.strip().split("LOSS")[-1])
         assert 0.0 < loss < 20.0
 
+    def test_constrain_binds_held_mesh(self):
+        """A mesh installed with the axes (as a spec-built service or a
+        forced test mesh does) resolves `constrain` outside `with mesh:`."""
+        out = run_sub("""
+            import os
+            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+            import jax, jax.numpy as jnp
+            from repro.distributed import context as mesh_ctx
+            from repro.launch.mesh import make_mesh
+
+            mesh = make_mesh((2, 2), ("data", "model"))
+            mesh_ctx.set_mesh_axes("data", "model", mesh)
+            f = jax.jit(lambda x: mesh_ctx.constrain(x * 2, "dp", None,
+                                                     "model", None))
+            y = f(jnp.ones((4, 3, 4, 8)))
+            assert float(y.sum()) == 2 * 4 * 3 * 4 * 8
+            print("SPEC", y.sharding.spec)
+            """)
+        assert "SPEC PartitionSpec('data', None, 'model')" in out
+
     def test_elastic_remesh_8dev(self):
         """Checkpoint on a (4,2) mesh restores onto (2,4) and keeps training."""
         out = run_sub("""

@@ -17,6 +17,7 @@ from repro.kernels.acam_similarity.acam_similarity import eq11
 from repro.kernels.acam_similarity.ref import acam_similarity_ref
 from repro.kernels.kd_loss import ops as kd_ops
 from repro.kernels.kd_loss.ref import kd_loss_ref
+from repro.match import MAX_FUSED_ROWS
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -56,9 +57,9 @@ class TestAcamMatch:
         feats = jax.random.normal(key, (64, 96))
         labels = jnp.arange(64) % 4
         bank = T.generate_templates(feats, labels, 4, k=2)
-        pred_kernel, _ = match_ops.classify(
-            feats, bank.thresholds, bank.templates.reshape(8, 96),
-            bank.valid.reshape(8), 4)
+        pred_kernel, _, _ = match_ops.classify_fused_margins(
+            feats, bank.thresholds, bank.templates, bank.valid,
+            max_rows=MAX_FUSED_ROWS)
         q = quant.binarize(feats, bank.thresholds)
         pred_core, _ = matching.classify(q, bank, method="feature_count")
         assert bool(jnp.all(pred_kernel == pred_core))

@@ -14,9 +14,10 @@ import pytest
 
 from repro.core import matching, quant
 from repro.core.templates import TemplateBank
-from repro.kernels import layout, tuning
+from repro.kernels import tuning
 from repro.kernels.acam_match import ops as match_ops
 from repro.kernels.acam_similarity import ops as sim_ops
+from repro.match import MAX_FUSED_ROWS
 
 
 def _bank(key, c, k, n, *, invalidate_some=True) -> TemplateBank:
@@ -132,23 +133,24 @@ class TestClassifyBinaryQueries:
 
 class TestFusedOpsDirect:
     def test_fused_ops_direct(self):
-        """classify_fused == two-stage kernel == reference, same bank."""
+        """Both methods' single-tenant serve-kernel faces == reference."""
         key = jax.random.PRNGKey(3)
         c, k, n = 6, 3, 300
         bank = _bank(key, c, k, n)
         feats = jax.random.normal(jax.random.fold_in(key, 9), (29, n))
-        pred_f, pc_f = match_ops.classify_fused(feats, bank.thresholds,
-                                                bank.templates, bank.valid)
-        pred_t, pc_t = match_ops.classify(feats, bank.thresholds,
-                                          bank.templates.reshape(c * k, n),
-                                          bank.valid.reshape(c * k), c)
-        np.testing.assert_array_equal(np.asarray(pred_f), np.asarray(pred_t))
-        np.testing.assert_allclose(np.asarray(pc_f), np.asarray(pc_t), atol=0)
-
-        pred_s, pc_s = sim_ops.classify_fused(feats, bank.thresholds,
-                                              bank.lower, bank.upper,
-                                              bank.valid, alpha=1.0)
         q = quant.binarize(feats, bank.thresholds)
+
+        pred_f, pc_f, _ = match_ops.classify_fused_margins(
+            feats, bank.thresholds, bank.templates, bank.valid,
+            max_rows=MAX_FUSED_ROWS)
+        pred_r, pc_r = matching.classify(q, bank, method="feature_count",
+                                         backend="reference")
+        np.testing.assert_array_equal(np.asarray(pred_f), np.asarray(pred_r))
+        np.testing.assert_allclose(np.asarray(pc_f), np.asarray(pc_r), atol=0)
+
+        pred_s, pc_s, _ = sim_ops.classify_fused_margins(
+            feats, bank.thresholds, bank.lower, bank.upper, bank.valid,
+            alpha=1.0, max_rows=MAX_FUSED_ROWS)
         pred_r, pc_r = matching.classify(q, bank, method="similarity",
                                          backend="reference")
         np.testing.assert_array_equal(np.asarray(pred_s), np.asarray(pred_r))
@@ -185,31 +187,6 @@ class TestBackendDispatch:
         q = jnp.zeros((2, 32))
         with pytest.raises(ValueError):
             matching.feature_count_scores(q, bank.templates, backend="gpuuu")
-
-
-class TestKmajorLayout:
-    def test_roundtrip(self):
-        key = jax.random.PRNGKey(0)
-        c, k, n = 10, 3, 17
-        arr = jax.random.normal(key, (c, k, n))
-        flat = layout.flatten_kmajor(arr, c)
-        cp = layout.padded_classes(c)
-        assert flat.shape == (k * cp, n)
-        for kk in range(k):
-            np.testing.assert_array_equal(
-                np.asarray(flat[kk * cp: kk * cp + c]),
-                np.asarray(arr[:, kk, :]))
-            # padded class rows are zero
-            assert not np.asarray(flat[kk * cp + c: (kk + 1) * cp]).any()
-
-    def test_valid_rows(self):
-        valid = jnp.array([[True, False], [True, True]])
-        v = layout.valid_kmajor(valid, 2)
-        cp = layout.padded_classes(2)
-        assert v.shape == (2 * cp,)
-        assert v[0] == 1.0 and v[1] == 1.0          # k=0: both classes valid
-        assert v[cp] == 0.0 and v[cp + 1] == 1.0    # k=1: class 0 invalid
-        assert float(v.sum()) == 3.0
 
 
 class TestTuning:

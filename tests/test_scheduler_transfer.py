@@ -35,6 +35,22 @@ from repro.serve import scheduler as sched_lib
 from repro.serve.registry import TemplateBankRegistry
 from test_bank_sharding import run_sub
 
+
+@pytest.fixture(autouse=True)
+def restore_mesh_context():
+    """`HybridService.from_spec` installs its serving mesh into the
+    process-wide context; put back whatever was there so no later test in
+    this process inherits it."""
+    saved_axes, saved_mesh = context.get(), context.get_mesh()
+    try:
+        yield
+    finally:
+        context.clear()
+        if saved_axes is not None:
+            context.set_mesh_axes(saved_axes.dp, saved_axes.model,
+                                  saved_mesh)
+
+
 N = 64
 SLOTS = 8
 

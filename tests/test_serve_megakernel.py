@@ -1,16 +1,18 @@
-"""The resident serving mega-kernel + symmetric chunked coverage (PR 8).
+"""The serving mega-kernel, the one classify kernel of each method.
 
 Four contracts:
 
   * `MatchEngine.classify_serve` on the kernel backend — the ONE-pallas_call
     gather -> binarize -> match -> per-class max -> WTA -> windowed margin ->
-    escalation-mask path — is bit-identical to the jnp reference oracle AND
-    to the pre-megakernel composition (``serve_fusion="compose"``), for both
-    methods, at resident AND class-chunked bank sizes;
+    escalation-mask path — is bit-identical to the jnp reference oracle,
+    for both methods, at resident AND class-chunked bank sizes;
   * the tick really is a single dispatch: the traced jaxpr contains exactly
-    one pallas_call, with no jnp epilogue, below and above MAX_FUSED_ROWS;
-  * the similarity method now has the same single-dispatch chunked coverage
-    as feature_count ((1100, 2) and (300, 8) both exceed the fused budget);
+    one pallas_call, the method's serve kernel, with no jnp epilogue, below
+    and above MAX_FUSED_ROWS — and so does the single-tenant face behind
+    `classify_features_margin`;
+  * both methods' non-serve classify calls run that face and match the
+    reference at resident and class-chunked sizes ((1100, 2) and (300, 8)
+    both exceed the fused budget);
   * "auto" backend routing uses per-method tiny cutoffs
     (`repro.match.tiny_cutoff`) from the measured reference/kernel
     crossovers, and the autotuner cache separates interpreted from compiled
@@ -56,9 +58,9 @@ def _serve_inputs(seed, b, c, k, n=N):
     return feats, thr_table, slot, bank, lo, hi
 
 
-def _eng(method, backend, serve_fusion="mega"):
+def _eng(method, backend):
     return match.engine_from_config(match.EngineConfig(
-        method=method, backend=backend, serve_fusion=serve_fusion))
+        method=method, backend=backend))
 
 
 # (c, k) resident in the fused budget, and two chunked shapes past it
@@ -69,7 +71,7 @@ CHUNKED = [(1100, 2), (300, 8)]
 class TestMegaKernelParity:
     @pytest.mark.parametrize("method", ["feature_count", "similarity"])
     @pytest.mark.parametrize("c,k", [RESIDENT] + CHUNKED)
-    def test_bit_identical_to_oracle_and_compose(self, method, c, k):
+    def test_bit_identical_to_oracle(self, method, c, k):
         feats, thr_table, slot, bank, lo, hi = _serve_inputs(c + k, 16, c, k)
         ref = _eng(method, "reference")
         # per-row taus straddle each oracle margin (above on even rows,
@@ -81,15 +83,10 @@ class TestMegaKernelParity:
         r = ref.classify_serve(feats, thr_table, slot, bank, lo, hi, tau)
         k_ = _eng(method, "kernel").classify_serve(
             feats, thr_table, slot, bank, lo, hi, tau)
-        comp = _eng(method, "kernel", "compose").classify_serve(
-            feats, thr_table, slot, bank, lo, hi, tau)
         for name, i in zip(("pred", "per_class", "margin", "escalate"),
                            range(4)):
             np.testing.assert_array_equal(
                 np.asarray(r[i]), np.asarray(k_[i]), err_msg=f"mega {name}")
-            np.testing.assert_array_equal(
-                np.asarray(r[i]), np.asarray(comp[i]),
-                err_msg=f"compose {name}")
         esc = np.asarray(r[3])
         assert esc.any() and not esc.all()  # mask exercised both ways
         assert int(r[0][0]) == 0 and float(r[2][0]) == 0.0  # empty window
@@ -101,9 +98,18 @@ class TestMegaKernelParity:
         feats, thr_table, slot, bank, lo, hi = _serve_inputs(7, 8, c, k)
         tau = jnp.zeros((8,), jnp.float32)
         eng = _eng(method, "kernel")
-        jaxpr = str(jax.make_jaxpr(lambda *a: eng.classify_serve(*a))(
-            feats, thr_table, slot, bank, lo, hi, tau))
-        assert jaxpr.count("pallas_call") == 1
+        serve = {"feature_count": "acam_match_serve",
+                 "similarity": "acam_similarity_serve"}[method]
+        ticks = {
+            "classify_serve": (eng.classify_serve,
+                               (feats, thr_table, slot, bank, lo, hi, tau)),
+            "classify_features_margin": (eng.classify_features_margin,
+                                         (feats, bank, lo, hi)),
+        }
+        for entry, (fn, args) in ticks.items():
+            jaxpr = str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
+            assert jaxpr.count("pallas_call") == 1, entry
+            assert f"name={serve}" in jaxpr, entry
 
     def test_default_tau_never_escalates(self):
         feats, thr_table, slot, bank, lo, hi = _serve_inputs(3, 8, *RESIDENT)
@@ -112,26 +118,30 @@ class TestMegaKernelParity:
         assert not np.asarray(out[3]).any()
 
 
-class TestChunkedSimilarityCoverage:
-    """The similarity method's symmetric single-dispatch chunked path."""
+class TestSingleTenantFaceCoverage:
+    """Both methods' non-serve classify calls: the serve kernel's
+    single-tenant face, resident and class-chunked."""
 
-    @pytest.mark.parametrize("c,k", CHUNKED)
-    def test_margin_parity_past_fused_budget(self, c, k):
-        assert k * layout.padded_classes(c) > match.MAX_FUSED_ROWS
+    @pytest.mark.parametrize("method", ["feature_count", "similarity"])
+    @pytest.mark.parametrize("c,k", [RESIDENT] + CHUNKED)
+    def test_margin_parity_resident_and_chunked(self, method, c, k):
+        assert (k * layout.padded_classes(c) > match.MAX_FUSED_ROWS) == \
+            ((c, k) in CHUNKED)
         feats, _, _, bank, lo, hi = _serve_inputs(c, 16, c, k)
-        ker = _eng("similarity", "kernel")
-        ref = _eng("similarity", "reference")
+        ker = _eng(method, "kernel")
+        ref = _eng(method, "reference")
         got = ker.classify_features_margin(feats, bank, lo, hi)
         want = ref.classify_features_margin(feats, bank, lo, hi)
         for g, w in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
-    @pytest.mark.parametrize("c,k", CHUNKED)
-    def test_classify_features_single_dispatch_parity(self, c, k):
+    @pytest.mark.parametrize("method", ["feature_count", "similarity"])
+    @pytest.mark.parametrize("c,k", [RESIDENT] + CHUNKED)
+    def test_classify_features_single_dispatch_parity(self, method, c, k):
         feats, _, _, bank, _, _ = _serve_inputs(c + 1, 8, c, k)
-        ker = _eng("similarity", "kernel")
+        ker = _eng(method, "kernel")
         got = ker.classify_features(feats, bank)
-        want = _eng("similarity", "reference").classify_features(feats, bank)
+        want = _eng(method, "reference").classify_features(feats, bank)
         np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
         np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
         jaxpr = str(jax.make_jaxpr(
@@ -189,13 +199,3 @@ class TestTuningCacheKeys:
         finally:
             tuning.clear_cache_for_tests()
 
-
-class TestServeFusionConfig:
-    def test_validate_rejects_unknown_mode(self):
-        with pytest.raises(ValueError, match="serve_fusion"):
-            match.MatchEngine(match.EngineConfig(serve_fusion="hyper"))
-
-    def test_modes_are_distinct_jit_keys(self):
-        a = match.EngineConfig(serve_fusion="mega")
-        b = match.EngineConfig(serve_fusion="compose")
-        assert a != b and hash(a) != hash(b)

@@ -18,8 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import layout, tuning
-from repro.kernels.acam_match.acam_match import (
-    acam_match_classify_margins_chunked, acam_match_serve)
+from repro.kernels.acam_match.acam_match import acam_match_serve
 from repro.kernels.acam_similarity.acam_similarity import acam_similarity_serve
 from repro.match.backends import MAX_FUSED_ROWS
 
@@ -29,6 +28,9 @@ TENANTS = 64
 #: classes x k=2 is exactly MAX_FUSED_ROWS rows (resident, one chunk);
 #: 64 tenants x 48 classes x k=2 (40 classes bucketed to 48) is past it
 BANKS = {"resident": (1024, 2), "chunked": (3072, 2)}
+#: one shard of `bench/configs/cifar10-k2-t512-4shard.json`'s class-sharded
+#: super-bank: (padded classes, templates per class) per chip
+SHARD_BANK = (2048, 2)
 
 
 @pytest.fixture(scope="module")
@@ -98,17 +100,22 @@ def test_serve_kernel_compiles(one_chip, no_persistent_cache, method, regime):
 
 
 def test_margins_chunked_compiles(one_chip, no_persistent_cache):
-    cp, k = BANKS["chunked"]
+    """The feature_count single-tenant face (`ops.classify_fused_margins`:
+    one thresholds row, slot 0, tau -inf) at one bank shard of the
+    512-tenant four-chip fleet: 2048 classes, K = 2, two chunks of 1024."""
+    cp, k = SHARD_BANK
     chunk = layout.class_chunk(cp, k, MAX_FUSED_ROWS)
-    blk = _block("acam_match", cp * k, clamp=False)
+    assert cp // chunk == 2
+    blk = _block("acam_match", cp * k, clamp=True)
 
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def fn(f, thr, t, v, lo, hi):
-        return acam_match_classify_margins_chunked(
-            f, thr, t, v, lo, hi, cp, chunk=chunk, block=blk,
-            interpret=False)
+        slot = jnp.zeros((B,), jnp.int32)
+        tau = jnp.full((B,), -jnp.inf, jnp.float32)
+        return acam_match_serve(f, thr[None, :], slot, t, v, lo, hi, tau, cp,
+                                chunk=chunk, block=blk, interpret=False)[:3]
 
     row = s((B,), jnp.int32)
     text = _compiled_text(fn, s((B, N)), s((N,)), s((k, cp, N)), s((k, cp)),
